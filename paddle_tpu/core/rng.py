@@ -21,9 +21,23 @@ import numpy as np
 
 class _KeyState(threading.local):
     def __init__(self):
-        self.key = jax.random.PRNGKey(0)
+        self._key = None
         self.counter = 0
         self.override = None  # (key, counter_box) inside key_scope
+
+    @property
+    def key(self):
+        # built on first use, not at import: making a key initialises the
+        # JAX backend, and a process that has done that holds the chip —
+        # launcher / lint / bench parents import this package and must
+        # leave the chip to the child they start
+        if self._key is None:
+            self._key = jax.random.PRNGKey(0)
+        return self._key
+
+    @key.setter
+    def key(self, value):
+        self._key = value
 
 
 _state = _KeyState()

@@ -18,9 +18,21 @@ from dataclasses import dataclass, field
 import jax
 import numpy as np
 
-# roofline constants (public spec sheets); overridable per call
-DEFAULT_PEAK_FLOPS = 197e12  # bf16 v5e-class
-DEFAULT_HBM_BYTES_PER_S = 819e9  # v5e HBM bandwidth
+
+
+def _roofline(device_kind, peak_flops, hbm_bytes_per_s):
+    """(peak FLOP/s, HBM bytes/s) of the chip the estimate is FOR: the
+    explicit numbers when given, else `profiler.flops`' table entry for
+    `device_kind` (None = the default backend's first device). A kind the
+    table does not list raises — planning on a CPU host for a TPU
+    deployment names its target, e.g. ``device_kind="TPU v5 lite"``."""
+    from ..profiler import flops
+
+    if peak_flops is None:
+        peak_flops = flops.peak_flops(device_kind)
+    if hbm_bytes_per_s is None:
+        hbm_bytes_per_s = flops.hbm_bytes_per_s(device_kind)
+    return peak_flops, hbm_bytes_per_s
 
 
 @dataclass
@@ -52,16 +64,16 @@ def _avals(args):
     return out
 
 
-def estimate_cost(fn, *example_args, peak_flops=DEFAULT_PEAK_FLOPS,
-                  hbm_bytes_per_s=DEFAULT_HBM_BYTES_PER_S, name=None,
-                  _want_out_avals=False):
-    """Cost of `fn(*example_args)` from XLA's compile-time analysis.
+def estimate_cost(fn, *example_args, device_kind=None, peak_flops=None,
+                  hbm_bytes_per_s=None, name=None, _want_out_avals=False):
+    """Cost of `fn(*example_args)` from XLA's compile-time analysis,
+    turned into a roofline time for `device_kind` (see `_roofline`).
 
     `example_args` may be arrays OR ShapeDtypeStructs — nothing executes."""
+    peak_flops, hbm_bytes_per_s = _roofline(device_kind, peak_flops,
+                                            hbm_bytes_per_s)
     lowered = jax.jit(fn).lower(*_avals(example_args))
     analysis = lowered.compile().cost_analysis()
-    if isinstance(analysis, (list, tuple)):  # older jax returns [dict]
-        analysis = analysis[0] if analysis else {}
     cd = CostData.from_cost_analysis(
         name or getattr(fn, "__name__", "fn"), analysis or {},
         peak_flops, hbm_bytes_per_s,
@@ -95,10 +107,10 @@ class CostModel:
     entirely abstractly (jax.eval_shape threads avals through the log,
     each op lowers to HLO for its analysis)."""
 
-    def __init__(self, peak_flops=DEFAULT_PEAK_FLOPS,
-                 hbm_bytes_per_s=DEFAULT_HBM_BYTES_PER_S):
-        self.peak_flops = peak_flops
-        self.hbm_bps = hbm_bytes_per_s
+    def __init__(self, device_kind=None, peak_flops=None,
+                 hbm_bytes_per_s=None):
+        self.peak_flops, self.hbm_bps = _roofline(device_kind, peak_flops,
+                                                  hbm_bytes_per_s)
 
     def profile_measure(self, program, startup_program=None, device="tpu",
                         fetch_cost_list=("time",)):
@@ -175,7 +187,8 @@ def balanced_partition(costs, k):
     return bounds
 
 
-def segment_layers_by_cost(layers, num_stages, sample_input, training=False):
+def segment_layers_by_cost(layers, num_stages, sample_input, training=False,
+                           device_kind=None):
     """Measured-cost pipeline segmentation: thread `sample_input`'s AVAL
     through `layers` (built nn.Layers / callables) with jax.eval_shape,
     measure each forward with XLA cost analysis, and balance the stages
@@ -201,7 +214,7 @@ def segment_layers_by_cost(layers, num_stages, sample_input, training=False):
 
             cd, out_info = estimate_cost(
                 fwd, params, aval, name=type(layer).__name__,
-                _want_out_avals=True,
+                device_kind=device_kind, _want_out_avals=True,
             )
         else:
 
@@ -211,7 +224,7 @@ def segment_layers_by_cost(layers, num_stages, sample_input, training=False):
 
             cd, out_info = estimate_cost(
                 _call_once, aval, name=getattr(layer, "__name__", "fn"),
-                _want_out_avals=True,
+                device_kind=device_kind, _want_out_avals=True,
             )
         per_layer.append(max(cd.time_us, 1e-9))
         out_aval = jax.tree_util.tree_leaves(out_info)[0]

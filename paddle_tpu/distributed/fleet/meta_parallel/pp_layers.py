@@ -42,7 +42,7 @@ class SharedLayerDesc(LayerDesc):
 
 
 class PipelineLayer(Layer):
-    def __init__(self, layers, num_stages=None, topology=None, loss_fn=None, seg_method="uniform", recompute_interval=0, recompute_ctx=None, num_virtual_pipeline_stages=None, seg_sample_input=None):
+    def __init__(self, layers, num_stages=None, topology=None, loss_fn=None, seg_method="uniform", recompute_interval=0, recompute_ctx=None, num_virtual_pipeline_stages=None, seg_sample_input=None, seg_device_kind=None):
         super().__init__()
         self._loss_fn = loss_fn
         self._topo = topology
@@ -80,8 +80,12 @@ class PipelineLayer(Layer):
                 )
             from ....cost_model import segment_layers_by_cost
 
+            # seg_device_kind names the chip whose roofline prices the
+            # layers (None = the one this process runs on; a kind the
+            # peaks table does not list raises)
             self.segment_parts, self.seg_cost_us = segment_layers_by_cost(
-                self._funcs, self.num_stages, seg_sample_input
+                self._funcs, self.num_stages, seg_sample_input,
+                device_kind=seg_device_kind,
             )
         else:
             self.segment_parts = self._segment(seg_method)

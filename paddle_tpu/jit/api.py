@@ -17,7 +17,6 @@ import os
 import pickle
 
 import jax
-import jax.export  # registers the `jax.export` attribute on older jax
 import numpy as np
 
 from ..core import rng

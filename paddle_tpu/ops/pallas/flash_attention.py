@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ._backend import interpret_mode, use_pallas
+from ._backend import interpret_mode, note_xla_fallback, use_pallas
 
 _NEG_INF = -1e30
 
@@ -540,47 +540,51 @@ def flash_attention_array(
 
     bq = _fit_block(block_q, sq)
     bk = _fit_block(block_k, sk)
-    mask_ok = True
+    # why this call cannot take the kernels (None = it can). Off-TPU the
+    # XLA path is the default; on a tpu backend note_xla_fallback counts
+    # and logs the reason, so no shape drops to XLA unseen.
+    why_xla = None
     mf = None
     if mask is not None:
         # additive float masks broadcastable over batch/head stream through
         # the kernel; bool masks fall back
         if mask.dtype == jnp.bool_ or mask.ndim != 4:
-            mask_ok = False
+            why_xla = f"mask dtype {mask.dtype} ndim {mask.ndim}"
         elif mask.shape[2] != sq or mask.shape[3] != sk:
-            mask_ok = False
+            why_xla = f"mask shape {mask.shape} for sq={sq} sk={sk}"
         elif not (
             (mask.shape[0] in (1, q.shape[0]))
             and (mask.shape[1] in (1, q.shape[2]))
         ):
-            mask_ok = False
+            why_xla = f"mask shape {mask.shape} does not broadcast"
         elif (mask.shape[0] == 1) != (mask.shape[1] == 1):
             # mixed broadcast (e.g. [B,1,Sq,Sk]) — materialize over heads
             mf = jnp.broadcast_to(mask, (q.shape[0], q.shape[2], sq, sk))
         else:
             mf = mask
-    drop_ok = dropout_p == 0.0 or dropout_key is not None
     if dropout_p > 0.0 and mask is not None:
         # mask gradients require recomputing ds outside the kernel, which is
         # impossible with the in-kernel dropout PRNG — keep semantics uniform
         # by using the XLA path for the (rare) mask+dropout combination
-        mask_ok = False
-    if (
-        mask_ok and drop_ok
-        and sq % bq == 0 and sk % bk == 0
-        and _use_pallas()
-    ):
-        interpret = interpret_mode()
-        if dropout_p > 0.0 and interpret:
-            # TPU PRNG primitives are unavailable in interpreter mode
-            return _attention_xla(q, k, v, mask, causal, dropout_p, dropout_key)
-        has_mask = mf is not None
-        mb = mf.shape[0] if has_mask else 0
-        mh = mf.shape[1] if has_mask else 0
-        seed = (
-            jax.random.randint(dropout_key, (1,), 0, np.int32(2**31 - 1), dtype=jnp.int32)
-            if dropout_p > 0.0 else jnp.zeros((1,), jnp.int32)
-        )
-        fn = _flash_custom(causal, bq, bk, float(dropout_p), has_mask, mb, mh, interpret)
-        return fn(q, k, v, mf if has_mask else None, seed)
-    return _attention_xla(q, k, v, mask, causal, dropout_p, dropout_key)
+        why_xla = "mask with dropout"
+    elif dropout_p > 0.0 and dropout_key is None:
+        why_xla = "dropout without a key"
+    if why_xla is None and (sq % bq or sk % bk):
+        why_xla = f"sq={sq} sk={sk} do not divide into tiles {bq}x{bk}"
+    if why_xla is None and not _use_pallas():
+        why_xla = "kernels off"
+    interpret = interpret_mode()
+    if why_xla is None and dropout_p > 0.0 and interpret:
+        why_xla = "TPU PRNG primitives are unavailable in interpreter mode"
+    if why_xla is not None:
+        note_xla_fallback("flash_attention", why_xla)
+        return _attention_xla(q, k, v, mask, causal, dropout_p, dropout_key)
+    has_mask = mf is not None
+    mb = mf.shape[0] if has_mask else 0
+    mh = mf.shape[1] if has_mask else 0
+    seed = (
+        jax.random.randint(dropout_key, (1,), 0, np.int32(2**31 - 1), dtype=jnp.int32)
+        if dropout_p > 0.0 else jnp.zeros((1,), jnp.int32)
+    )
+    fn = _flash_custom(causal, bq, bk, float(dropout_p), has_mask, mb, mh, interpret)
+    return fn(q, k, v, mf if has_mask else None, seed)

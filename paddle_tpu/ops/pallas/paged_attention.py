@@ -39,8 +39,8 @@ Kernel design (TPU):
 
 The dispatch (`paged_attention_arrays`) is the seam `serving/block_pool.py`
 calls after scattering the step's new K/V into the arena: Pallas on TPU (or
-interpreted when PADDLE_TPU_FORCE_PALLAS_INTERPRET / _PALLAS_INTERPRET is
-set), XLA gather everywhere else. The fallback gathers into the SAME
+interpreted when PADDLE_TPU_FORCE_PALLAS_INTERPRET is set), XLA gather
+everywhere else. The fallback gathers into the SAME
 ``[rows, seq, heads, head_dim]`` layout and einsum as `models/gpt.py`'s
 contiguous-cache decode, keeping greedy serving outputs token-for-token
 identical to `GPT.generate`.
@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ._backend import interpret_mode, use_pallas
+from ._backend import interpret_mode, note_xla_fallback, use_pallas
 
 _NEG_INF = -1e30
 
@@ -118,9 +118,10 @@ def _ragged_kernel(bt_ref, qs_ref, kl_ref, qb_ref, q_ref, k_ref, v_ref,
     ``qt``-wide query tile — dead q blocks re-address the last live tile
     (no DMA) and skip all compute, exactly like the dead KV iterations.
 
-    ``quant`` (int8 arena): two extra per-(layer, head, block) f32 scale
-    refs ride the same kv index map, and each DMA'd int8 tile dequantizes
-    IN VMEM (one multiply per tile) before the MXU dot — the arena walk
+    ``quant`` (int8 arena): two extra f32 scale refs carry this (row,
+    head)'s per-block scales as one ``[1, nk]`` lane row in block-table
+    order; step j picks lane j and each DMA'd int8 tile dequantizes IN
+    VMEM (one multiply per tile) before the MXU dot — the arena walk
     moves a quarter of the f32 bytes and the compute path is unchanged."""
     from jax.experimental import pallas as pl
 
@@ -146,7 +147,10 @@ def _ragged_kernel(bt_ref, qs_ref, kl_ref, qb_ref, q_ref, k_ref, v_ref,
         q = q_ref[0, 0]        # [qt, D]
         kt = k_ref[0, 0, 0]    # [bs, D]
         if quant:
-            kt = kt.astype(jnp.float32) * ksc_ref[0, 0, 0]
+            lane = jax.lax.broadcasted_iota(
+                jnp.int32, ksc_ref.shape[2:], 1) == j      # [1, nk]
+            kt = kt.astype(jnp.float32) * jnp.sum(
+                jnp.where(lane, ksc_ref[0, 0], 0.0), axis=1, keepdims=True)
         s = jax.lax.dot_general(
             q, kt, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -166,7 +170,8 @@ def _ragged_kernel(bt_ref, qs_ref, kl_ref, qb_ref, q_ref, k_ref, v_ref,
         l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         vt = v_ref[0, 0, 0]    # [bs, D]
         if quant:
-            vt = vt.astype(jnp.float32) * vsc_ref[0, 0, 0]
+            vt = vt.astype(jnp.float32) * jnp.sum(
+                jnp.where(lane, vsc_ref[0, 0], 0.0), axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -216,11 +221,10 @@ def _build_ragged(B, H, sq, d, bs, nk, layer, dtype_name, interpret,
         return (layer, h, bt[i, jc], 0, 0)
 
     def sc_index(i, h, qb, j, bt, qs, kl, qlb):
-        # the int8 scale sidecars [layers, H, num_blocks] walk the SAME
-        # clamped block index as the payload tiles — one f32 scalar rides
-        # along with each [bs, d] int8 tile's DMA
-        jc = jnp.where(qb < qlb[i], jnp.minimum(j, kl[i] - 1), kl[i] - 1)
-        return (layer, h, bt[i, jc])
+        # the int8 scales arrive gathered per row, [B, H, 1, nk] in
+        # block-table order (see ragged_paged_attention): one whole lane
+        # row per (row, head), fetched once and unchanged across qb and j
+        return (i, h, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, qt, d), q_index),
@@ -228,8 +232,8 @@ def _build_ragged(B, H, sq, d, bs, nk, layer, dtype_name, interpret,
         pl.BlockSpec((1, 1, 1, bs, d), kv_index),
     ]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, 1), sc_index),
-                     pl.BlockSpec((1, 1, 1), sc_index)]
+        in_specs += [pl.BlockSpec((1, 1, 1, nk), sc_index),
+                     pl.BlockSpec((1, 1, 1, nk), sc_index)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B, H, nq, nk),
@@ -283,7 +287,18 @@ def ragged_paged_attention(q, k_arena, v_arena, layer, block_tables,
     qh = jnp.transpose(q, (0, 2, 1, 3))  # [B, H, S, D]
     operands = (qh, k_arena, v_arena)
     if quant:
-        operands += (k_scale, v_scale)
+        # The arena-side sidecars stay [layers, H, num_blocks], but a
+        # (1, 1, 1) block over them does not lower for TPU ("the last two
+        # dimensions of your block shape [must be] divisible by 8 and 128
+        # respectively, or be equal to the respective dimensions of the
+        # overall array"). So each row's scales are gathered through its
+        # block table here, a [B, H, 1, nk] f32 array of a few KB, whose
+        # (1, nk) minor dims a block can take whole.
+        def row_scales(sc):
+            return jnp.transpose(sc[layer][:, block_tables],
+                                 (1, 0, 2))[:, :, None, :]
+
+        operands += (row_scales(k_scale), row_scales(v_scale))
     o = fn(
         block_tables.astype(jnp.int32),
         q_start.astype(jnp.int32),
@@ -372,22 +387,29 @@ def paged_attention_arrays(q, k_arena, v_arena, layer, block_tables, qpos,
     no wrapper — GSPMD partitions the padded gather over the arena's
     head sharding on its own (and its causal qpos mask already discards
     dead query rows, so it ignores q_lens)."""
-    if (
-        q_start is not None and kv_live is not None
-        and scale is None  # kernel bakes 1/sqrt(D); custom scales fall back
-        and use_pallas()
-    ):
-        if mesh is not None and mesh.shape.get(tp_axis, 1) > 1:
-            return ragged_paged_attention_sharded(
-                q, k_arena, v_arena, layer, block_tables, q_start, kv_live,
-                q_lens=q_lens, mesh=mesh, tp_axis=tp_axis,
-                interpret=interpret_mode(),
-                k_scale=k_scale, v_scale=v_scale,
-            )
-        return ragged_paged_attention(
+    why_xla = None
+    if q_start is None or kv_live is None:
+        why_xla = "no ragged metadata (q_start / kv_live)"
+    elif scale is not None:  # kernel bakes 1/sqrt(D)
+        why_xla = "custom softmax scale"
+    elif not use_pallas():
+        why_xla = "kernels off"
+    if why_xla is not None:
+        # off-TPU this is the default path; on a tpu backend it is
+        # counted and logged, never quiet
+        note_xla_fallback("paged_attention", why_xla)
+        return paged_attention_xla(q, k_arena, v_arena, layer, block_tables,
+                                   qpos, scale, k_scale=k_scale,
+                                   v_scale=v_scale)
+    if mesh is not None and mesh.shape.get(tp_axis, 1) > 1:
+        return ragged_paged_attention_sharded(
             q, k_arena, v_arena, layer, block_tables, q_start, kv_live,
-            q_lens=q_lens, interpret=interpret_mode(),
+            q_lens=q_lens, mesh=mesh, tp_axis=tp_axis,
+            interpret=interpret_mode(),
             k_scale=k_scale, v_scale=v_scale,
         )
-    return paged_attention_xla(q, k_arena, v_arena, layer, block_tables,
-                               qpos, scale, k_scale=k_scale, v_scale=v_scale)
+    return ragged_paged_attention(
+        q, k_arena, v_arena, layer, block_tables, q_start, kv_live,
+        q_lens=q_lens, interpret=interpret_mode(),
+        k_scale=k_scale, v_scale=v_scale,
+    )

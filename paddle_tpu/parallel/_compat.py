@@ -1,29 +1,11 @@
-"""Single shard_map shim shared by every manual-SPMD module."""
+"""The one `shard_map` spelling shared by every manual-SPMD module."""
 from __future__ import annotations
 
-import functools
-
-
-def _resolve():
-    try:
-        from jax import shard_map as mod
-
-        fn = mod.shard_map if hasattr(mod, "shard_map") else mod
-    except Exception:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map as fn
-    return fn
-
-
-_raw = _resolve()
+import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs, **kwargs):
+    """`jax.shard_map` with replication checking off unless asked for."""
     kwargs.setdefault("check_vma", False)
-    try:
-        return _raw(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-    except TypeError:
-        # older API spells the flag check_rep
-        kwargs.pop("check_vma", None)
-        return _raw(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False, **kwargs
-        )
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

@@ -40,7 +40,7 @@ def _block_attn(q, k, v, m, l, acc, q_off, k_off, causal, scale):
 
 def _ring_body(q, k, v, axis_name, causal, scale):
     """Runs on each 'sp' shard: local q stays; k/v rotate around the ring."""
-    n = jax.lax.psum(1, axis_name)  # jax.lax.axis_size absent in older jax
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, sq, h, d = q.shape
     seq_block = sq  # per-device block length

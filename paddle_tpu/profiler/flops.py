@@ -14,9 +14,11 @@ Three layers:
   fused CE head's backward logit recompute (ops/fused_ce.py) is extra
   hardware work that buys HBM, so it raises throughput but is excluded;
   MFU stays honest.
-- **Peak FLOPs** (`peak_flops`): bf16 peak by TPU generation from public
-  spec sheets, matched against `device.device_kind` (longest key wins),
-  with a conservative v5e-class default for unknown hardware.
+- **Peaks** (`peak_flops`, `hbm_bytes_per_s`): bf16 peak and HBM
+  bandwidth by TPU generation from public spec sheets, matched against
+  `device.device_kind` (longest key wins). A device that is not in the
+  table is an error, never a default: a utilization against somebody
+  else's peak is not a measurement.
 - **Goodput** (`goodput_summary`, `collective_time`): tokens/s,
   step-time p50/p95 from a `TrainTracer` export's ``train_step`` spans,
   and time-in-collectives from an xplane capture's op categories — the
@@ -38,26 +40,45 @@ PEAK_FLOPS_BF16 = {
     "v6e": 918e12,
 }
 
-# conservative default for unknown hardware (v5e-class)
-DEFAULT_PEAK_FLOPS = 197e12
+# HBM bytes/s by TPU generation (same spec sheets)
+HBM_BYTES_PER_S = {
+    "v4": 1228e9,
+    "v5 lite": 819e9,
+    "v5e": 819e9,
+    "v5": 2765e9,
+    "v5p": 2765e9,
+    "v6 lite": 1640e9,
+    "v6e": 1640e9,
+}
 
 
-def peak_flops(device=None) -> float:
-    """bf16 peak FLOP/s for `device` (a jax Device, or a device_kind
+def _by_device_kind(table, device, what):
+    """`table`'s entry for `device` (a jax Device, or a device_kind
     string; None = the default backend's first device). Longest matching
-    registry key wins, so "v5p" beats "v5"."""
+    key wins, so "v5p" beats "v5"; no match raises."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
     kind = device if isinstance(device, str) else getattr(
         device, "device_kind", "")
-    kind = kind.lower()
-    for key, val in sorted(PEAK_FLOPS_BF16.items(),
-                           key=lambda kv: -len(kv[0])):
-        if key in kind:
+    for key, val in sorted(table.items(), key=lambda kv: -len(kv[0])):
+        if key in kind.lower():
             return val
-    return DEFAULT_PEAK_FLOPS
+    raise ValueError(
+        f"no {what} listed for device_kind {kind!r} (known: "
+        f"{sorted(table)}); name a listed device_kind or add this one "
+        "with its source")
+
+
+def peak_flops(device=None) -> float:
+    """bf16 peak FLOP/s for `device`; an unlisted device_kind raises."""
+    return _by_device_kind(PEAK_FLOPS_BF16, device, "bf16 peak FLOP/s")
+
+
+def hbm_bytes_per_s(device=None) -> float:
+    """HBM bandwidth for `device`; an unlisted device_kind raises."""
+    return _by_device_kind(HBM_BYTES_PER_S, device, "HBM bandwidth")
 
 
 def dense_train_flops_per_token(hidden_size, num_layers, seq_len,

@@ -50,15 +50,9 @@ def _in_abstract_trace(x):
     autodiff tracers (jit-of-grad). Eager autodiff also passes tracers
     through (jax.vjp linearization), but their `.primal` chain bottoms
     out at a concrete array, not a jaxpr tracer — no warning there."""
-    try:
-        from jax.interpreters import partial_eval as pe
+    from jax.interpreters import partial_eval as pe
 
-        dyn = pe.DynamicJaxprTracer
-    except Exception:  # noqa: BLE001 — schema drift: fall back to coarse
-        try:
-            return isinstance(x, jax.core.Tracer)
-        except Exception:  # noqa: BLE001 — diagnostics must never crash
-            return False
+    dyn = pe.DynamicJaxprTracer
     for _ in range(8):  # unwrap nested autodiff/batching tracers
         if isinstance(x, dyn):
             return True

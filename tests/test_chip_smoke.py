@@ -1,0 +1,90 @@
+"""chip_smoke.py on the CPU: the script's phase functions at `gpt_tiny`
+size with the kernels interpreted, and the script itself refusing to run
+without a tpu backend. The same functions run at full size on the chip
+(`python chip_smoke.py`); checks only a tpu backend can meet (device
+placement, `tpu_custom_call` counts) are skipped here by the script itself.
+"""
+import os
+import subprocess
+import sys
+
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.models.gpt import gpt_tiny
+
+import chip_smoke  # tests/conftest.py puts the repo root on sys.path
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(autouse=True)
+def _interpreted_kernels(monkeypatch):
+    monkeypatch.delenv("PADDLE_TPU_DISABLE_PALLAS", raising=False)
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS_INTERPRET", "1")
+
+
+def test_kernel_phase_interpreted():
+    # gpt_tiny's attention: 8 heads of 32; tables of 4 blocks of 16
+    chip_smoke.kernel_phase(
+        ragged_shapes=[dict(batch=3, heads=2, head_dim=32, block_size=16,
+                            max_blocks=4)],
+        widths=(1, 5, 16),
+        flash_shapes=[dict(batch=1, seq=128, heads=2, head_dim=32)],
+    )
+
+
+def test_kernel_phase_catches_a_wrong_kernel(monkeypatch):
+    """The comparison has teeth: a kernel that ignores the int8 scales
+    (what a dropped sidecar would do) fails the phase."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    real = pa.ragged_paged_attention
+
+    def unscaled(*a, k_scale=None, v_scale=None, **kw):
+        ones = None if k_scale is None else k_scale * 0 + 1
+        return real(*a, k_scale=ones, v_scale=ones, **kw)
+
+    monkeypatch.setattr(pa, "ragged_paged_attention", unscaled)
+    with pytest.raises(chip_smoke.SmokeFailure, match="int8 arena"):
+        chip_smoke.kernel_phase(
+            ragged_shapes=[dict(batch=3, heads=2, head_dim=32,
+                                block_size=16, max_blocks=4)],
+            widths=(5,), flash_shapes=[])
+
+
+def test_serve_phase_tiny():
+    paddle.seed(0)
+    chip_smoke.serve_phase(
+        gpt_tiny(), max_batch=2, max_seq_len=96, prefill_chunk=32,
+        max_new=6, prompt_lens=(20, 5, 40))
+
+
+def test_serve_phase_tiny_tp2():
+    paddle.seed(0)
+    chip_smoke.serve_phase(
+        gpt_tiny(), max_batch=2, max_seq_len=96, prefill_chunk=32,
+        max_new=4, prompt_lens=(20, 40), mesh=2)
+
+
+def test_train_phase_tiny():
+    paddle.seed(0)
+    chip_smoke.train_phase(gpt_tiny(), degrees={"dp": 1}, batch=2, seq=128)
+
+
+def test_train_phase_tiny_zero2_dp2():
+    paddle.seed(0)
+    chip_smoke.train_phase(gpt_tiny(), degrees={"dp": 2}, batch=2, seq=128,
+                           zero_stage=2, remat=True)
+
+
+def test_script_refuses_a_cpu_backend(tmp_path):
+    """`python chip_smoke.py` under JAX_PLATFORMS=cpu exits non-zero, names
+    the platform it found, and prints no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, env=env, cwd=tmp_path)
+    assert proc.returncode != 0
+    assert "found platform 'cpu'" in proc.stderr
+    assert '"ok"' not in proc.stdout

@@ -19,12 +19,18 @@ from paddle_tpu.cost_model import (
 )
 
 
+# the chip the estimates are for: the tests run on a CPU host, which the
+# peaks table (rightly) does not list
+V5E = "TPU v5 lite"
+
+
 def test_estimate_cost_matmul_flops():
     import jax.numpy as jnp
 
     cd = estimate_cost(
         lambda a, b: a @ b,
         np.zeros((256, 512), np.float32), np.zeros((512, 128), np.float32),
+        device_kind=V5E,
     )
     # 2*M*K*N flops
     assert cd.flops == pytest.approx(2 * 256 * 512 * 128, rel=0.01)
@@ -34,9 +40,20 @@ def test_estimate_cost_matmul_flops():
 
 def test_layer_cost_scales_with_width():
     paddle.seed(0)
-    small = layer_cost(nn.Linear(64, 64), np.zeros((32, 64), np.float32))
-    big = layer_cost(nn.Linear(64, 512), np.zeros((32, 64), np.float32))
+    small = layer_cost(nn.Linear(64, 64), np.zeros((32, 64), np.float32),
+                       device_kind=V5E)
+    big = layer_cost(nn.Linear(64, 512), np.zeros((32, 64), np.float32),
+                     device_kind=V5E)
     assert big.flops > 4 * small.flops
+
+
+def test_unlisted_device_kind_raises():
+    """No default roofline: the CPU host these tests run on is not in the
+    peaks table, so an estimate that names no target must fail."""
+    with pytest.raises(ValueError, match="device_kind"):
+        estimate_cost(lambda a: a + 1, np.zeros((8,), np.float32))
+    with pytest.raises(ValueError, match="device_kind"):
+        CostModel()
 
 
 def test_profile_measure_program():
@@ -46,7 +63,7 @@ def test_profile_measure_program():
         net = nn.Linear(128, 256)
         y = net(x)
         z = nn.functional.relu(y)
-    cm = CostModel()
+    cm = CostModel(device_kind=V5E)
     costs = cm.profile_measure(prog)
     assert len(costs) == prog.num_ops()
     # the linear dominates: 2*64*128*256 flops
@@ -83,6 +100,7 @@ def test_pipeline_layer_cost_segmentation():
     pl = PipelineLayer(
         descs, num_stages=2, seg_method="cost",
         seg_sample_input=np.zeros((32, 64), np.float32),
+        seg_device_kind=V5E,
     )
     assert pl.seg_cost_us is not None and len(pl.seg_cost_us) == 4
     # the heavy first layer gets its own stage; uniform would split 2/2

@@ -212,7 +212,7 @@ def test_flash_attention_pallas_interpret():
     """Run the actual Pallas kernel in interpret mode on CPU."""
     import os
 
-    os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = "1"
+    os.environ["PADDLE_TPU_FORCE_PALLAS_INTERPRET"] = "1"
     try:
         from paddle_tpu.ops.pallas.flash_attention import (
             _attention_xla,
@@ -225,7 +225,7 @@ def test_flash_attention_pallas_interpret():
         ref = _attention_xla(jnp.asarray(q), jnp.asarray(q), jnp.asarray(q), causal=True)
         assert np.allclose(np.asarray(out), np.asarray(ref), atol=2e-3)
     finally:
-        del os.environ["PADDLE_TPU_PALLAS_INTERPRET"]
+        del os.environ["PADDLE_TPU_FORCE_PALLAS_INTERPRET"]
 
 
 @pytest.mark.slow
@@ -260,11 +260,11 @@ def test_flash_attention_mask_grad_matches_xla():
         mask = jnp.asarray(rs.randn(*mshape).astype(np.float32) * 0.5)
 
         def loss_pallas(m):
-            os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = "1"
+            os.environ["PADDLE_TPU_FORCE_PALLAS_INTERPRET"] = "1"
             try:
                 return flash_attention_array(qq, kk, vv, mask=m).sum()
             finally:
-                del os.environ["PADDLE_TPU_PALLAS_INTERPRET"]
+                del os.environ["PADDLE_TPU_FORCE_PALLAS_INTERPRET"]
 
         def loss_xla(m):
             return _attention_xla(qq, kk, vv, mask=m).sum()

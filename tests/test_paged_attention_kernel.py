@@ -153,11 +153,39 @@ def test_kernel_bfloat16_tolerance():
         assert err < 2e-2, f"row {i}: bf16 max err {err}"
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("width", [1, 5, 128])
+def test_kernel_lowers_for_tpu(width, quant):
+    """Cross-lower (no chip needed) the compiled kernel for TPU at the
+    serving shapes. JAX's Pallas TPU lowering refuses a block whose last
+    two dims are neither (8, 128)-divisible nor the whole array — which is
+    what the int8 scale sidecars' (1, 1, 1) blocks were — before Mosaic
+    ever sees the kernel, so a refusal of that kind shows up here."""
+    import jax
+
+    B, H, D, bs, nk, NB = 8, 16, 128, 16, 128, 64
+    f32, i32 = jnp.float32, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    arena = sds((2, H, NB, bs, D), jnp.int8 if quant else jnp.bfloat16)
+    args = [sds((B, width, H, D), jnp.bfloat16), arena, arena,
+            sds((B, nk), i32), sds((B,), i32), sds((B,), i32),
+            sds((B,), i32)]
+    if quant:
+        args += [sds((2, H, NB), f32)] * 2
+
+    def f(q, k, v, tables, q_start, kv_live, q_lens, *scales):
+        kw = dict(zip(("k_scale", "v_scale"), scales))
+        return ragged_paged_attention(q, k, v, 1, tables, q_start, kv_live,
+                                      q_lens=q_lens, interpret=False, **kw)
+
+    lowered = jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+
+
 def test_backend_gate_env_overrides(monkeypatch):
     """DISABLE beats FORCE beats platform; FORCE turns on interpret mode."""
     monkeypatch.delenv("PADDLE_TPU_DISABLE_PALLAS", raising=False)
     monkeypatch.delenv("PADDLE_TPU_FORCE_PALLAS_INTERPRET", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
     assert use_pallas() is False  # CPU backend, no opt-in
     assert interpret_mode() is False
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS_INTERPRET", "1")
@@ -165,11 +193,6 @@ def test_backend_gate_env_overrides(monkeypatch):
     assert interpret_mode() is True
     monkeypatch.setenv("PADDLE_TPU_DISABLE_PALLAS", "1")
     assert use_pallas() is False  # DISABLE wins
-    monkeypatch.delenv("PADDLE_TPU_DISABLE_PALLAS")
-    monkeypatch.delenv("PADDLE_TPU_FORCE_PALLAS_INTERPRET")
-    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    assert use_pallas() is True  # legacy knob still opts in
-    assert interpret_mode() is True
 
 
 def test_flash_attention_shares_backend_gate():

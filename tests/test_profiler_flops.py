@@ -56,8 +56,7 @@ def test_bench_mfu_unchanged_to_4_decimals():
     tokens_per_sec = 82400.0  # the r03 number
     fpt = _pre_lift_flops_per_token(1024, 12, 1024, 32768,
                                     cfg.intermediate_size)
-    for kind, peak in (("TPU v5e", 197e12), ("TPU v4", 275e12),
-                       ("unknown accelerator", 197e12)):
+    for kind, peak in (("TPU v5e", 197e12), ("TPU v4", 275e12)):
         want = round(tokens_per_sec * fpt / peak, 4)
         got = round(flops.mfu(tokens_per_sec,
                               flops.gpt_train_flops_per_token(cfg),
@@ -73,11 +72,16 @@ def test_peak_flops_registry_matches_pre_lift():
         "TPU v5p": 459e12,        # longest-key-wins: v5p beats v5
         "TPU v6e": 918e12,
         "TPU v6 lite": 918e12,
-        "anything else": 197e12,  # conservative default
     }
     for kind, want in pre_lift.items():
         assert flops.peak_flops(_Dev(kind)) == want
         assert flops.peak_flops(kind) == want      # plain strings work too
+    # a device the table does not list is an error, not a v5e
+    for unknown in ("anything else", _Dev("cpu")):
+        with pytest.raises(ValueError, match="device_kind"):
+            flops.peak_flops(unknown)
+        with pytest.raises(ValueError, match="device_kind"):
+            flops.hbm_bytes_per_s(unknown)
 
 
 def test_resnet50_flops_matches_pre_lift():

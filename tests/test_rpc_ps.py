@@ -129,7 +129,7 @@ def test_ps_runtime_deployment():
 
     port = _free_port()
     env = dict(os.environ)
-    env["PADDLE_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     script = os.path.join(os.path.dirname(__file__), "_ps_runtime_worker.py")
     procs = [
         subprocess.Popen(
