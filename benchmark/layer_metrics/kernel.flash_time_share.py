@@ -1,0 +1,7 @@
+"""Device time in the three flash-attention kernels over the time in which
+the device ran anything, traced window, averaged over the chips."""
+from benchmark.reduce import kernels
+
+
+def read(run):
+    return kernels.time_share(run, "flash")
