@@ -195,6 +195,23 @@ def _q_tile(S):
     return 8 if S > 8 and S % 8 == 0 else S
 
 
+def ragged_grid_steps(q_lens, kv_live, width, max_blocks):
+    """`(live, total)` grid steps of one launch of the ragged kernel, a
+    head a layer: what the engine's step span reports as ``grid_live`` /
+    ``grid_total``. Host-side arithmetic over the numpy vectors the step
+    is built from, kept beside the grid it counts (`_build_ragged`: grid
+    ``(B, H, nq, nk)``, a step live where ``qb < qb_live[i]`` and ``j <
+    kv_live[i]``) so that a change to the one changes the other.
+
+    q_lens, kv_live: ``[B]``, one entry a lane. A lane whose ``q_lens``
+    is 0 carries no request and counts as dead: the kernel does walk one
+    step there, over the null block, but it is no useful work."""
+    qt = _q_tile(width)
+    q_tiles = -(-q_lens // qt)
+    live = int(np.sum(q_tiles * np.where(q_lens > 0, kv_live, 0)))
+    return live, len(q_lens) * (width // qt) * max_blocks
+
+
 @functools.lru_cache(maxsize=None)
 def _build_ragged(B, H, sq, d, bs, nk, layer, dtype_name, interpret,
                   quant=False):
@@ -251,6 +268,7 @@ def _build_ragged(B, H, sq, d, bs, nk, layer, dtype_name, interpret,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, sq, d), jnp.dtype(dtype_name)),
         interpret=interpret,
+        name="ragged_paged_attention",
     )
 
 

@@ -43,9 +43,18 @@ def _capture_paths(logdir_or_file):
     return find_xplane_files(logdir_or_file)
 
 
+# an HLO instruction's name, alone or in front of its text: `fusion.123`,
+# `%copy.31.remat2 = bf16[...] copy(...)`
+_HLO_NAME = re.compile(r"%?([A-Za-z_][\w-]*)(?:\.\w+)*(?: = .*)?$", re.S)
+
+
 def _category(op_name):
-    base = re.sub(r"[.\d]+ =.*", "", op_name).strip("%")
-    return re.sub(r"\.\d+$", "", base)
+    """An operation's name up to its first dot (`fusion.123`,
+    `%copy.31.remat2 = ...` and `ragged_paged_attention.7` fold to
+    `fusion`, `copy` and `ragged_paged_attention`), so a kernel or the
+    copies are one row; a host event that is no HLO name stays whole."""
+    m = _HLO_NAME.match(op_name)
+    return m.group(1) if m else op_name
 
 
 def summarize(logdir_or_file, device_only=True, top=30):

@@ -119,6 +119,7 @@ from collections import namedtuple
 import numpy as np
 
 from ..core.functional import functional_call, state_dict_arrays
+from ..ops.pallas.paged_attention import ragged_grid_steps
 from . import faults
 from .block_pool import (BlockPool, PagedState, blocks_for,
                          chain_block_hashes)
@@ -627,6 +628,7 @@ class LLMEngine:
         self._requests = {}
         self._step_fns = {}
         self._phases = {}   # current step's {phase: (t0, t1)} when tracing
+        self._span_args = {}  # and what its span says of grid and hand-over
         self._retrace_warned = False
         # stamped by AsyncLLMEngine.start(): while that thread is alive,
         # stepping from any OTHER thread would race the arena donation
@@ -1511,10 +1513,14 @@ class LLMEngine:
         return jax.profiler.TraceAnnotation(
             self.tracer.step_annotation(step_id))
 
-    def _run_step(self, fn, a, last_idx, spec_lens, step_id=0):
+    def _run_step(self, fn, a, last_idx, spec_lens, step_id=0, t_disp=0.0):
         """Dispatch the unified step program; returns the DEVICE packed
         array (the caller's single np.asarray on it is the step's ONE
-        host sync)."""
+        host sync). While tracing, the step span's ``h2d_arrays`` is the
+        number of host arrays handed over here and ``h2d_ms`` the time
+        from ``t_disp`` (the start of the ``dispatch`` phase) to just
+        before the program is launched: the hand-overs and the key
+        split, so ``dispatch - h2d_ms`` is the launch."""
         import jax
         import jax.numpy as jnp
 
@@ -1524,17 +1530,17 @@ class LLMEngine:
         mid = ()
         if pool.quantized:
             arenas += (pool.k_scale, pool.v_scale)
-            mid = (jnp.asarray(a["touched"]), jnp.asarray(a["touch_idx"]))
-        args = (
-            self._params, self._buffers, *arenas, self._lora_tables,
-            jnp.asarray(a["ids"]), jnp.asarray(a["tables"]),
-            jnp.asarray(a["slots"]), jnp.asarray(a["offs"]),
-            jnp.asarray(a["qpos"]), jnp.asarray(a["q_start"]),
-            jnp.asarray(a["kv_live"]), *mid,
-            jnp.asarray(a["adapter_slots"]), jnp.asarray(last_idx),
-            jnp.asarray(spec_lens), jnp.asarray(a["temps"]),
-            jnp.asarray(a["top_ks"]), jnp.asarray(a["top_ps"]), sub,
-        )
+            mid = (a["touched"], a["touch_idx"])
+        # every host array of the step, in the program's argument order
+        host = (a["ids"], a["tables"], a["slots"], a["offs"], a["qpos"],
+                a["q_start"], a["kv_live"], *mid, a["adapter_slots"],
+                last_idx, spec_lens, a["temps"], a["top_ks"], a["top_ps"])
+        args = (self._params, self._buffers, *arenas, self._lora_tables,
+                *map(jnp.asarray, host), sub)
+        if self.tracer is not None:
+            self._span_args.update(
+                h2d_arrays=len(host),
+                h2d_ms=(time.monotonic() - t_disp) * 1e3)
         with self._annotation(step_id):
             if pool.quantized:
                 (packed, pool.k, pool.v,
@@ -1674,6 +1680,7 @@ class LLMEngine:
         step_id = tr.next_step_id() if tr is not None else 0
         if tr is not None:
             self._phases = {"plan": (t_plan0, time.monotonic())}
+            self._span_args = {}
         t_step0 = time.monotonic()
         with self.metrics.timed(f"{kind}_step"):
             outs = self._run_rows(rows, W, step_id)
@@ -1683,13 +1690,13 @@ class LLMEngine:
             tr.record_step(step_id, kind, self._phases, {
                 "rows": len(rows),
                 "width": W,
-                "host_syncs": 1,
                 "decode_rows": sum(1 for r in rows
                                    if r.count == 1 and not r.draft),
                 "prefill_rows": sum(1 for r in rows if r.count > 1),
                 "spec_lanes": sum(1 for r in rows if r.draft),
                 "fed_tokens": sum(r.count + len(r.draft) for r in rows),
                 "emitted_tokens": len(outs),
+                **self._span_args,
             })
         self.metrics.inc(f"{kind}_steps")
         self.metrics.set_gauge(
@@ -1859,9 +1866,17 @@ class LLMEngine:
             self._fill_row(a, i, req, start, count + k, W)
         fn = self._get_step_fn(self.max_batch, W)
         K = self._draft_capacity(W)
+        if tr is not None:
+            # what the ragged kernel walks for this step, a head a layer:
+            # lanes past the planned rows carry no request (q_len 0)
+            q_lens = np.where(np.arange(self.max_batch) < len(rows),
+                              last_idx + 1 + spec_lens, 0)
+            live, total = ragged_grid_steps(q_lens, a["kv_live"], W,
+                                            self.max_blocks)
+            self._span_args.update(grid_live=live, grid_total=total)
         t_disp = time.monotonic() if tr is not None else 0.0
         packed_dev = self._run_step(fn, a, last_idx, spec_lens,
-                                    step_id=step_id)
+                                    step_id=step_id, t_disp=t_disp)
         t_sync = time.monotonic() if tr is not None else 0.0
         # THE host sync: one packed [B, K+3] transfer carries the emitted
         # runs, accept lengths, and row-finite flags for the whole step

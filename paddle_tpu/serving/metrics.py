@@ -105,6 +105,7 @@ _HELP = {
     "decode_step": "Decode-step wall time",
     "verify_step": "Verify-step wall time",
     "ttft": "Request arrival to first emitted token",
+    "queue_wait": "Request arrival to its first admission into a lane",
     "tokens_in_flight": "Tokens held by running sequences",
     "num_running": "Sequences in the running batch",
     "num_waiting": "Requests waiting for a lane",

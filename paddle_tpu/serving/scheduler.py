@@ -540,6 +540,11 @@ class Scheduler:
         now = time.monotonic()
         if req.admit_time is None:
             req.admit_time = now   # queue wait = first admission only
+            if self.metrics is not None:
+                # same clock and origin as the engine's `ttft`, so the
+                # two subtract: ttft - queue_wait is prefill
+                self.metrics.observe("queue_wait", now - req.arrival_time,
+                                     interval=False)
         if self.slo is not None:
             # compute phase opens at admission: prefill while >1 token
             # is pending (fresh prompts AND post-preemption replays),

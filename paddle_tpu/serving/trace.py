@@ -19,8 +19,10 @@ exactly those two views as Chrome/Perfetto trace events:
   assembly), ``dispatch`` (device program launch), ``sync`` (host sync on
   the sampled tokens), ``emit`` (token emission), tagged with the batch
   composition (decode rows, prefill chunks, spec lanes), program kind
-  (mixed/decode/verify), and token counts. Pool evictions land as
-  instants on a ``block-pool`` track.
+  (mixed/decode/verify), token counts, the ragged kernel's live and
+  total grid steps (``grid_live``/``grid_total``) and the hand-over to
+  the device inside ``dispatch`` (``h2d_arrays``/``h2d_ms``). Pool
+  evictions land as instants on a ``block-pool`` track.
 
 The ring buffer, clocks, export, and the xplane join annotation are the
 shared recorder in `paddle_tpu.profiler.tracing` (`Tracer`), which the

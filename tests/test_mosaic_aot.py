@@ -5,10 +5,17 @@ computes and `lowering_platforms=("tpu",)` what Pallas will lower; only this
 shows what Mosaic refuses (tilings, layouts, casts, VMEM). It compiles and
 never runs, so numerics on the chip stay `chip_smoke.py`'s to check.
 
+It also shows what the device plane will call each kernel: the TPU compiler
+names a Pallas custom call after the last scope in front of it, so the
+compiled text holds `%ragged_paged_attention(.N)`, `%flash_fwd`,
+`%flash_bwd_dkv`, `%flash_bwd_dq`, which is what a reader of a device capture
+matches (`benchmark/layer_metrics/kernel.*`).
+
 Runs in a subprocess: the topology client is libtpu's, and nothing it does
 should be able to take the test process down with it. Skips when libtpu
 cannot describe the topology here.
 """
+import json
 import os
 import subprocess
 import sys
@@ -18,7 +25,10 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _SCRIPT = r'''
+import collections
+import json
 import os
+import re
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
 os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
@@ -40,10 +50,25 @@ def sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one)
 
 
-def compile_for_v5e(fn, *args):
-    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+NAMES = {}     # case -> {custom call's instruction name, no .N: how many}
+
+
+def kernel_names(compiled):
+    names = collections.Counter()
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", line).group(1)
+            names[re.sub(r"\.\d+$", "", name)] += 1
+    return dict(names)
+
+
+def compile_for_v5e(fn, *args, case=None):
+    jitted = fn if hasattr(fn, "trace") else jax.jit(fn)
+    lowered = jitted.trace(*args).lower(lowering_platforms=("tpu",))
     n = lowered.as_text().count("tpu_custom_call")
-    lowered.compile()          # the TPU compiler; raises on a refusal
+    compiled = lowered.compile()   # the TPU compiler; raises on a refusal
+    if case is not None:
+        NAMES[case] = kernel_names(compiled)
     return n
 
 
@@ -66,7 +91,8 @@ for quant in (False, True):
                 q, k, v, 1, tables, q_start, kv_live, q_lens=q_lens,
                 interpret=False, **kw)
 
-        assert compile_for_v5e(ragged, *args) == 1, (quant, width)
+        case = f"ragged-{'int8' if quant else 'bf16'}-w{width}"
+        assert compile_for_v5e(ragged, *args, case=case) == 1, case
 
 # the flash kernels at the training head sizes (the dispatch asks the
 # default backend, which is the CPU here, so the gate is opened by hand)
@@ -78,18 +104,82 @@ for shape in ((2, 1024, 12, 64), (1, 2048, 16, 128)):
         o = fa.flash_attention_array(q, k, v, causal=True)
         return o.astype(jnp.float32).sum()
 
-    n = compile_for_v5e(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    n = compile_for_v5e(grad, x, x, x, case=f"flash-grad-d{shape[3]}")
     assert n == 3, (shape, n)          # forward, dK/dV, dQ
+# rematerialised, as a train step with `remat` calls it: other transforms
+# stand around the kernels, and their names must not move
+compile_for_v5e(jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2)), x, x, x,
+                case="flash-grad-remat")
+
+# the engine's own step programs at a tiny depth: one ragged call a layer
+# under the engine's `jit(step)`, and no Pallas call under another name
+import paddle_tpu as paddle
+from paddle_tpu.models.gpt import GPT, GPTConfig
+from paddle_tpu.ops.pallas import paged_attention as pa
+from paddle_tpu.serving import LLMEngine
+
+pa.use_pallas = lambda: True
+pa.interpret_mode = lambda: False
+paddle.seed(0)
+model = GPT(GPTConfig(vocab_size=256, hidden_size=256, num_layers=2,
+                      num_heads=2, max_seq_len=256, dropout=0.0))
+engine = LLMEngine(model, block_size=16, max_batch=4, max_seq_len=256,
+                   prefill_chunk=32)
+like = lambda a: sds(a.shape, a.dtype)
+h = lambda shape, dt="int32": sds(shape, dt)
+B = engine.max_batch
+for W in engine.width_buckets:
+    compile_for_v5e(
+        engine._get_step_fn(B, W),
+        {k: like(v) for k, v in engine._params.items()},
+        {k: like(v) for k, v in engine._buffers.items()},
+        like(engine.pool.k), like(engine.pool.v), engine._lora_tables,
+        h((B, W)), h((B, engine.max_blocks)), h((B, W)), h((B, W)),
+        h((B, W)), h((B,)), h((B,)), h((B,)), h((B,)), h((B,)),
+        h((B,), "float32"), h((B,)), h((B,), "float32"), like(engine._key),
+        case=f"engine-step-w{W}")
+print("KERNEL_NAMES " + json.dumps(NAMES))
 print("MOSAIC_OK")
 '''
 
+FLASH = {"flash_fwd": 1, "flash_bwd_dkv": 1, "flash_bwd_dq": 1}
+# case -> the Pallas custom calls its compiled text must hold, by name
+EXPECTED = {
+    **{f"ragged-{dt}-w{w}": {"ragged_paged_attention": 1}
+       for dt in ("bf16", "int8") for w in (1, 5, 128)},
+    "flash-grad-d64": FLASH,
+    "flash-grad-d128": FLASH,
+    "flash-grad-remat": FLASH,
+    # gpt of two layers; LLMEngine's default width buckets
+    "engine-step-w1": {"ragged_paged_attention": 2},
+    "engine-step-w32": {"ragged_paged_attention": 2},
+}
 
-def test_attention_kernels_compile_for_v5e():
+
+@pytest.fixture(scope="module")
+def aot():
+    """The script's output, run once for the module."""
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                           capture_output=True, text=True, timeout=600)
     if "NO_TOPOLOGY" in proc.stdout:
         pytest.skip("libtpu cannot describe a v5e topology here: "
                     + proc.stdout.strip().splitlines()[-1][:200])
-    assert proc.returncode == 0 and "MOSAIC_OK" in proc.stdout, (
-        proc.stdout[-2000:] + proc.stderr[-4000:])
+    return proc
+
+
+def test_attention_kernels_compile_for_v5e(aot):
+    assert aot.returncode == 0 and "MOSAIC_OK" in aot.stdout, (
+        aot.stdout[-2000:] + aot.stderr[-4000:])
+
+
+@pytest.mark.parametrize("case", sorted(EXPECTED))
+def test_compiled_text_names_each_kernel(aot, case):
+    """One name a kernel, whatever the width, the arena's dtype or the
+    transforms around the call, and no Pallas call under another name."""
+    line = next((ln for ln in aot.stdout.splitlines()
+                 if ln.startswith("KERNEL_NAMES ")), None)
+    assert line is not None, aot.stdout[-2000:] + aot.stderr[-4000:]
+    names = json.loads(line[len("KERNEL_NAMES "):])
+    assert names[case] == EXPECTED[case]

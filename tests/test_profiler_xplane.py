@@ -355,3 +355,27 @@ def test_join_on_real_traced_serve():
         # the annotation wraps only the dispatch, so it can never exceed
         # the full host step span by more than measurement jitter
         assert r["host_dur_us"] > 0
+
+
+@pytest.mark.parametrize("name,folded", [
+    ("fusion.123", "fusion"),
+    ("copy.31.remat2", "copy"),
+    ("ragged_paged_attention.7", "ragged_paged_attention"),
+    # a v5e capture names an event by the operation's whole HLO text
+    ("%copy.315.remat2 = bf16[24,16,2048,16,128]{4,3,2,1,0} "
+     "copy(bf16[24,16,2048,16,128]{4,1,3,2,0} %get-tuple-element.3)", "copy"),
+    ("%flash_bwd_dkv.5 = (bf16[96,2048,128], bf16[96,2048,128]) "
+     "custom-call(%p.1), custom_call_target=\"tpu_custom_call\"",
+     "flash_bwd_dkv"),
+    ("all-reduce-start.3", "all-reduce-start"),
+    ("convolution_add_fusion", "convolution_add_fusion"),
+    # a host plane's events are no HLO names and stay whole
+    ("$core.py:123 bind", "$core.py:123 bind"),
+    ("PjitFunction(step)", "PjitFunction(step)"),
+])
+def test_category_folds_an_operation_at_its_first_dot(name, folded):
+    """One row a kernel and one for the copies in `print_summary`, as
+    `benchmark/reduce/intervals.py category` folds them for the ledger."""
+    from paddle_tpu.profiler.xplane import _category
+
+    assert _category(name) == folded

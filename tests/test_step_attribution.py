@@ -1,0 +1,172 @@
+"""What a step span and the latency summary say of where a step's time and
+the kernel's grid went (serving/engine.py `_run_rows`, `_run_step`;
+scheduler.py `_admit`; ops/pallas/paged_attention.py `ragged_grid_steps`):
+
+- ``grid_live`` / ``grid_total``: the ragged kernel's live and total grid
+  steps a head a layer, against a brute-force walk of the kernel's own live
+  condition;
+- ``h2d_arrays`` / ``h2d_ms``: the host arrays handed to the device and
+  what the hand-over took, inside the ``dispatch`` phase;
+- ``queue_wait``: arrival to first admission, observed like ``ttft``, with
+  tracing on or off.
+"""
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.models.gpt import GPT, GPTConfig
+from paddle_tpu.ops.pallas.paged_attention import _q_tile, ragged_grid_steps
+from paddle_tpu.serving import LLMEngine
+
+PHASES = ("plan", "build", "dispatch", "sync", "emit")
+
+
+def _walk(q_lens, kv_live, width, max_blocks):
+    """The kernel's grid `(B, nq, nk)` of one head, step by step, with its
+    own live test (`_ragged_kernel`: `qb < qb_live[i]` and `j < kv_live[i]`,
+    the wrapper's `qb_live = ceil(max(q_len, 1) / qt)`), counted over the
+    rows that carry a request."""
+    qt = _q_tile(width)
+    nq = width // qt
+    live = total = 0
+    for q_len, kl in zip(q_lens, kv_live):
+        qb_live = (max(q_len, 1) + qt - 1) // qt
+        for qb in range(nq):
+            for j in range(max_blocks):
+                total += 1
+                live += bool(q_len > 0 and qb < qb_live and j < kl)
+    return live, total
+
+
+# (q_lens, kv_live, width, max_blocks); q_len 0 = a lane with no request,
+# whose kv_live the engine leaves at 1 (the null block)
+GRID_CASES = {
+    "decode-w1": ([1, 1, 1, 1], [3, 16, 1, 7], 1, 16),
+    "decode-w1-empty-lanes": ([1, 0, 1, 0], [5, 1, 2, 1], 1, 8),
+    "verify-w5": ([5, 3, 1, 0], [2, 4, 8, 1], 5, 8),
+    "chunk-w128-full": ([128, 128], [8, 12], 128, 16),
+    "chunk-w128-ragged": ([128, 37, 9, 8], [16, 5, 1, 3], 128, 16),
+    "decode-row-rides-w128": ([128, 1, 1, 0], [9, 4, 16, 1], 128, 16),
+    "all-lanes-empty": ([0, 0], [1, 1], 1, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GRID_CASES))
+def test_ragged_grid_steps_counts_the_kernels_live_steps(case):
+    q_lens, kv_live, width, max_blocks = GRID_CASES[case]
+    got = ragged_grid_steps(np.array(q_lens, np.int32),
+                            np.array(kv_live, np.int32), width, max_blocks)
+    assert got == _walk(q_lens, kv_live, width, max_blocks)
+    assert all(type(x) is int for x in got)     # a span arg: plain JSON
+    assert 0 <= got[0] <= got[1]
+
+
+@pytest.fixture(scope="module")
+def model():
+    paddle.seed(0)
+    cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2, num_heads=2,
+                    max_seq_len=64, attn_impl="xla", dropout=0.0)
+    m = GPT(cfg)
+    m.eval()
+    return m
+
+
+def _prompts(lengths, seed=0):
+    rs = np.random.RandomState(seed)
+    return [rs.randint(0, 128, (n,)).tolist() for n in lengths]
+
+
+def _engine(model, **kw):
+    kw.setdefault("block_size", 8)
+    kw.setdefault("max_batch", 2)
+    kw.setdefault("max_seq_len", 64)
+    return LLMEngine(model, **kw)
+
+
+def _steps(engine):
+    """`[(step span, {phase: span})]` of the engine's trace, in order."""
+    evs = [e for e in engine.tracer.chrome_trace()["traceEvents"]
+           if e["ph"] == "X" and e["pid"] == 1 and e["tid"] == 0]
+    phases = {}
+    for e in evs:
+        if e["name"] in PHASES:
+            phases.setdefault(e["args"]["step"], {})[e["name"]] = e
+    return [(e, phases[e["args"]["step"]]) for e in evs
+            if e["name"].startswith("step[")]
+
+
+@pytest.mark.parametrize("kv_dtype,arrays", [(None, 13), ("int8", 15)],
+                         ids=["bf16-arena", "int8-arena"])
+def test_every_step_span_says_grid_and_handover(model, kv_dtype, arrays):
+    """Mixed, decode and (with speculation) verify steps alike."""
+    engine = _engine(model, prefill_chunk=8, trace=1.0, kv_dtype=kv_dtype,
+                     spec_decoding=True)
+    # a repeated pattern, so the n-gram drafter proposes and verify steps run
+    prompts = _prompts((20, 7), seed=1) + [[5, 6, 7] * 6]
+    engine.generate(prompts, max_new_tokens=6)
+    steps = _steps(engine)
+    assert {s["args"]["kind"] for s, _ in steps} >= {"mixed", "decode"}
+    per_step = engine.max_batch * engine.max_blocks
+    for span, phases in steps:
+        a = span["args"]
+        assert "host_syncs" not in a
+        nq = a["width"] // _q_tile(a["width"])
+        assert a["grid_total"] == per_step * nq
+        # every planned row walks at least its own first block
+        assert a["rows"] <= a["grid_live"] <= a["grid_total"]
+        assert a["h2d_arrays"] == arrays
+        # ts/dur are rounded to 3 decimals of a microsecond
+        assert 0.0 <= a["h2d_ms"] <= phases["dispatch"]["dur"] / 1e3 + 1e-5
+    assert engine.metrics.counters["host_syncs"] == len(steps)
+
+
+def test_grid_live_follows_the_rows_contexts(model):
+    """A decode step's live grid is the rows' KV blocks: one query tile a
+    row, so it grows by one each time a row crosses a block boundary."""
+    engine = _engine(model, trace=1.0, max_batch=2)
+    engine.generate(_prompts((7, 15), seed=3), max_new_tokens=10)
+    decode = [s["args"] for s, _ in _steps(engine)
+              if s["args"]["kind"] == "decode" and s["args"]["rows"] == 2]
+    assert len(decode) >= 8
+    # step n feeds positions 7+n and 15+n (block_size 8): blocks walked
+    first = decode[0]["grid_live"]
+    assert first == (7 // 8 + 1) + (15 // 8 + 1)
+    lives = [a["grid_live"] for a in decode]
+    assert lives == sorted(lives) and lives[-1] > first
+
+
+@pytest.mark.parametrize("trace", [None, 1.0], ids=["untraced", "traced"])
+def test_queue_wait_is_observed_once_a_request_admitted(model, trace):
+    engine = _engine(model, max_batch=2, trace=trace)
+    engine.generate(_prompts((9, 14, 6, 11, 5), seed=7), max_new_tokens=4)
+    lat = engine.metrics.latency_summary()
+    assert lat["queue_wait"]["count"] == 5 == lat["ttft"]["count"]
+    # admission comes before the first token, request by request
+    assert lat["queue_wait"]["total_ms"] <= lat["ttft"]["total_ms"]
+    assert "queue_wait" in engine.metrics.snapshot()["latency"]
+    text = engine.metrics.prometheus_text()
+    assert "# HELP paddle_tpu_serving_queue_wait_seconds Request arrival " \
+        "to its first admission into a lane" in text
+    assert "paddle_tpu_serving_queue_wait_seconds_count 5" in text
+
+
+def test_queue_wait_counts_first_admissions_only(model):
+    """A preempted request is admitted twice and waited in the queue once."""
+    engine = _engine(model, max_batch=2, num_blocks=7, prefix_cache=False)
+    engine.generate(_prompts((14, 15), seed=4), max_new_tokens=12)
+    assert engine.metrics.counters.get("preemptions", 0) >= 1
+    assert engine.metrics.latency_summary()["queue_wait"]["count"] == 2
+
+
+def test_a_request_behind_a_full_batch_waits_out_the_steps_before_it(model):
+    engine = _engine(model, max_batch=1, trace=1.0)
+    engine.generate(_prompts((9, 6), seed=5), max_new_tokens=3)
+    lat = engine.metrics.latency_summary()["queue_wait"]
+    assert lat["count"] == 2
+    # the second request arrived before the first step and got the lane
+    # only after the first one's last: it waited out every step in between
+    steps = _steps(engine)
+    first_request = [s for s, _ in steps][:3]      # prefill + 2 decodes
+    waited_ms = sum(s["dur"] for s in first_request) / 1e3
+    assert lat["max_ms"] >= waited_ms
+    assert lat["max_ms"] >= max(s["dur"] for s in first_request) / 1e3
