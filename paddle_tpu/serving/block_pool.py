@@ -6,7 +6,8 @@ Attention"): K/V live in ONE fixed-shape, head-major arena
 owns a list of block ids. Head-major is the Pallas-friendly layout: each
 (layer, head, block) slice is a contiguous ``[block_size, head_dim]`` tile
 the ragged kernel DMAs straight from HBM (ops/pallas/paged_attention.py).
-Appending tokens is a fixed-shape ``.at[...].set`` scatter; attention runs
+Appending tokens is a fixed-shape ``.at[...].set`` scatter of ``head_dim``
+rows (`_append`: the arena keeps the kernel's layout); attention runs
 through `paged_attention`, which dispatches to the ragged Pallas kernel on
 TPU and to an XLA gather of the padded ``[rows, max_blocks]`` block table
 everywhere else. Because every device op has a static shape, the whole
@@ -182,8 +183,49 @@ class PagedState:
         )
 
 
+def _append(arena, layer, new, slots, offs, mesh):
+    """Write the step's tokens `new` [B, S, H, D] to their homes
+    ``arena[layer, h, slots, offs, :]``: THE append of the step programs,
+    float and int8 alike.
+
+    The head is an INDEX of the scatter, not part of its window: each
+    update is one ``head_dim`` row at a ``(head, block, offset)``, which is
+    how the ragged kernel addresses the arena too. With a ``[heads,
+    head_dim]`` window the TPU compiler lays the arena out heads-minor for
+    the scatter and row-major for the Pallas call that follows, and relays
+    the whole arena between the two in every layer (77 % of a decode
+    step's device time on a v5e; PERF.md section 6, PR 25); with the
+    ``[head_dim]`` window the scatter runs on a bitcast of the
+    row-major arena, which then keeps one layout from the program's
+    parameter to its donated output.
+
+    Padded and inactive tokens carry slot 0 (the null block); their rows
+    collide there, which is what the null block is for. Under a tp `mesh`
+    the write runs per shard over the head axis (the specs of
+    `ragged_paged_attention_sharded`): each chip scatters its own heads
+    with the replicated block ids, and GSPMD has no index array to gather.
+    """
+    import jax.numpy as jnp
+
+    def write(arena, new, slots, offs):
+        heads = jnp.arange(arena.shape[1], dtype=jnp.int32)
+        return arena.at[layer, heads, slots[..., None], offs[..., None]].set(
+            new.astype(arena.dtype))
+
+    if mesh is not None and mesh.shape.get("tp", 1) > 1:
+        from jax.sharding import PartitionSpec as P
+
+        from ..parallel._compat import shard_map
+
+        write = shard_map(
+            write, mesh=mesh,
+            in_specs=(P(None, "tp"), P(None, None, "tp", None), P(), P()),
+            out_specs=P(None, "tp"))
+    return write(arena, new, slots, offs)
+
+
 def _quantize_scatter(arena, scales, layer, new, slots, offs, touched,
-                      touch_idx):
+                      touch_idx, mesh):
     """Int8 arena append with per-(layer, head, block) scale growth.
 
     `new` [B, S, H, D] f32 tokens land in blocks `slots`/`offs`; every
@@ -233,8 +275,7 @@ def _quantize_scatter(arena, scales, layer, new, slots, offs, touched,
     tok_sc = new_sc.T[gidx].reshape(B, S, H)                # [B, S, H]
     qn = jnp.clip(jnp.round(new.astype(jnp.float32) / tok_sc[..., None]),
                   -127, 127)
-    arena = arena.at[layer, :, slots, offs].set(qn.astype(arena.dtype))
-    return arena, scales
+    return _append(arena, layer, qn, slots, offs, mesh), scales
 
 
 def paged_attention(q, k_new, v_new, view, scale=None):
@@ -250,8 +291,8 @@ def paged_attention(q, k_new, v_new, view, scale=None):
     st, layer = view.state, view.layer
     if st.mesh is not None:
         # tensor-parallel serving: pin the step's new K/V (and q) to the
-        # head sharding BEFORE the scatter, so GSPMD writes each chip's
-        # own head slab of the arena instead of inventing a gather
+        # head sharding BEFORE the append, so each chip quantizes and
+        # writes its own head slab of the arena and GSPMD invents no gather
         q = st.constrain(q, None, None, "tp", None)
         k_new = st.constrain(k_new, None, None, "tp", None)
         v_new = st.constrain(v_new, None, None, "tp", None)
@@ -261,20 +302,13 @@ def paged_attention(q, k_new, v_new, view, scale=None):
         # XLA fallback's einsum — ops/pallas/paged_attention.py)
         st.k, st.k_scale = _quantize_scatter(
             st.k, st.k_scale, layer, k_new, st.slots, st.offs,
-            st.touched, st.touch_idx)
+            st.touched, st.touch_idx, st.mesh)
         st.v, st.v_scale = _quantize_scatter(
             st.v, st.v_scale, layer, v_new, st.slots, st.offs,
-            st.touched, st.touch_idx)
+            st.touched, st.touch_idx, st.mesh)
     else:
-        # scatter the step's K/V rows into their (block, offset) homes;
-        # padded and inactive rows carry slot 0 (the null block). The
-        # advanced indices (layer, slots, offs) are separated by the
-        # head-axis slice, so the indexed view is [B, S, heads, head_dim]
-        # — k_new's own layout.
-        st.k = st.k.at[layer, :, st.slots, st.offs].set(
-            k_new.astype(st.k.dtype))
-        st.v = st.v.at[layer, :, st.slots, st.offs].set(
-            v_new.astype(st.v.dtype))
+        st.k = _append(st.k, layer, k_new, st.slots, st.offs, st.mesh)
+        st.v = _append(st.v, layer, v_new, st.slots, st.offs, st.mesh)
     return paged_attention_arrays(
         q, st.k, st.v, layer, st.block_tables, st.qpos,
         q_start=st.q_start, kv_live=st.kv_live, q_lens=st.q_lens,

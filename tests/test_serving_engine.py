@@ -306,6 +306,58 @@ def test_block_pool_alloc_free_copy():
         pool.free([0])
 
 
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("kind", ["width1", "chunk", "int8-chunk"])
+def test_append_writes_what_the_windowed_scatter_wrote(kind, tp):
+    """`block_pool._append` (the head an index of the scatter) against the
+    expression it replaced (the head in the scatter's window), written out
+    here: the same values at the same addresses. Width 1 with an idle lane,
+    and a chunk that crosses a block boundary beside a shorter, padded row;
+    the int8 case feeds the quantized token write's integers. Under tp=2
+    the helper runs per shard on a 2-device mesh. The null block is
+    scratch (padded tokens collide there, in no stated order), so it is
+    compared nowhere."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving import build_serving_mesh
+    from paddle_tpu.serving.block_pool import _append
+
+    pool = BlockPool(num_blocks=8, num_layers=3, block_size=4, num_heads=4,
+                     head_dim=8)
+    if kind == "width1":
+        # (blocks, first position, live tokens) a row; a lane without a
+        # request feeds nothing
+        rows, width = [([3], 2, 1), ([5, 1], 7, 1), ([], 0, 0)], 1
+    else:
+        rows, width = [([2, 6, 4], 2, 6), ([7], 0, 3)], 6
+    slots, offs = map(np.stack, zip(*(
+        pool.positions_to_slots(blocks or [0], start, count, width)
+        for blocks, start, count in rows)))
+    assert (slots == 0).any() and (slots != 0).any()
+    dtype = jnp.int8 if kind.startswith("int8") else jnp.float32
+    rs = np.random.RandomState(0)
+    draw = lambda shape: jnp.asarray(  # noqa: E731
+        rs.randint(-127, 128, shape), dtype)
+    arena = draw(pool.k.shape)
+    new = draw((len(rows), width, 4, 8))
+    layer = 1
+    want = arena.at[layer, :, slots, offs].set(new)
+
+    mesh = None
+    if tp > 1:
+        smesh = build_serving_mesh(tp)
+        mesh = smesh.mesh
+        arena = jax.device_put(arena, smesh.arena_sharding())
+    got = jax.jit(_append, static_argnums=(1, 5))(
+        arena, layer, new, jnp.asarray(slots), jnp.asarray(offs), mesh)
+    assert got.dtype == want.dtype
+    assert np.array_equal(np.asarray(got)[:, :, 1:],
+                          np.asarray(want)[:, :, 1:])
+    # the other layers whole, their null blocks too
+    assert np.array_equal(np.asarray(got)[[0, 2]], np.asarray(arena)[[0, 2]])
+
+
 def test_scheduler_fcfs_mixed_rows_and_token_budget():
     """One mixed plan per step: FCFS lane admission, decode rows always
     ride, prefill chunks split under the per-step token budget."""
