@@ -11,6 +11,13 @@ requests, the same for the same seed. A new mix is a new data file.
                      one list. open: arrivals on a schedule fixed by the seed,
                      `poisson` at `rate_rps`, or `onoff` (bursts: `rate_rps`
                      for `on_s` seconds, silence for `off_s`)
+    lengths_seed     optional. The lengths (prompt, output, prefix) are then
+                     drawn from it and are the same for every `seed`, which
+                     still draws the token ids and the arrivals: every run
+                     of the mix is the same amount of work
+    start            optional, a closed loop's: `"stationary"` cuts the
+                     outputs of the first `clients` requests as if the job
+                     had run for a while (`stationary_output`)
 """
 from __future__ import annotations
 
@@ -53,19 +60,37 @@ def _arrivals(rng, loop):
     raise ValueError(f"unknown arrivals {loop['arrivals']!r}")
 
 
+def stationary_output(output, i, clients):
+    """What is left of the `i`-th first request's `output` tokens in a job
+    caught mid-way: its clients' requests are evenly spread over their
+    lengths, so completions (and the admissions that replace them) come from
+    the start at the steady rate, not all after the shortest answer."""
+    return max(1, round(output * (i + 0.5) / clients))
+
+
 def stream(mix, seed, vocab_size):
     """Yield `{"prompt": [ids], "max_tokens": n, "due_s": t or None,
     "shared_tokens": k}` without end. `shared_tokens` is the length of the
     part an earlier request of the stream already sent."""
-    lengths = np.random.default_rng([seed, 1])
+    lengths = np.random.default_rng([mix.get("lengths_seed", seed), 1])
     tokens = np.random.default_rng([seed, 2])
     due = _arrivals(np.random.default_rng([seed, 3]), mix["loop"])
     ids = lambda n: tokens.integers(0, vocab_size, n).tolist()  # noqa: E731
 
+    start = mix.get("start")
+    if start not in (None, "stationary"):
+        raise ValueError(f"unknown start {start!r}")
+    clients = int(mix["loop"]["clients"]) if start else 0
+    sent = 0
+
     def one(prefix, first_use):
-        return {"prompt": prefix + ids(draw(lengths, mix["prompt"])),
-                "max_tokens": draw(lengths, mix["output"]),
-                "due_s": next(due),
+        nonlocal sent
+        prompt = prefix + ids(draw(lengths, mix["prompt"]))
+        output = draw(lengths, mix["output"])
+        if sent < clients:
+            output = stationary_output(output, sent, clients)
+        sent += 1
+        return {"prompt": prompt, "max_tokens": output, "due_s": next(due),
                 "shared_tokens": 0 if first_use else len(prefix)}
 
     share = mix.get("prefix")

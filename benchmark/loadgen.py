@@ -54,6 +54,7 @@ class LoadGen:
         self.requests = requests
         self.loop = loop
         self.samples = []
+        self.tokens_read = 0      # by every client, since the load started
         self.started = None
         self._tasks = set()
         self._stopping = False
@@ -140,6 +141,7 @@ class LoadGen:
                 for tok in choice["token_ids"]:
                     s.tokens.append(tok)
                     s.token_times.append(now)
+                    self.tokens_read += 1
                 s.finish = choice["finish_reason"] or s.finish
         except (ConnectionError, asyncio.IncompleteReadError, OSError) as e:
             s.error = f"{type(e).__name__}: {e}"

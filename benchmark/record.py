@@ -19,6 +19,8 @@ class Run:
         self.memory_peak_bytes = None
         self.attempted = self.failed = 0
         self.correct = False
+        self.compared = {}              # what `correct` compared: name ->
+                                        # {"value", "limit"} (correct.py)
         self.notes = []                 # lines for the log, not the result
         # the traced part of the window (a traced run only)
         self.capture = None             # reduce.xplane.Capture
@@ -28,6 +30,8 @@ class Run:
         # end-to-end metrics are of the window; per-layer readers may look at
         # the whole load where the window alone holds too little, and say so
         self.load_start = None
+        self.tokens_short = None        # a window that closes on a token
+                                        # count: tokens missing at the cap
         self.samples = None             # loadgen.Sample, every request sent
         self.counters = None            # the program's counters, window only
         self.load_counters = None       # the same, since the load started

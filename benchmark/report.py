@@ -53,4 +53,7 @@ def result(run, log):
             log(f"  device op {s:9.4f}s x{n:<6d} {name[:140]}")
     for note in run.notes:
         log(note)
+    # what `correct` compared, each number beside its limit: the last key of
+    # the line and (`run.py`) the last lines on standard error
+    line["compared"] = run.compared
     return line

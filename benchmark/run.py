@@ -5,8 +5,10 @@
 from the root of a checkout, on a machine that holds the chips the cell asks
 for. The last line of standard output is one JSON object with the keys
 `correct`, `attempted`, `failed`, `metrics`, `device` (and `breakdown` in a
-traced run): the cell's end-to-end metrics with `--trace 0`, its per-layer
-metrics with `--trace 1`. Everything else goes to earlier lines. No chip, no
+traced run) and, last, `compared`: the cell's end-to-end metrics with
+`--trace 0`, its per-layer metrics with `--trace 1`, and each number that
+decided `correct` beside its limit (also the last lines on standard error).
+Everything else goes to earlier lines. No chip, no
 result: the exit code is then not 0 and there is no such line.
 
 `--platform cpu` is the rehearsal: the same code at the tiny sizes the data
@@ -46,6 +48,12 @@ def parse(argv):
     p.add_argument("--trace", type=int, choices=(0, 1), required=True)
     p.add_argument("--platform", choices=("tpu", "cpu"), default="tpu",
                    help="cpu: the rehearsal, at tiny sizes")
+    p.add_argument("--control", type=int, choices=(0, 1), default=0,
+                   help="1: the control, which has to come out as not "
+                   "correct: after the window the reference is also put in "
+                   "the program's place in the precisions the configuration "
+                   "lists under `control` (correct.py). By hand and in "
+                   "tests; no run of a check")
     return p.parse_args(argv)
 
 
@@ -77,6 +85,10 @@ def main(argv=None):
     runner = spec.resolve(f"benchmark.runners.{config['kind']}.run")
     runner(run, log)
     line = report.result(run, log)
+    for name, c in line["compared"].items():
+        print(f"compared {name}: {c['value']} limit {c['limit']}",
+              file=sys.stderr)
+    print(f"correct: {line['correct']}", file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -13,8 +13,8 @@ from ..reduce.stats import median
 from ..tracing import DeviceTracer
 
 POLL_S = 0.01
-GRACE_S = 0.05      # reading goes on this long after the window, so that a
-                    # burst its end cut in two is read whole
+GRACE_S = 0.05      # reading goes on this long after the ramp and after the
+                    # window, so that a burst cut in two is read whole
 
 
 def build_engine(config, seed, **extra):
@@ -42,7 +42,11 @@ def run(run, log):
     asyncio.run(_drive(run, engine, requests, log))
     run.memory_peak_bytes = device.memory_peak_bytes()
     params = {k: p._array for k, p in model.named_parameters_dict().items()}
-    run.correct = correct.serving(config, params, run.samples, args.seed, log)
+    # the program's state (the arena) is freed before the reference runs
+    del engine, model
+    run.correct, run.compared = correct.serving(
+        config, params, run.samples, args.seed, log,
+        control=config["control"] if args.control else ())
 
 
 async def _drive(run, engine, requests, log):
@@ -64,13 +68,22 @@ async def _drive(run, engine, requests, log):
         before = dict(metrics.counters)
         run.compile_setup = run.clock.snapshot()
         run.t0 = time.monotonic()
-        t_end = run.t0 + args.seconds
-        if tracer is not None:
-            trace_s = min(run.config["trace_seconds"], args.seconds)
-            await asyncio.sleep(max(0.0, t_end - trace_s - time.monotonic()))
-            await loop.run_in_executor(None, tracer.start_profiler)
-            tracer.mark_start()
-        await asyncio.sleep(max(0.0, t_end - time.monotonic()))
+        # a closed loop's window holds a fixed amount of work where the mix
+        # says how much: it closes when the clients have read that many
+        # tokens, and `--seconds` is the cap. Else it is a window of time
+        want = (mix.get("window") or {}).get("tokens")
+        base = load.tokens_read
+        cap = run.t0 + args.seconds
+        trace_s = min(run.config["trace_seconds"], args.seconds)
+        while True:
+            now, read = time.monotonic(), load.tokens_read - base
+            if tracer is not None and tracer.due(now, run.t0, cap, trace_s,
+                                                 read, want):
+                await loop.run_in_executor(None, tracer.start_profiler)
+                tracer.mark_start()
+            if now >= cap or (want is not None and read >= want):
+                break
+            await asyncio.sleep(min(POLL_S, max(0.0, cap - now)))
         run.t1 = time.monotonic()
         after = dict(metrics.counters)
         run.compile_window = run.clock.snapshot()
@@ -79,6 +92,9 @@ async def _drive(run, engine, requests, log):
         if tracer is not None:
             tracer.stop()
         await asyncio.sleep(GRACE_S)
+        if want is not None:
+            run.t1, run.tokens_short = client.close_on_tokens(
+                load.samples, run.t0, want, cap)
     finally:
         await load.stop()
         await server.shutdown(drain=False)
@@ -102,6 +118,19 @@ async def _drive(run, engine, requests, log):
     log(f"window: {len(gaps)} token gaps, median "
         f"{(median(gaps) or 0) * 1e3:.1f} ms, largest "
         f"{max(gaps, default=0) * 1e3:.1f} ms")
+    if want is not None:
+        log(f"window closes on {want} tokens: {run.tokens_short} short at "
+            f"the cap of {args.seconds:g}s")
+        # a closed loop's next request is admitted by the first plan after
+        # it arrives: how long after the burst that ended its predecessor
+        # it left says how near a step's edge that is
+        bursts = sorted(t for s in load.samples for t in s.token_times)
+        left = [(s.sent - max((t for t in bursts if t <= s.sent),
+                              default=s.sent)) * 1e3 for s in sent]
+        if left:
+            log(f"turn-round: the window's {len(left)} requests left "
+                f"{min(left):.1f}-{max(left):.1f} ms after the last token "
+                "read")
     log(f"window {run.seconds:.2f}s: {len(seen)} requests seen, "
         f"{len(sent)} sent in it, {run.failed} failed, "
         f"{sum(s.ended is not None and run.in_window(s.ended) for s in seen)}"
@@ -126,5 +155,8 @@ async def _ramp(load, ramp, log):
             raise RuntimeError(f"ramp: request {bad[0].index} failed: "
                                f"HTTP {bad[0].status} {bad[0].error}")
         await asyncio.sleep(POLL_S)
+    # the burst that brought the last of them is read whole before the window
+    # opens, so that none of its tokens counts as the window's
+    await asyncio.sleep(GRACE_S)
     log(f"ramp: {want} first tokens after {time.monotonic() - t0:.2f}s, "
         f"{len(load.samples)} requests sent")

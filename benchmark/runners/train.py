@@ -87,10 +87,11 @@ def run(run, log):
             loss, params, buffers, opt_state = step(
                 params, buffers, opt_state, lr, jax.random.PRNGKey(i), ids,
                 labels)
+            t_sent = time.monotonic()
             loss = float(np.asarray(loss))   # the step ends here
         return host, {"index": i, "input_wait": t_start - t_in,
-                      "start": t_start, "end": time.monotonic(),
-                      "loss": loss}
+                      "start": t_start, "sent": t_sent,
+                      "end": time.monotonic(), "loss": loss}
 
     quiet = lambda name: contextlib.nullcontext()   # noqa: E731
     try:
@@ -107,12 +108,17 @@ def run(run, log):
         run.compile_setup = run.clock.snapshot()
         run.t0 = time.monotonic()
         t_end = run.t0 + args.seconds
+        # where the mix says how many steps a window holds it is a fixed
+        # piece of work, and `--seconds` is the cap; else a window of time
+        want = (mix.get("window") or {}).get("steps")
+        trace_s = min(config["trace_seconds"], args.seconds)
         annotate = quiet
         i = 1 + tr["warm_steps"]
-        while time.monotonic() < t_end:
-            if tracer is not None and tracer.t0 is None and \
-                    time.monotonic() >= t_end - min(config["trace_seconds"],
-                                                    args.seconds):
+        while time.monotonic() < t_end and (want is None
+                                            or len(steps) < want):
+            if tracer is not None and tracer.due(
+                    time.monotonic(), run.t0, t_end, trace_s, len(steps),
+                    want):
                 tracer.start_profiler()
                 tracer.mark_start()
                 annotate = jax.profiler.TraceAnnotation
@@ -134,9 +140,19 @@ def run(run, log):
     losses = [s["loss"] for s in run.steps]
     run.attempted = len(run.steps)
     run.failed = sum(not np.isfinite(x) for x in losses)
-    log(f"window {run.seconds:.2f}s: {len(run.steps)} steps, losses "
-        f"{run.loss_first:.4f} -> {losses[-1] if losses else None}")
+    log(f"window {run.seconds:.2f}s: {len(run.steps)} steps"
+        + ("" if want is None else f" of the mix's {want}")
+        + f", losses {run.loss_first:.4f} -> "
+        f"{losses[-1] if losses else None}")
+    # a stall shows as one long step: where in it the time went
+    for s in sorted(steps, key=lambda s: s["start"] - s["end"])[:3]:
+        log(f"longest steps: step {s['index']} at "
+            f"+{s['start'] - run.t0:.1f}s took {s['end'] - s['start']:.3f}s "
+            f"(hand-over {s['sent'] - s['start']:.3f}s, waiting for the "
+            f"loss {s['end'] - s['sent']:.3f}s) after {s['input_wait']:.3f}s"
+            " of input wait")
     del params, opt_state
     initial = weights.initial_arrays(config, args.seed)
-    run.correct = bool(losses) and correct.training(
-        config, initial, first_batch, run.loss_first, losses, log)
+    if losses:
+        run.correct, run.compared = correct.training(
+            config, initial, first_batch, run.loss_first, losses, log)

@@ -112,6 +112,9 @@ def main():
                    type=lambda s: [float(x) for x in s.split(",")])
     p.add_argument("--seconds", type=float, default=30.0)
     p.add_argument("--seed", type=int, default=1)
+    p.add_argument("--lengths-seed", type=int, default=None,
+                   help="lay this `lengths_seed` over the mix's: a mix that "
+                   "fixes its lengths, tried by hand on lengths it never saw")
     p.add_argument("--out", default=None, help="also write the table here")
     p.add_argument("--platform", choices=("tpu", "cpu"), default="tpu",
                    help="cpu: the rehearsal, at tiny sizes")
@@ -128,6 +131,8 @@ def main():
         device.rehearse_on_cpu(config["chips"])
         config = spec.merged(config, config["rehearsal"])
         mix = spec.merged(mix, mix["rehearsal"])
+    if args.lengths_seed is not None:
+        mix = spec.merged(mix, {"lengths_seed": args.lengths_seed})
     found = device.describe()
     why = device.refusal(found, args.platform, config["chips"])
     if why is not None:

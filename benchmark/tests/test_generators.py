@@ -32,12 +32,14 @@ def test_same_seed_same_requests_other_seed_other_requests():
 def test_lengths_stay_inside_the_mix_and_the_model():
     for name in ("rollout", "docqa", "chat"):
         mix, reqs = _take(name, 3, 400)
-        for r in reqs:
+        # a stationary start cuts the first `clients` outputs, down to 1
+        cut = mix["loop"]["clients"] if mix.get("start") else 0
+        for i, r in enumerate(reqs):
             own = len(r["prompt"]) - r["shared_tokens"] \
                 if mix.get("prefix") else len(r["prompt"])
             assert own >= mix["prompt"]["min"]
-            assert mix["output"]["min"] <= r["max_tokens"] \
-                <= mix["output"]["max"]
+            assert (1 if i < cut else mix["output"]["min"]) \
+                <= r["max_tokens"] <= mix["output"]["max"]
             assert len(r["prompt"]) + r["max_tokens"] <= 2048
 
 

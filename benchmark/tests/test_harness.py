@@ -9,7 +9,8 @@ import sys
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
-CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+                 "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -35,15 +36,36 @@ def test_no_chip_no_result():
 
 
 def test_last_line_holds_the_contracts_keys_and_no_other():
-    line = _last_line(_run(
-        REPO, "--workload", "c1p3b-serve.rollout", "--seed", "1",
-        "--seconds", "4", "--trace", "0"))
+    proc = _run(REPO, "--workload", "c1p3b-serve.rollout", "--seed", "1",
+                "--seconds", "20", "--trace", "0")
+    line = _last_line(proc)
     assert set(line) == CONTRACT_KEYS
+    # each number compared beside its limit: the line's last key, and the
+    # last lines on standard error
+    assert list(line)[-1] == "compared"
+    deficit = line["compared"]["logit_deficit"]
+    assert 0 <= deficit["value"] <= deficit["limit"] == 0.12
+    said = proc.stderr.strip().splitlines()
+    assert said[-1] == "correct: True"
+    assert said[-2] == (f"compared logit_deficit: {deficit['value']} "
+                        "limit 0.12")
     assert set(line["device"]) == DEVICE_KEYS
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 4
     # a CPU run reports no timing under a metric's name
     assert line["metrics"] == {}
+
+
+def test_a_training_window_holds_the_steps_its_mix_states():
+    line = _last_line(_run(
+        REPO, "--workload", "c590m-train.pretrain", "--seed", "2",
+        "--seconds", "30", "--trace", "0"))
+    steps = json.loads((REPO / "benchmark/traffic/packed-2k.json")
+                       .read_text())["rehearsal"]["window"]["steps"]
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] == steps
+    assert list(line["compared"]) == ["first_loss_gap", "losses_not_finite",
+                                      "last_loss"]
 
 
 def test_a_new_configuration_mix_cell_and_reader_are_new_files(tmp_path):
@@ -64,7 +86,7 @@ def test_a_new_configuration_mix_cell_and_reader_are_new_files(tmp_path):
         json.dumps(config))
     mix = json.loads((REPO / "benchmark/traffic/rollout.json").read_text())
     mix.update(name="throwaway")
-    mix["rehearsal"]["output"] = {"median": 10, "min": 8, "max": 12}
+    mix["rehearsal"]["output"] = {"median": 20, "min": 16, "max": 24}
     (tmp_path / "benchmark/traffic/throwaway.json").write_text(
         json.dumps(mix))
     (tmp_path / "benchmark/layer_metrics/throwaway.finished.py").write_text(
@@ -91,7 +113,7 @@ def test_a_new_configuration_mix_cell_and_reader_are_new_files(tmp_path):
 
     line = _last_line(_run(
         tmp_path, "--workload", "throwaway-serve.throwaway", "--seed", "3",
-        "--seconds", "4", "--trace", "1"))
+        "--seconds", "20", "--trace", "1"))
     assert line["correct"] is True
     assert line["metrics"]["throwaway.finished"]["value"] >= 1
     after = {p: p.read_bytes() for p in before}

@@ -18,6 +18,17 @@ class DeviceTracer:
         self.t0 = self.t1 = None
         self._mark = None
 
+    def due(self, now, t0, cap, trace_s, done=0, want=None):
+        """Whether to start now: the traced part is the window's last
+        `trace_s`, by the cap, or (a window that closes on `want` units of
+        work, `done` of them so far) by the work still due at the pace so
+        far."""
+        if self.t0 is not None:
+            return False
+        return now >= cap - trace_s or bool(
+            want is not None and done
+            and (want - done) * (now - t0) <= trace_s * done)
+
     def start_profiler(self):
         """The slow part of starting (it may run in a worker thread)."""
         import jax
