@@ -8,34 +8,42 @@ prefill-chunk rows (up to `prefill_chunk` tokens) — which is what lets the
 engine run chunked prefill and decode in a single XLA program.
 
 Kernel design (TPU):
-- Grid ``(rows, heads, q_blocks, kv_blocks)`` with the KV-block dimension
-  innermost. The block index map reads the row's block table through
-  scalar prefetch (SMEM), so each grid step DMAs exactly ONE live KV
-  block ``[block_size, head_dim]`` from the arena in HBM — the padded
-  tail of the block table is never fetched: dead iterations clamp the
-  index map to the last live block (Mosaic elides the re-fetch of an
-  unchanged block) and `pl.when` skips their compute. This is the whole
-  point vs. the XLA fallback below, which gathers the full padded
-  ``[rows, max_blocks]`` table every layer.
+- The arenas stay where they are (HBM, unblocked). The grid is what is
+  not ragged: ``(rows, query tiles)``, every head a step. The walk over
+  a row's KV pages is a loop INSIDE the kernel whose trip count is the
+  row's live pages (scalar prefetch, SMEM) over the pages a fetch
+  brings: each iteration copies a group of pages named by the row's
+  block table, one strided ``[heads, block_size, head_dim]`` copy a page,
+  into one of two VMEM buffers while the other is multiplied, and a
+  tile's last iteration starts the next grid step's first fetch. A call
+  costs its live work: the padded tail of a block table is neither
+  fetched nor stepped over.
+  This is the whole point vs. the XLA fallback below, which gathers the
+  full padded ``[rows, max_blocks]`` table every layer.
 - Query lengths are ragged PER ROW (``q_lens``): the query axis is tiled
-  and each row declares how many tiles are live, so a decode row (1 live
-  token) riding a wide mixed/verify-width program computes one query
-  tile while a full prefill chunk in the same launch walks them all —
-  dead q blocks clamp their index map (no DMA) and skip compute exactly
-  like dead KV iterations. This is what lets ONE program shape serve
-  decode, prefill-chunk, and speculative-verify rows (the unified
-  ragged step program in serving/engine.py).
-- Online-softmax state (m, l, acc) lives in VMEM scratch across the KV
+  and a tile past a row's live tokens runs no iteration and writes zeros,
+  so a decode row (1 live token) riding a wide mixed/verify-width program
+  computes one query tile while a full prefill chunk in the same launch
+  walks them all. A lane that carries no request runs nothing. This is
+  what lets ONE program shape serve decode, prefill-chunk, and
+  speculative-verify rows (the unified ragged step program in
+  serving/engine.py).
+- The layer is a scalar the kernel reads, so one built kernel (and one
+  Mosaic body in a step program) serves every layer.
+- Online-softmax state (m, l, acc) lives in VMEM scratch across the
   iterations, exactly like flash_attention.py; fp32 accumulation on the MXU.
 - Causal masking is positional: query positions are ``q_start[row] + iota``
-  (chunk tokens are consecutive), key positions ``block * block_size +
-  iota``; ``qpos >= kpos`` also discards the garbage tail of a partially
-  filled last block.
+  (chunk tokens are consecutive), key positions follow from a page's place
+  in the table; ``qpos >= kpos`` also discards the garbage tail of a
+  partially filled last block and the slots of a last fetch past it.
 - Head-major arena so each (head, block) tile is a 2-D ``(block_size,
-  head_dim)`` VMEM block: Mosaic requires the minor two dims of a block to
-  be (8, 128)-divisible or equal to the array dims, which a head axis in
-  second-to-minor position would violate (same constraint that shapes
-  flash_attention.py's [B*H, S, D] layout).
+  head_dim)`` slab whose minor two dims a copy takes whole: Mosaic tiles
+  the minor two dims of an array by (8, 128) (x2 rows for bf16, x4 for
+  int8), which a head axis in second-to-minor position would cut across
+  (same constraint that shapes flash_attention.py's [B*H, S, D] layout).
+  For the same reason a head size that is no multiple of 128 stays on the
+  XLA path when compiled: Mosaic pads such an arena's minor dimension in
+  HBM and then refuses to slice it.
 
 The dispatch (`paged_attention_arrays`) is the seam `serving/block_pool.py`
 calls after scattering the step's new K/V into the arena: Pallas on TPU (or
@@ -105,168 +113,233 @@ def paged_attention_xla(q, k_arena, v_arena, layer, block_tables, qpos,
 # Pallas ragged kernel
 # ---------------------------------------------------------------------------
 
-def _ragged_kernel(bt_ref, qs_ref, kl_ref, qb_ref, q_ref, k_ref, v_ref,
-                   *rest, bs, qt, scale, quant):
-    """One (row, head, q-block) tile's online-softmax walk over its live
-    KV blocks.
+# Chosen on the chip (PERF.md section 6, PR 31): the keys one loop iteration
+# fetches (whole pages of them) and the widest query tile. A grid step
+# serves every head: fewer, larger copies beat more steps at every size.
+_KV_FETCH_TOKENS = 512
+_Q_TILE = 128
+# Two slots of 512 keys of K and of V across 16 heads of 128 are 8 MiB, half
+# of what Mosaic lets a kernel have unasked; more heads need more.
+_VMEM_LIMIT_BYTES = 64 << 20
 
-    bt_ref/qs_ref/kl_ref/qb_ref are the scalar-prefetched block tables,
-    per-row query start positions, per-row live KV block counts, and
-    per-row live QUERY block counts (SMEM). The q-block grid dimension is
-    what makes query length ragged PER ROW: a decode row (1 live query
-    token) riding a wide mixed/verify program computes only its first
-    ``qt``-wide query tile — dead q blocks re-address the last live tile
-    (no DMA) and skip all compute, exactly like the dead KV iterations.
 
-    ``quant`` (int8 arena): two extra f32 scale refs carry this (row,
-    head)'s per-block scales as one ``[1, nk]`` lane row in block-table
-    order; step j picks lane j and each DMA'd int8 tile dequantizes IN
-    VMEM (one multiply per tile) before the MXU dot — the arena walk
-    moves a quarter of the f32 bytes and the compute path is unchanged."""
-    from jax.experimental import pallas as pl
+def _pages_per_fetch(bs):
+    return max(1, _KV_FETCH_TOKENS // bs)
 
-    if quant:
-        ksc_ref, vsc_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ksc_ref = vsc_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
 
-    i = pl.program_id(0)   # batch row
-    qb = pl.program_id(2)  # query block
-    j = pl.program_id(3)   # kv block step (innermost)
-    q_live = qb < qb_ref[i]
-
-    @pl.when(q_live & (j == 0))
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(q_live & (j < kl_ref[i]))
-    def _():
-        q = q_ref[0, 0]        # [qt, D]
-        kt = k_ref[0, 0, 0]    # [bs, D]
-        if quant:
-            lane = jax.lax.broadcasted_iota(
-                jnp.int32, ksc_ref.shape[2:], 1) == j      # [1, nk]
-            kt = kt.astype(jnp.float32) * jnp.sum(
-                jnp.where(lane, ksc_ref[0, 0], 0.0), axis=1, keepdims=True)
-        s = jax.lax.dot_general(
-            q, kt, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        # chunk query positions are consecutive from q_start; key positions
-        # follow from the block index. qpos >= kpos is both the causal mask
-        # and the guard over a partially filled last block's stale tail.
-        qp = (qs_ref[i] + qb * qt
-              + jax.lax.broadcasted_iota(jnp.int32, (qt, bs), 0))
-        kp = j * bs + jax.lax.broadcasted_iota(jnp.int32, (qt, bs), 1)
-        s = jnp.where(qp >= kp, s, _NEG_INF)
-        m_prev = m_ref[:]
-        l_prev = l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        vt = v_ref[0, 0, 0]    # [bs, D]
-        if quant:
-            vt = vt.astype(jnp.float32) * jnp.sum(
-                jnp.where(lane, vsc_ref[0, 0], 0.0), axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = m_new
-
-    @pl.when(q_live & (j == kl_ref[i] - 1))
-    def _():
-        o_ref[0, 0] = (
-            acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
-        ).astype(o_ref.dtype)
+def _padded(nk, bs):
+    """`nk` table slots rounded up to whole fetches."""
+    pages = _pages_per_fetch(bs)
+    return -(-nk // pages) * pages
 
 
 def _q_tile(S):
-    """Query-tile width: the whole width for narrow programs, 8-wide
-    sublane-aligned tiles when the width divides (fp32 Mosaic tiling —
-    minor-two dims of a block must be (8, 128)-divisible or equal to the
-    array dims). A width that is neither <= 8 nor 8-divisible keeps one
-    full-width tile (per-row raggedness then costs nothing extra: it
-    degrades to the pre-ragged single-tile layout)."""
-    return 8 if S > 8 and S % 8 == 0 else S
+    """Query-tile width: the whole width for narrow programs, `_Q_TILE`
+    where it divides a wider one (Mosaic tiling: the minor two dims of a
+    block are (8, 128)-divisible or equal to the array's). A width that
+    is neither keeps one full-width tile."""
+    return _Q_TILE if S > _Q_TILE and S % _Q_TILE == 0 else S
 
 
-def ragged_grid_steps(q_lens, kv_live, width, max_blocks):
-    """`(live, total)` grid steps of one launch of the ragged kernel, a
-    head a layer: what the engine's step span reports as ``grid_live`` /
-    ``grid_total``. Host-side arithmetic over the numpy vectors the step
-    is built from, kept beside the grid it counts (`_build_ragged`: grid
-    ``(B, H, nq, nk)``, a step live where ``qb < qb_live[i]`` and ``j <
-    kv_live[i]``) so that a change to the one changes the other.
+def _ragged_kernel(layer_ref, bt_ref, qs_ref, kl_ref, ql_ref, q_ref,
+                   k_hbm, v_hbm, *rest, bs, qt, pages, scale, quant):
+    """One (row, query tile)'s online-softmax walk over the row's live KV
+    pages, every head: a loop whose trip count is the row's live page
+    groups, `pages` pages a fetch, double-buffered.
 
-    q_lens, kv_live: ``[B]``, one entry a lane. A lane whose ``q_lens``
-    is 0 carries no request and counts as dead: the kernel does walk one
-    step there, over the null block, but it is no useful work."""
-    qt = _q_tile(width)
-    q_tiles = -(-q_lens // qt)
-    live = int(np.sum(q_tiles * np.where(q_lens > 0, kv_live, 0)))
-    return live, len(q_lens) * (width // qt) * max_blocks
+    layer_ref/bt_ref/qs_ref/kl_ref/ql_ref are scalar-prefetched (SMEM):
+    the layer, the block tables, and per row the first query position,
+    the live KV pages and the live query tokens. `k_hbm`/`v_hbm` are the
+    whole arenas, left where they are: iteration j copies pages
+    ``bt[i, j*pages : (j+1)*pages]`` across heads (one strided
+    ``[H, bs, D]`` copy a page) into one slot of the VMEM buffers while
+    the other is multiplied, and a tile's last iteration starts the first
+    fetch of the grid step that follows (`state` hands its slot over),
+    so only a launch's first fetch is waited for in full. Slots past the
+    row's last live page re-fetch that page (finite values, masked by
+    position). A query tile past the row's live tokens, or a lane with
+    none, runs no iteration and writes zeros (`kv_live` 0 says the same
+    of a lane).
 
-
-@functools.lru_cache(maxsize=None)
-def _build_ragged(B, H, sq, d, bs, nk, layer, dtype_name, interpret,
-                  quant=False):
+    ``quant`` (int8 arena): two f32 refs carry the row's scales a KEY
+    (``[1, H, max_blocks * bs]``, block-table order), applied along the
+    key axis of the scores and of the probabilities, so the int8 tiles
+    go to the MXU as they are."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    scale = 1.0 / np.sqrt(d)
+    if quant:
+        ksc_ref, vsc_ref, o_ref, *scratch = rest
+    else:
+        o_ref, *scratch = rest
+    kbuf, vbuf, sem, state, m_ref, l_ref, acc_ref = scratch
+
+    i = pl.program_id(0)   # batch row
+    qb = pl.program_id(1)  # query tile
+    rows, tiles = pl.num_programs(0), pl.num_programs(1)
+    H, _, _, D = kbuf.shape[1:]
+    T = pages * bs
+    layer = layer_ref[0]
+
+    def fetches(row, tile):
+        return jnp.where(tile * qt < ql_ref[row],
+                         (kl_ref[row] + pages - 1) // pages, 0)
+
+    def fetch(row, slot, j, wait=False):
+        """Start, or wait for, the copies of row `row`'s j-th page group
+        into `slot`: one copy a page and arena, a loop and not `pages`
+        copies in a row, which the interpreter would take minutes over."""
+        last = kl_ref[row] - 1
+
+        def page_copies(p, carry):
+            page = bt_ref[row, jnp.minimum(j * pages + p, last)]
+            for s, (arena, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                copy = pltpu.make_async_copy(
+                    arena.at[layer, :, page], buf.at[slot, :, p],
+                    sem.at[s, slot])
+                if wait:
+                    copy.wait()
+                else:
+                    copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, pages, page_copies, None)
+
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    n_fetch = fetches(i, qb)
+    # state: whether the grid step before this one started this one's
+    # first fetch, and into which slot
+    started = jnp.where((i == 0) & (qb == 0), 0, state[0])
+    slot0 = jnp.where(started == 1, state[1], 0)
+    state[0] = 0
+
+    @pl.when((n_fetch > 0) & (started == 0))
+    def _():
+        fetch(i, 0, 0)
+
+    # chunk query positions are consecutive from q_start; key positions
+    # follow from the page's place in the table. qpos >= kpos is both the
+    # causal mask and the guard over the last page's stale tail and over
+    # the slots past it.
+    qp = (qs_ref[i] + qb * qt
+          + jax.lax.broadcasted_iota(jnp.int32, (qt, T), 0))
+    kp0 = jax.lax.broadcasted_iota(jnp.int32, (qt, T), 1)
+
+    def body(j, carry):
+        slot = (slot0 + j) % 2
+
+        @pl.when(j + 1 < n_fetch)
+        def _():
+            fetch(i, 1 - slot, j + 1)
+
+        @pl.when(j + 1 == n_fetch)
+        def _():
+            last = qb + 1 == tiles
+            row = jnp.where(last, i + 1, i)
+            row_c = jnp.minimum(row, rows - 1)
+            tile = jnp.where(last, 0, qb + 1)
+
+            @pl.when((row < rows) & (fetches(row_c, tile) > 0))
+            def _():
+                fetch(row_c, 1 - slot, 0)
+                state[0] = 1
+                state[1] = 1 - slot
+
+        fetch(i, slot, j, wait=True)
+        live = qp >= j * T + kp0
+        for h in range(H):
+            q = q_ref[0, h]                                   # [qt, D]
+            kt = kbuf[slot, h].astype(q.dtype).reshape(T, D)
+            s = jax.lax.dot_general(
+                q, kt, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if quant:
+                keys = pl.ds(pl.multiple_of(j * T, T), T)
+                s = s * ksc_ref[0, pl.ds(h, 1), keys]
+            s = jnp.where(live, s, _NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            if quant:
+                p = p * vsc_ref[0, pl.ds(h, 1), keys]
+            vt = vbuf[slot, h].astype(q.dtype).reshape(T, D)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_fetch, body, None)
+    o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+
+
+def ragged_grid_steps(q_lens, kv_live, width, block_size):
+    """`(live, total)` steps of one launch of the ragged kernel, a layer:
+    what the engine's step span reports as ``grid_live`` /
+    ``grid_total``. Host-side arithmetic over the numpy vectors the step
+    is built from, kept beside the walk it counts (`_ragged_kernel`: grid
+    ``(B, nq)``, a query tile live where ``qb * qt < q_lens[i]``, and a
+    live tile runs ``ceil(kv_live[i] / pages)`` iterations) so that a
+    change to the one changes the other.
+
+    ``live`` is the loop iterations, each of which fetched a live row's
+    pages; ``total`` adds the grid steps that ran none: query tiles past
+    a row's live tokens, and every tile of a lane that carries no request
+    (``q_lens`` 0)."""
+    qt = _q_tile(width)
+    q_tiles = -(-q_lens // qt)
+    fetches = -(-kv_live // _pages_per_fetch(block_size))
+    live = int(np.sum(q_tiles * fetches))
+    return live, live + int(np.sum(width // qt - q_tiles))
+
+
+@functools.lru_cache(maxsize=None)
+def _build_ragged(B, H, sq, d, bs, nk, dtype_name, kv_dtype_name, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
     qt = _q_tile(sq)
-    nq = sq // qt
+    pages = _pages_per_fetch(bs)
+    quant = kv_dtype_name == "int8"
 
-    def q_index(i, h, qb, j, bt, qs, kl, qlb):
-        # dead q blocks re-address the row's last live tile: Mosaic
-        # elides the DMA for an unchanged index, pl.when skips compute
-        return (i, h, jnp.minimum(qb, qlb[i] - 1), 0)
-
-    def kv_index(i, h, qb, j, bt, qs, kl, qlb):
-        # dead iterations (j >= live count) re-address the last live
-        # block; dead q TILES freeze the whole KV walk there too — the
-        # index must stay UNCHANGED across their inner j steps or Mosaic
-        # re-fetches every live KV block once per dead tile (kl[i]-1 is
-        # also where the preceding live tile's walk ended, so the freeze
-        # elides the DMA across the tile boundary as well)
-        jc = jnp.where(qb < qlb[i], jnp.minimum(j, kl[i] - 1), kl[i] - 1)
-        return (layer, h, bt[i, jc], 0, 0)
-
-    def sc_index(i, h, qb, j, bt, qs, kl, qlb):
-        # the int8 scales arrive gathered per row, [B, H, 1, nk] in
-        # block-table order (see ragged_paged_attention): one whole lane
-        # row per (row, head), fetched once and unchanged across qb and j
-        return (i, h, 0, 0)
+    def tile(i, qb, *_):
+        return (i, 0, qb, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, qt, d), q_index),
-        pl.BlockSpec((1, 1, 1, bs, d), kv_index),
-        pl.BlockSpec((1, 1, 1, bs, d), kv_index),
+        pl.BlockSpec((1, H, qt, d), tile),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, 1, nk), sc_index),
-                     pl.BlockSpec((1, 1, 1, nk), sc_index)]
+        row = pl.BlockSpec((1, H, _padded(nk, bs) * bs),
+                           lambda i, qb, *_: (i, 0, 0))
+        in_specs += [row, row]
+    kv_buf = pltpu.VMEM((2, H, pages, bs, d), jnp.dtype(kv_dtype_name))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, H, nq, nk),
+        num_scalar_prefetch=5,
+        grid=(B, sq // qt),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, qt, d), q_index),
+        out_specs=pl.BlockSpec((1, H, qt, d), tile),
         scratch_shapes=[
-            pltpu.VMEM((qt, 1), jnp.float32),   # running max m
-            pltpu.VMEM((qt, 1), jnp.float32),   # running normalizer l
-            pltpu.VMEM((qt, d), jnp.float32),   # output accumulator
+            kv_buf, kv_buf,
+            pltpu.SemaphoreType.DMA((2, 2)),       # (K or V, slot)
+            pltpu.SMEM((2,), jnp.int32),           # next step's first fetch
+            pltpu.VMEM((H, qt, 1), jnp.float32),   # running max m
+            pltpu.VMEM((H, qt, 1), jnp.float32),   # running normalizer l
+            pltpu.VMEM((H, qt, d), jnp.float32),   # output accumulator
         ],
     )
     return pl.pallas_call(
-        functools.partial(_ragged_kernel, bs=bs, qt=qt, scale=scale,
-                          quant=quant),
+        functools.partial(_ragged_kernel, bs=bs, qt=qt, pages=pages,
+                          scale=1.0 / np.sqrt(d), quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, sq, d), jnp.dtype(dtype_name)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="ragged_paged_attention",
     )
@@ -275,53 +348,47 @@ def _build_ragged(B, H, sq, d, bs, nk, layer, dtype_name, interpret,
 def ragged_paged_attention(q, k_arena, v_arena, layer, block_tables,
                            q_start, kv_live, q_lens=None, interpret=False,
                            k_scale=None, v_scale=None):
-    """Pallas ragged paged attention over live KV blocks — and live
+    """Pallas ragged paged attention over live KV pages — and live
     QUERY tiles — only.
 
     q: [B, S, H, D]; arenas: [layers, H, num_blocks, bs, D];
     block_tables: [B, max_blocks]; q_start: [B] first query position per
-    row; kv_live: [B] number of live KV blocks per row (>= 1); q_lens:
-    [B] live query tokens per row (ragged widths — a decode row riding a
-    wide program declares 1 and pays one query tile; None means every
-    row is full-width). `k_scale`/`v_scale` [layers, H, num_blocks]
-    switch the kernel to int8 arenas with in-VMEM dequant. Returns
-    [B, S, H, D]. Rows/columns beyond each row's live tokens hold
-    garbage — the engine discards them.
+    row; kv_live: [B] number of live KV blocks per row; q_lens: [B] live
+    query tokens per row (ragged widths — a decode row riding a wide
+    program declares 1 and pays one query tile; None means every row is
+    full-width). A lane that carries no request declares 0 of either
+    and pays nothing. `k_scale`/`v_scale` [layers, H, num_blocks] switch
+    the kernel to int8 arenas, dequantized by the scales inside it.
+    `layer` may be traced: one built kernel serves every layer. Returns
+    [B, S, H, D]. Query tiles with no live token hold zeros; positions
+    past a row's live tokens inside a live tile hold garbage — the engine
+    discards them.
     """
     B, S, H, D = q.shape
     bs = k_arena.shape[3]
     nk = block_tables.shape[1]
-    quant = k_scale is not None
-    fn = _build_ragged(B, H, S, D, bs, nk, int(layer), str(q.dtype),
-                       bool(interpret), quant=quant)
-    qt = _q_tile(S)
+    fn = _build_ragged(B, H, S, D, bs, nk, str(q.dtype), str(k_arena.dtype),
+                       bool(interpret))
     if q_lens is None:
-        qb_live = jnp.full((B,), S // qt, jnp.int32)
-    else:
-        # live query TILES per row (>= 1: padding lanes walk one tile of
-        # the null block, like kv_live's clamp)
-        ql = jnp.maximum(q_lens.astype(jnp.int32), 1)
-        qb_live = (ql + qt - 1) // qt
-    qh = jnp.transpose(q, (0, 2, 1, 3))  # [B, H, S, D]
-    operands = (qh, k_arena, v_arena)
-    if quant:
-        # The arena-side sidecars stay [layers, H, num_blocks], but a
-        # (1, 1, 1) block over them does not lower for TPU ("the last two
-        # dimensions of your block shape [must be] divisible by 8 and 128
-        # respectively, or be equal to the respective dimensions of the
-        # overall array"). So each row's scales are gathered through its
-        # block table here, a [B, H, 1, nk] f32 array of a few KB, whose
-        # (1, nk) minor dims a block can take whole.
-        def row_scales(sc):
-            return jnp.transpose(sc[layer][:, block_tables],
-                                 (1, 0, 2))[:, :, None, :]
+        q_lens = jnp.full((B,), S, jnp.int32)
+    operands = (jnp.transpose(q, (0, 2, 1, 3)), k_arena, v_arena)  # [B,H,S,D]
+    if k_scale is not None:
+        # each row's scales gathered through its block table (padded to
+        # whole fetches) and repeated a key, [B, H, keys] f32: the kernel
+        # takes one aligned lane slice of them an iteration
+        tables = jnp.pad(block_tables, ((0, 0), (0, _padded(nk, bs) - nk)))
 
-        operands += (row_scales(k_scale), row_scales(v_scale))
+        def key_scales(sc):
+            return jnp.repeat(jnp.transpose(sc[layer][:, tables],
+                                            (1, 0, 2)), bs, axis=2)
+
+        operands += (key_scales(k_scale), key_scales(v_scale))
     o = fn(
+        jnp.asarray(layer, jnp.int32).reshape(1),
         block_tables.astype(jnp.int32),
         q_start.astype(jnp.int32),
-        jnp.maximum(kv_live.astype(jnp.int32), 1),
-        qb_live,
+        kv_live.astype(jnp.int32),
+        q_lens.astype(jnp.int32),
         *operands,
     )
     return jnp.transpose(o, (0, 2, 1, 3))
@@ -338,9 +405,9 @@ def ragged_paged_attention_sharded(q, k_arena, v_arena, layer, block_tables,
                                    k_scale=None, v_scale=None):
     """Per-shard dispatch of the single-device ragged kernel on a tp mesh.
 
-    The kernel walks one (row, head, block) grid and DMAs (head, block)
-    tiles out of the local arena — it has no concept of a mesh. Under
-    `shard_map` over the head axis each shard sees exactly its local
+    The kernel copies pages across the heads of the local arena — it has
+    no concept of a mesh. Under `shard_map` over the head axis each shard
+    sees exactly its local
     slice: q ``[B, S, H/tp, D]`` and arenas ``[layers, H/tp, blocks,
     block_size, head_dim]``, with the block table / ragged metadata
     replicated (block ids are global, shard-invariant host bookkeeping).
@@ -385,9 +452,8 @@ def ragged_paged_attention_sharded(q, k_arena, v_arena, layer, block_tables,
                   P(None, tp_axis), P(), P(), P(), P()),
         out_specs=P(None, None, tp_axis, None),
     )
-    # raw metadata passes through; ragged_paged_attention normalizes
-    # (int32 casts + the >=1 kv_live/q_lens clamps) per shard — one
-    # canonical site
+    # raw metadata passes through; ragged_paged_attention casts it per
+    # shard — one canonical site
     return fn(q, k_arena, v_arena, block_tables, q_start, kv_live, q_lens)
 
 
@@ -412,6 +478,11 @@ def paged_attention_arrays(q, k_arena, v_arena, layer, block_tables, qpos,
         why_xla = "custom softmax scale"
     elif not use_pallas():
         why_xla = "kernels off"
+    elif q.shape[-1] % 128 and not interpret_mode():
+        # Mosaic (jaxlib 0.9.0) refuses to slice an HBM array whose minor
+        # dimension it pads to the 128 lanes, which the walk's page copies
+        # do (PERF.md section 7)
+        why_xla = "head size not a multiple of 128"
     if why_xla is not None:
         # off-TPU this is the default path; on a tpu backend it is
         # counted and logged, never quiet

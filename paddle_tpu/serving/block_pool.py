@@ -113,8 +113,8 @@ class PagedState:
                     (also the model's position-embedding indices)
       q_start       [B] int32 — first live query position per row (ragged
                     kernel metadata; chunk tokens are consecutive)
-      kv_live       [B] int32 — live KV blocks per row (>= 1); the ragged
-                    kernel walks exactly this many blocks
+      kv_live       [B] int32 — live KV blocks per row (0: an idle
+                    lane); the ragged kernel walks exactly this many
       q_lens        [B] int32 — live query tokens per row (ragged widths:
                     a decode row riding a wide unified-step launch
                     declares 1 and the kernel computes one query tile;

@@ -1792,8 +1792,9 @@ class LLMEngine:
             "top_ks": np.zeros(B, np.int32),
             "top_ps": np.ones(B, np.float32),
             "q_start": np.zeros(B, np.int32),
-            # idle lanes walk just the null block
-            "kv_live": np.ones(B, np.int32),
+            # idle lanes have no live block: the ragged kernel runs no
+            # iteration there
+            "kv_live": np.zeros(B, np.int32),
             # idle/pad lanes read the all-zeros base slot 0
             "adapter_slots": np.zeros(B, np.int32),
             **({
@@ -1872,7 +1873,7 @@ class LLMEngine:
             q_lens = np.where(np.arange(self.max_batch) < len(rows),
                               last_idx + 1 + spec_lens, 0)
             live, total = ragged_grid_steps(q_lens, a["kv_live"], W,
-                                            self.max_blocks)
+                                            self.block_size)
             self._span_args.update(grid_live=live, grid_total=total)
         t_disp = time.monotonic() if tr is not None else 0.0
         packed_dev = self._run_step(fn, a, last_idx, spec_lens,

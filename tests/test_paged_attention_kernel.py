@@ -14,9 +14,13 @@ import pytest
 
 import jax.numpy as jnp
 
+from paddle_tpu.ops.pallas import paged_attention as pa
 from paddle_tpu.ops.pallas._backend import interpret_mode, use_pallas
 from paddle_tpu.ops.pallas.paged_attention import (
+    _build_ragged,
+    _pages_per_fetch,
     paged_attention_xla,
+    ragged_grid_steps,
     ragged_paged_attention,
 )
 
@@ -24,7 +28,7 @@ TOL = 1e-3  # issue acceptance: kernel matches fallback to >= 1e-3
 
 
 def _case(lengths_counts, *, block_size, num_heads=2, head_dim=16,
-          num_layers=2, layer=1, seed=0):
+          num_layers=2, layer=1, seed=0, table_padding=1):
     """Build a random arena + ragged batch. `lengths_counts` is a list of
     (total_tokens, chunk_count): each row's query chunk is the LAST `count`
     positions of its `total` tokens (count == total -> fresh prefill;
@@ -35,7 +39,8 @@ def _case(lengths_counts, *, block_size, num_heads=2, head_dim=16,
         max(1, -(-total // block_size)) for total, _ in lengths_counts
     ]
     num_blocks = 1 + sum(blocks_per)  # block 0 = null
-    max_blocks = max(blocks_per) + 1  # leave table padding to exercise
+    # leave table padding to exercise, unless the case is a full row
+    max_blocks = max(blocks_per) + table_padding
     # garbage EVERYWHERE (incl. the null block and each partially filled
     # last block's tail): correctness must come from masking, not zeros
     k = rs.randn(num_layers, num_heads, num_blocks, block_size,
@@ -122,6 +127,154 @@ def test_kernel_ragged_query_decode_in_wide_launch():
     _check_ragged_q([(9, 1), (23, 1)], pad_to=8, block_size=8)
 
 
+PAGES = _pages_per_fetch(16)    # pages one iteration of the walk fetches
+
+# rows as (total tokens, live query tokens) at block_size 16: what the walk
+# over page groups has to get right at a group's edges
+WALK_CASES = {
+    "one-page": [(5, 1)],
+    "one-less-than-a-group": [(16 * (PAGES - 1) - 3, 1)],
+    "a-whole-group": [(16 * PAGES, 1), (16 * PAGES - 15, 1)],
+    "one-more-than-a-group": [(16 * PAGES + 1, 1), (9, 1)],
+    "two-groups-and-a-page": [(16 * 2 * PAGES + 7, 3), (40, 3)],
+    "chunk-128-beside-decode-rows": [(150, 128), (16 * PAGES + 1, 1),
+                                     (33, 1)],
+    # two query tiles: the decode row's second runs nothing
+    "chunk-256-beside-a-decode-row": [(300, 256), (40, 1)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_kernel_walks_live_page_groups(case):
+    """The walk fetches `PAGES` pages an iteration: rows of one page, one
+    less than, exactly and one more than a group, and chunks of one and
+    of two query tiles beside decode rows, against the reference."""
+    rows = WALK_CASES[case]
+    width = max(c for _, c in rows)
+    _check_ragged_q(rows, pad_to=width, block_size=16)
+
+
+def test_kernel_full_row_walks_every_table_slot():
+    """`kv_live == max_blocks`: no table padding behind the last page,
+    and a last group that is not whole (the slots past the row's last
+    page re-fetch it; their positions are masked)."""
+    rows = [(16 * (PAGES + 3), 1), (16 * (PAGES + 3) - 20, 5)]
+    q, k, v, layer, tables, qpos, q_start, kv_live = _case(
+        rows, block_size=16, table_padding=0)
+    assert int(kv_live.max()) == tables.shape[1] == PAGES + 3
+    _check_ragged_q(rows, pad_to=5, block_size=16, table_padding=0)
+
+
+def test_kernel_idle_lane_writes_zeros_and_leaves_neighbours():
+    """A lane that carries no request (`q_lens` 0, or `kv_live` 0 as the
+    engine marks it) runs no iteration and writes zeros, whatever its
+    table points at; the live lanes beside it read as they do alone."""
+    rows = [(37, 1), (5, 1), (150, 1)]
+    q, k, v, layer, tables, qpos, q_start, kv_live = _case(
+        rows, block_size=16)
+    alone = np.asarray(ragged_paged_attention(
+        q, k, v, layer, tables, q_start, kv_live,
+        q_lens=jnp.asarray([1, 1, 1]), interpret=True))
+    for q_lens, kvl in (([1, 0, 1], kv_live),
+                        ([1, 1, 1], kv_live.at[1].set(0))):
+        out = np.asarray(ragged_paged_attention(
+            q, k, v, layer, tables, q_start, kvl,
+            q_lens=jnp.asarray(q_lens), interpret=True))
+        assert np.isfinite(out).all()
+        assert not out[1].any()
+        np.testing.assert_array_equal(out[[0, 2]], alone[[0, 2]])
+    ref = np.asarray(paged_attention_xla(q, k, v, layer, tables, qpos))
+    assert np.abs(alone - ref).max() < TOL
+
+
+def test_kernel_int8_arena_matches_dequantized_reference():
+    """int8 pages go to the dots as they are and the scales, a key, are
+    applied to the scores and the probabilities: the same numbers as the
+    reference's dequantize-then-attend, across a group's edge."""
+    rows = [(16 * PAGES + 9, 4), (21, 1)]
+    q, k, v, layer, tables, qpos, q_start, kv_live = _case(
+        rows, block_size=16)
+    rs = np.random.RandomState(1)
+    k8, v8 = (jnp.asarray(rs.randint(-127, 128, k.shape), jnp.int8)
+              for _ in range(2))
+    ksc, vsc = (jnp.asarray(rs.uniform(0.005, 0.02, k.shape[:3]),
+                            jnp.float32) for _ in range(2))
+    q_lens = jnp.asarray([c for _, c in rows], jnp.int32)
+    out = np.asarray(ragged_paged_attention(
+        q, k8, v8, layer, tables, q_start, kv_live, q_lens=q_lens,
+        interpret=True, k_scale=ksc, v_scale=vsc))
+    ref = np.asarray(paged_attention_xla(q, k8, v8, layer, tables, qpos,
+                                         k_scale=ksc, v_scale=vsc))
+    for i, (_, count) in enumerate(rows):
+        assert np.abs(out[i, :count] - ref[i, :count]).max() < TOL
+
+
+def test_kernel_head_size_64():
+    _check_ragged_q([(16 * PAGES + 2, 2), (30, 1)], pad_to=2,
+                    block_size=16, head_dim=64)
+
+
+def test_one_built_kernel_serves_every_layer():
+    """The layer is an index the kernel reads, not a key of the build:
+    two layers through the same shapes build one kernel, traced or not."""
+    import jax
+
+    rows = [(40, 1), (16 * PAGES + 1, 1)]
+    q, k, v, _, tables, qpos, q_start, kv_live = _case(
+        rows, block_size=16, num_layers=3)
+    _build_ragged.cache_clear()
+    traced = jax.jit(lambda layer: ragged_paged_attention(
+        q, k, v, layer, tables, q_start, kv_live, interpret=True))
+    for layer in (0, 2):
+        ref = np.asarray(paged_attention_xla(q, k, v, layer, tables, qpos))
+        for out in (traced(layer), ragged_paged_attention(
+                q, k, v, layer, tables, q_start, kv_live, interpret=True)):
+            assert np.abs(np.asarray(out) - ref).max() < TOL
+    assert not np.allclose(traced(0), traced(2))
+    info = _build_ragged.cache_info()
+    assert info.currsize == 1 and info.misses == 1, info
+
+
+def test_ragged_grid_steps_against_a_count_by_hand():
+    """Width 256 cuts into two query tiles of 128; block_size 16 makes a
+    fetch 32 pages. Row 0: a chunk of 200 tokens (2 live tiles) over 65
+    pages (3 fetches): 6 iterations, nothing skipped. Row 1: a decode row
+    over 32 pages: 1 iteration, 1 tile skipped. Row 2: a decode row over
+    33 pages: 2 iterations, 1 skipped. Row 3: no request: 2 skipped."""
+    assert (pa._q_tile(256), PAGES) == (128, 32)
+    got = ragged_grid_steps(np.array([200, 1, 1, 0], np.int32),
+                            np.array([65, 32, 33, 0], np.int32), 256, 16)
+    assert got == (6 + 1 + 2, 6 + 1 + 2 + 0 + 1 + 1 + 2)
+    # width 1: one tile a row, nothing skipped but the idle lane
+    got = ragged_grid_steps(np.array([1, 1, 1, 0], np.int32),
+                            np.array([65, 32, 33, 0], np.int32), 1, 16)
+    assert got == (3 + 1 + 2, 3 + 1 + 2 + 1)
+
+
+def test_dispatch_keeps_a_head_size_mosaic_refuses_on_the_xla_path(
+        monkeypatch):
+    """Compiled (not interpreted), the walk's page copies slice the arena
+    in HBM, which Mosaic refuses where it pads the head size to the 128
+    lanes: the dispatch keeps such a model on the XLA path."""
+    monkeypatch.setattr(pa, "use_pallas", lambda: True)
+    monkeypatch.setattr(pa, "interpret_mode", lambda: False)
+
+    def refuse(*a, **kw):
+        raise AssertionError("the kernel was dispatched")
+
+    monkeypatch.setattr(pa, "ragged_paged_attention", refuse)
+    q, k, v, layer, tables, qpos, q_start, kv_live = _case(
+        [(20, 1)], block_size=16, head_dim=64)
+    out = pa.paged_attention_arrays(q, k, v, layer, tables, qpos,
+                                    q_start=q_start, kv_live=kv_live)
+    ref = paged_attention_xla(q, k, v, layer, tables, qpos)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    with pytest.raises(AssertionError, match="was dispatched"):
+        q, k, v, *_ = _case([(20, 1)], block_size=16, head_dim=128)
+        pa.paged_attention_arrays(q, k, v, layer, tables, qpos,
+                                  q_start=q_start, kv_live=kv_live)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("block_size", [4, 8, 16])
 @pytest.mark.parametrize("lengths_counts", [
@@ -154,16 +307,20 @@ def test_kernel_bfloat16_tolerance():
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("D", [128, 64], ids=["d128", "d64"])
 @pytest.mark.parametrize("width", [1, 5, 128])
-def test_kernel_lowers_for_tpu(width, quant):
+def test_kernel_lowers_for_tpu(width, D, quant):
     """Cross-lower (no chip needed) the compiled kernel for TPU at the
     serving shapes. JAX's Pallas TPU lowering refuses a block whose last
     two dims are neither (8, 128)-divisible nor the whole array — which is
     what the int8 scale sidecars' (1, 1, 1) blocks were — before Mosaic
-    ever sees the kernel, so a refusal of that kind shows up here."""
+    ever sees the kernel, so a refusal of that kind shows up here. What
+    Mosaic itself refuses does not: head size 64 lowers here and is
+    refused there (tests/test_mosaic_aot.py compiles; the dispatch keeps
+    that size on the XLA path)."""
     import jax
 
-    B, H, D, bs, nk, NB = 8, 16, 128, 16, 128, 64
+    B, H, bs, nk, NB = 8, 16, 16, 128, 64
     f32, i32 = jnp.float32, jnp.int32
     sds = jax.ShapeDtypeStruct
     arena = sds((2, H, NB, bs, D), jnp.int8 if quant else jnp.bfloat16)

@@ -2,9 +2,9 @@
 the kernel's grid went (serving/engine.py `_run_rows`, `_run_step`;
 scheduler.py `_admit`; ops/pallas/paged_attention.py `ragged_grid_steps`):
 
-- ``grid_live`` / ``grid_total``: the ragged kernel's live and total grid
-  steps a head a layer, against a brute-force walk of the kernel's own live
-  condition;
+- ``grid_live`` / ``grid_total``: the iterations of the ragged kernel's
+  walk and, added to them, the grid steps that ran none, a layer, against a
+  brute-force walk of the kernel's own conditions;
 - ``h2d_arrays`` / ``h2d_ms``: the host arrays handed to the device and
   what the hand-over took, inside the ``dispatch`` phase;
 - ``queue_wait``: arrival to first admission, observed like ``ttft``, with
@@ -15,48 +15,59 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models.gpt import GPT, GPTConfig
-from paddle_tpu.ops.pallas.paged_attention import _q_tile, ragged_grid_steps
+from paddle_tpu.ops.pallas.paged_attention import (
+    _pages_per_fetch,
+    _q_tile,
+    ragged_grid_steps,
+)
 from paddle_tpu.serving import LLMEngine
 
 PHASES = ("plan", "build", "dispatch", "sync", "emit")
 
 
-def _walk(q_lens, kv_live, width, max_blocks):
-    """The kernel's grid `(B, nq, nk)` of one head, step by step, with its
-    own live test (`_ragged_kernel`: `qb < qb_live[i]` and `j < kv_live[i]`,
-    the wrapper's `qb_live = ceil(max(q_len, 1) / qt)`), counted over the
-    rows that carry a request."""
+def _walk(q_lens, kv_live, width, block_size):
+    """The kernel's grid `(B, nq)`, step by step, with
+    its own conditions (`_ragged_kernel`: a tile runs its loop where `qb *
+    qt < q_lens[i]`, one iteration a fetch of `pages` pages until the row's
+    `kv_live` are walked): `(iterations, iterations + grid steps that ran
+    none)`."""
     qt = _q_tile(width)
-    nq = width // qt
-    live = total = 0
+    pages = _pages_per_fetch(block_size)
+    live = idle = 0
     for q_len, kl in zip(q_lens, kv_live):
-        qb_live = (max(q_len, 1) + qt - 1) // qt
-        for qb in range(nq):
-            for j in range(max_blocks):
-                total += 1
-                live += bool(q_len > 0 and qb < qb_live and j < kl)
-    return live, total
+        for qb in range(width // qt):
+            ran = 0
+            if qb * qt < q_len:
+                page = 0
+                while page < kl:
+                    ran += 1
+                    page += pages
+            live += ran
+            idle += not ran
+    return live, live + idle
 
 
-# (q_lens, kv_live, width, max_blocks); q_len 0 = a lane with no request,
-# whose kv_live the engine leaves at 1 (the null block)
+# (q_lens, kv_live, width, block_size); q_len 0 = a lane with no request,
+# whose kv_live the engine leaves at 0
 GRID_CASES = {
     "decode-w1": ([1, 1, 1, 1], [3, 16, 1, 7], 1, 16),
-    "decode-w1-empty-lanes": ([1, 0, 1, 0], [5, 1, 2, 1], 1, 8),
-    "verify-w5": ([5, 3, 1, 0], [2, 4, 8, 1], 5, 8),
+    "decode-w1-empty-lanes": ([1, 0, 1, 0], [5, 0, 2, 0], 1, 8),
+    "verify-w5": ([5, 3, 1, 0], [2, 4, 8, 0], 5, 8),
     "chunk-w128-full": ([128, 128], [8, 12], 128, 16),
     "chunk-w128-ragged": ([128, 37, 9, 8], [16, 5, 1, 3], 128, 16),
-    "decode-row-rides-w128": ([128, 1, 1, 0], [9, 4, 16, 1], 128, 16),
-    "all-lanes-empty": ([0, 0], [1, 1], 1, 4),
+    "decode-row-rides-w128": ([128, 1, 1, 0], [9, 4, 16, 0], 128, 16),
+    "long-rows-w1": ([1, 1], [128, 65], 1, 16),
+    "small-blocks-w1": ([1, 1, 1], [33, 32, 31], 1, 4),
+    "all-lanes-empty": ([0, 0], [0, 0], 1, 4),
 }
 
 
 @pytest.mark.parametrize("case", sorted(GRID_CASES))
 def test_ragged_grid_steps_counts_the_kernels_live_steps(case):
-    q_lens, kv_live, width, max_blocks = GRID_CASES[case]
+    q_lens, kv_live, width, block_size = GRID_CASES[case]
     got = ragged_grid_steps(np.array(q_lens, np.int32),
-                            np.array(kv_live, np.int32), width, max_blocks)
-    assert got == _walk(q_lens, kv_live, width, max_blocks)
+                            np.array(kv_live, np.int32), width, block_size)
+    assert got == _walk(q_lens, kv_live, width, block_size)
     assert all(type(x) is int for x in got)     # a span arg: plain JSON
     assert 0 <= got[0] <= got[1]
 
@@ -106,33 +117,40 @@ def test_every_step_span_says_grid_and_handover(model, kv_dtype, arrays):
     engine.generate(prompts, max_new_tokens=6)
     steps = _steps(engine)
     assert {s["args"]["kind"] for s, _ in steps} >= {"mixed", "decode"}
-    per_step = engine.max_batch * engine.max_blocks
     for span, phases in steps:
         a = span["args"]
         assert "host_syncs" not in a
         nq = a["width"] // _q_tile(a["width"])
-        assert a["grid_total"] == per_step * nq
-        # every planned row walks at least its own first block
+        # every planned row fetches at least its own first pages, and
+        # every query tile of every lane either iterates or is skipped
         assert a["rows"] <= a["grid_live"] <= a["grid_total"]
+        assert a["grid_total"] >= engine.max_batch * nq
+        assert a["grid_total"] - a["grid_live"] <= engine.max_batch * nq
         assert a["h2d_arrays"] == arrays
         # ts/dur are rounded to 3 decimals of a microsecond
         assert 0.0 <= a["h2d_ms"] <= phases["dispatch"]["dur"] / 1e3 + 1e-5
     assert engine.metrics.counters["host_syncs"] == len(steps)
 
 
-def test_grid_live_follows_the_rows_contexts(model):
-    """A decode step's live grid is the rows' KV blocks: one query tile a
-    row, so it grows by one each time a row crosses a block boundary."""
+def test_grid_live_follows_the_rows_contexts(model, monkeypatch):
+    """A decode step's live count is the rows' fetches: one query tile a
+    row and one iteration a fetch, so it grows by one each time a row's
+    context crosses a fetch's edge (here cut to one 8-token page, so that
+    contexts a test can hold cross some), and nothing is skipped while
+    every lane is busy."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_KV_FETCH_TOKENS", 8)
     engine = _engine(model, trace=1.0, max_batch=2)
     engine.generate(_prompts((7, 15), seed=3), max_new_tokens=10)
     decode = [s["args"] for s, _ in _steps(engine)
               if s["args"]["kind"] == "decode" and s["args"]["rows"] == 2]
     assert len(decode) >= 8
-    # step n feeds positions 7+n and 15+n (block_size 8): blocks walked
-    first = decode[0]["grid_live"]
-    assert first == (7 // 8 + 1) + (15 // 8 + 1)
-    lives = [a["grid_live"] for a in decode]
-    assert lives == sorted(lives) and lives[-1] > first
+    # step n feeds positions 7+n and 15+n (block_size 8): fetches made
+    for n, a in enumerate(decode):
+        assert a["grid_live"] == (7 + n) // 8 + 1 + (15 + n) // 8 + 1
+        assert a["grid_total"] == a["grid_live"]
+    assert decode[-1]["grid_live"] > decode[0]["grid_live"]
 
 
 @pytest.mark.parametrize("trace", [None, 1.0], ids=["untraced", "traced"])
