@@ -166,7 +166,7 @@ def _base_opcode(opcode):
 
 def collective_counts(ops):
     """{collective opcode: count} over every parsed op, zero-filled so a
-    contract (and the bench JSON) can assert on absent opcodes too."""
+    contract can assert on absent opcodes too."""
     counts = {k: 0 for k in COLLECTIVE_OPS}
     for op in ops:
         if op.opcode.endswith("-done"):
@@ -575,15 +575,3 @@ def default_artifacts():
                               prefix="serve_lora")
     arts += train_artifacts()
     return arts
-
-
-def engine_collective_counts(engine, kinds=None):
-    """{kind: {collective: count}} for a live engine's programs — the
-    bench's ``collectives`` JSON object (bench.py gpt_serve_multichip),
-    so the bench trajectory catches collective-count drift, not just
-    tok/s drift. Lowers + compiles fresh artifacts; never serves."""
-    return {
-        kind: collective_counts(
-            parse_hlo_ops(lowered.compile().as_text()))
-        for kind, lowered in engine.lowered_step_programs(kinds=kinds).items()
-    }

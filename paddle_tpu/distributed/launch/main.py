@@ -70,6 +70,7 @@ def rendezvous(master, nnodes, rank, job_id, timeout=300.0):
                              world_size=nnodes, timeout=int(timeout))
         except RuntimeError:
             store = None  # another local node already serves it
+    serving = store is not None
     if store is None:
         deadline = time.monotonic() + timeout
         while True:
@@ -130,6 +131,14 @@ def rendezvous(master, nnodes, rank, job_id, timeout=300.0):
         store.get(f"{pfx}/node/{r}").decode() for r in range(nnodes)
     ]
     authkey = store.get(f"{pfx}/authkey").decode()
+    # every node says when it has read the table, and the node that serves
+    # the store leaves last: its launcher exits, and takes the store down,
+    # as soon as its trainer does, while a peer may still be polling
+    # `joined` (seen as "TCPStore.add failed" on a loaded host)
+    n = store.add(f"{pfx}/read", 1)
+    while serving and n < nnodes and time.monotonic() < deadline:
+        time.sleep(0.05)
+        n = store.add(f"{pfx}/read", 0)
     return rank, endpoints, authkey, store
 
 

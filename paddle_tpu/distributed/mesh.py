@@ -230,8 +230,8 @@ class HybridCommunicateGroup:
 
 
 class AxisGroup:
-    """A 'process group' that is a named mesh axis (the ProcessGroupXla of
-    BASELINE.json's north star: collectives on it compile to XLA ICI ops)."""
+    """A 'process group' that is a named mesh axis (a ProcessGroupXla:
+    collectives on it compile to XLA ICI ops)."""
 
     def __init__(self, mesh: Mesh, axis: str):
         self.mesh = mesh

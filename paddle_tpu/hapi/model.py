@@ -5,8 +5,8 @@ train_batch:1178, DynamicGraphAdapter:763 vs StaticGraphAdapter:286.
 
 TPU-native design: there is ONE adapter — the compiled-step adapter. Each
 train/eval batch executes a single cached XLA program (forward + loss + grads
-+ optimizer update, buffers donated) built from functional_call — this is the
-whole-program-XLA north star of BASELINE.json applied at the hapi level.
++ optimizer update, buffers donated) built from functional_call: one whole
+XLA program a step, at the hapi level.
 Eager fallback (`compiled=False`) runs the tape for debugging.
 """
 from __future__ import annotations
@@ -222,8 +222,8 @@ class Model:
 
     def _dist_mesh(self):
         """The active fleet/SPMD mesh, if Model.fit should train sharded
-        (the reference hapi's automatic fleet integration — BASELINE north
-        star: Model.fit + Fleet Sharding scaling). Pipeline degrees are the
+        (the reference hapi's automatic fleet integration: Model.fit +
+        Fleet Sharding scaling). Pipeline degrees are the
         fleet PipelineParallel wrapper's job, not hapi's."""
         from ..distributed.mesh import get_mesh
 

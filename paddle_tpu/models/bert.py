@@ -1,5 +1,5 @@
-"""BERT/ERNIE-style encoder (BASELINE.json config 3: ERNIE-3.0/BERT-base
-pretrain with Sharding-2).
+"""BERT/ERNIE-style encoder (ERNIE-3.0/BERT-base pretrain with
+Sharding-2).
 
 Encoder built from the framework's TP layers + flash attention; MLM + NSP
 heads for pretrain parity with the reference's ERNIE recipe.
@@ -132,7 +132,7 @@ def bert_base(**kw):
 
 
 def ernie_base(**kw):
-    """ERNIE-3.0-base shape (BASELINE north star)."""
+    """ERNIE-3.0-base shape."""
     kw.setdefault("vocab_size", 40000)
     return Bert(BertConfig(**kw))
 

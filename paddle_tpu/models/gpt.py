@@ -1,4 +1,4 @@
-"""GPT: the flagship decoder-only LM (BASELINE.json config 4: GPT-1.3B TP+PP).
+"""GPT: the flagship decoder-only LM (GPT-2-style block; 1.3B with TP+PP).
 
 Built from the framework's own TP layers (ColumnParallelLinear /
 RowParallelLinear / VocabParallelEmbedding — the Megatron partitioning of the
@@ -73,50 +73,6 @@ def _split_fused_qkv(qkv, b, s, num_heads, head_dim):
     return q, k, v
 
 
-def _serving_row_parallel(layer, x, op_name, cache):
-    """RowParallel output projection on the paged serving path: routed
-    through the EQuARX-quantized collective (serving/sharded.py
-    `quantized_row_parallel` — int8 payload + per-shard scale instead of
-    the f32 psum) when the threaded-through `PagedState` gates `op_name`
-    on, the plain layer otherwise. The gate lives on the state, not the
-    module, so ONE model serves quantized and f32 engines at once and
-    the training path never sees it (GSPMD's implicit training-mesh
-    all-reduce has no jnp-level seam to quantize)."""
-    st = getattr(cache, "state", cache)
-    if (getattr(st, "mesh", None) is not None
-            and op_name in getattr(st, "quant_collectives", ())):
-        from ..serving.sharded import quantized_row_parallel
-
-        o = quantized_row_parallel(
-            x._array, layer.weight._array,
-            None if layer.bias is None else layer.bias._array,
-            st.mesh)
-        return Tensor._from_op(o)
-    return layer(x)
-
-
-def _serving_column_parallel(layer, x, op_name, cache):
-    """ColumnParallel projection on the paged serving path, with each
-    lane's LoRA delta added when the threaded-through `PagedState`
-    carries gathered adapter rows for `op_name` (models/lora.py —
-    ``y + x @ A[slot] @ B[slot]``, slot 0 all-zeros = base). The gate
-    lives on the state like `_serving_row_parallel`'s quant gate: ONE
-    model serves adapter-enabled and plain engines at once, the delta
-    inherits the base output's tp layout from B's sharded out axis (no
-    new collectives), and a lora-less engine traces the byte-identical
-    program it always has."""
-    y = layer(x)
-    st = getattr(cache, "state", cache)
-    lora = getattr(st, "lora", None)
-    if lora is None or op_name not in lora:
-        return y
-    from .lora import apply_adapter_rows
-
-    a_rows, b_rows = lora[op_name]
-    delta = apply_adapter_rows(x._array, a_rows, b_rows, cache.layer)
-    return Tensor._from_op(y._array + delta)
-
-
 class CausalSelfAttention(nn.Layer):
     def __init__(self, cfg: GPTConfig):
         super().__init__()
@@ -133,28 +89,21 @@ class CausalSelfAttention(nn.Layer):
 
     def forward(self, x, cache=None):
         b, s, _ = x.shape
-        if cache is not None and getattr(cache, "is_paged", False):
-            # [b, s, 3h] (mp-sharded on last dim) + per-lane LoRA delta
-            qkv = _serving_column_parallel(self.qkv, x, "attn_qkv", cache)
-        else:
-            qkv = self.qkv(x)  # [b, s, 3h] (mp-sharded on last dim)
+        paged = getattr(cache, "is_paged", False)
+        # [b, s, 3h] (mp-sharded on last dim); a paged cache does the
+        # projections that serving has a say in (PagedLayerView)
+        qkv = (cache.column_parallel(self.qkv, x, "attn_qkv") if paged
+               else self.qkv(x))
         # per-head-grouped regroup (module-level so hlolint's seeded
         # regression can patch in the qkv-major layout it exists to catch)
         q, k, v = _split_fused_qkv(qkv, b, s, self.num_heads, self.head_dim)
-        if cache is not None and getattr(cache, "is_paged", False):
-            # serving path: K/V live in the global block arena and are
-            # attended through this sequence's block table (vLLM-style
-            # paged attention; serving/block_pool.py scatters, then
-            # ops/pallas/paged_attention.py dispatches the ragged Pallas
-            # kernel on TPU or the XLA gather fallback elsewhere)
-            from ..serving.block_pool import paged_attention
-
-            o = paged_attention(q._array, k._array, v._array, cache)
+        if paged:
+            # serving path: the cache appends K/V to its block arena and
+            # attends through this sequence's block table
             out = M.reshape(
-                Tensor._from_op(o), [b, s, self.num_heads * self.head_dim]
+                cache.attend(q, k, v), [b, s, self.num_heads * self.head_dim]
             )
-            return _serving_row_parallel(self.proj, out, "attn_proj",
-                                         cache), cache
+            return cache.row_parallel(self.proj, out, "attn_proj"), cache
         if cache is not None:
             # incremental decode: fixed-size KV cache so every step compiles
             # once (reference fused_multi_transformer's cache_kv role).
@@ -216,10 +165,11 @@ class GPTBlock(nn.Layer):
         if cache is not None:
             attn_out, new_cache = self.attn(self.ln1(x), cache=cache)
             x = x + attn_out
-            h = _serving_column_parallel(self.fc1, self.ln2(x), "ffn_fc1",
-                                         cache)
-            x = x + _serving_row_parallel(
-                self.fc2, self.act(h), "ffn_fc2", cache)
+            if getattr(cache, "is_paged", False):
+                h = cache.column_parallel(self.fc1, self.ln2(x), "ffn_fc1")
+                x = x + cache.row_parallel(self.fc2, self.act(h), "ffn_fc2")
+            else:
+                x = x + self.fc2(self.act(self.fc1(self.ln2(x))))
             return x, new_cache
         x = x + self.dropout(self.attn(self.ln1(x)))
         x = _constraint(x, "dp", "sp", None)
@@ -462,7 +412,7 @@ def gpt_small(**kw):
 
 
 def gpt_1p3b(**kw):
-    """GPT-3 1.3B shape (BASELINE config 4)."""
+    """GPT-3 1.3B shape."""
     return GPT(
         GPTConfig(
             vocab_size=50304, hidden_size=2048, num_layers=24, num_heads=16,

@@ -47,7 +47,8 @@ from __future__ import annotations
 import numpy as np
 
 # Column-parallel serving ops that accept adapters, by the op names
-# models/gpt.py threads through `_serving_column_parallel`. Row-parallel
+# models/gpt.py hands to its cache's `column_parallel`
+# (serving/block_pool.py PagedLayerView). Row-parallel
 # ops are deliberately NOT targets: their tp-sharded INPUT would force
 # the A-projection to reduce over a sharded axis (a psum per layer per
 # adapter — exactly the collective creep IR001 exists to forbid).

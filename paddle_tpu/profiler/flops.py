@@ -1,11 +1,11 @@
 """MFU / goodput accounting: model-FLOPs estimators, a peak-FLOPs
 registry, and step-time statistics over recorded training traces.
 
-Lifted out of `bench.py` (which is now a consumer) so the numbers the
-bench rounds report — MFU, tokens/s, step-time percentiles — are
-computable for ANY run, not just the bench harness: a hapi `Model.fit`
+MFU, tokens/s and step-time percentiles for ANY run: a hapi `Model.fit`
 traced with `profiler.tracing.TrainTracer`, a raw `ShardedTrainStep`
-loop, or a device capture read back through `profiler.xplane`.
+loop, or a device capture read back through `profiler.xplane`. (The
+benchmark keeps its own copies under `benchmark/reduce/`, which a PR that
+claims a gain may not touch.)
 
 Three layers:
 
@@ -84,8 +84,7 @@ def hbm_bytes_per_s(device=None) -> float:
 def dense_train_flops_per_token(hidden_size, num_layers, seq_len,
                                 vocab_size, intermediate_size) -> float:
     """6*N for the matmuls (fwd+bwd) + causal attention score/value FLOPs
-    of a decoder-only transformer — the formula bench.py's MFU has used
-    since round 1, parameterized."""
+    of a decoder-only transformer."""
     H, L, S, V = hidden_size, num_layers, seq_len, vocab_size
     Ff = intermediate_size
     n_matmul = L * (4 * H * H + 2 * H * Ff) + V * H  # qkv+proj + mlp + unembed

@@ -99,7 +99,6 @@ from .block_pool import (  # noqa: F401
     BlockPool,
     PagedState,
     chain_block_hashes,
-    paged_attention,
 )
 from .engine import LLMEngine, StepOutput  # noqa: F401
 from .faults import FaultInjected, FaultPlan, FaultPoint  # noqa: F401

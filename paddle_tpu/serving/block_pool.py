@@ -8,9 +8,9 @@ owns a list of block ids. Head-major is the Pallas-friendly layout: each
 the ragged kernel DMAs straight from HBM (ops/pallas/paged_attention.py).
 Appending tokens is a fixed-shape ``.at[...].set`` scatter of ``head_dim``
 rows (`_append`: the arena keeps the kernel's layout); attention runs
-through `paged_attention`, which dispatches to the ragged Pallas kernel on
-TPU and to an XLA gather of the padded ``[rows, max_blocks]`` block table
-everywhere else. Because every device op has a static shape, the whole
+through `PagedLayerView.attend`, which dispatches to the ragged Pallas
+kernel on TPU and to an XLA gather of the padded ``[rows, max_blocks]``
+block table everywhere else. Because every device op has a static shape, the whole
 mixed prefill+decode serve compiles to two programs — no shape ever depends
 on how many requests are in flight or how long they are.
 
@@ -43,6 +43,7 @@ from collections import OrderedDict
 
 import numpy as np
 
+from ..core.tensor import Tensor
 from . import faults
 
 
@@ -86,12 +87,18 @@ def chain_block_hashes(token_ids, block_size, salt=None):
 
 
 class PagedLayerView:
-    """One layer's window onto a threaded-through paged forward.
+    """One layer's window onto a threaded-through paged forward, and the
+    part of that layer which is serving's to decide.
 
-    `CausalSelfAttention.forward` receives this as its `cache` argument and
-    calls `paged_attention`, which scatters the new K/V into the arena and
-    attends through the block table. The arena arrays live on the shared
-    `state` so each layer's update feeds the next layer's trace.
+    The model's attention and MLP receive this as their `cache` argument
+    and hand it three jobs: `attend` (how K/V reach the arena and how the
+    block table is read), `column_parallel` (which adapter rows a lane
+    adds) and `row_parallel` (which collective sums the tp partials). The
+    arena arrays live on the shared `state` so each layer's update feeds
+    the next layer's trace; the gates live there too, not on the model's
+    modules, so ONE model serves quantized, adapter-enabled and plain
+    engines at once, the training path never sees them, and an engine
+    without them traces the byte-identical program it always has.
     """
 
     is_paged = True
@@ -99,6 +106,76 @@ class PagedLayerView:
     def __init__(self, state, layer):
         self.state = state
         self.layer = layer
+
+    def attend(self, q, k_new, v_new):
+        """Append `k_new`/`v_new` into the arena and attend `q` through
+        the block table. Tensors [B, S, heads, head_dim] in and out, all
+        shapes static. The attention itself is
+        ops/pallas/paged_attention.py's dispatch: ragged Pallas kernel
+        over live blocks on TPU, padded XLA gather elsewhere."""
+        from ..ops.pallas.paged_attention import paged_attention_arrays
+
+        st, layer = self.state, self.layer
+        q, k_new, v_new = q._array, k_new._array, v_new._array
+        if st.mesh is not None:
+            # tensor-parallel serving: pin the step's new K/V (and q) to
+            # the head sharding BEFORE the append, so each chip quantizes
+            # and writes its own head slab of the arena and GSPMD invents
+            # no gather
+            q = st.constrain(q, None, None, "tp", None)
+            k_new = st.constrain(k_new, None, None, "tp", None)
+            v_new = st.constrain(v_new, None, None, "tp", None)
+        if st.k_scale is not None:
+            # int8 arena: quantize at the scatter, scales growing per
+            # touched block (dequant happens inside the Pallas kernel /
+            # before the XLA fallback's einsum)
+            st.k, st.k_scale = _quantize_scatter(
+                st.k, st.k_scale, layer, k_new, st.slots, st.offs,
+                st.touched, st.touch_idx, st.mesh)
+            st.v, st.v_scale = _quantize_scatter(
+                st.v, st.v_scale, layer, v_new, st.slots, st.offs,
+                st.touched, st.touch_idx, st.mesh)
+        else:
+            st.k = _append(st.k, layer, k_new, st.slots, st.offs, st.mesh)
+            st.v = _append(st.v, layer, v_new, st.slots, st.offs, st.mesh)
+        return Tensor._from_op(paged_attention_arrays(
+            q, st.k, st.v, layer, st.block_tables, st.qpos,
+            q_start=st.q_start, kv_live=st.kv_live, q_lens=st.q_lens,
+            mesh=st.mesh, k_scale=st.k_scale, v_scale=st.v_scale,
+        ))
+
+    def column_parallel(self, layer, x, op_name):
+        """ColumnParallel projection `layer(x)`, with each lane's LoRA
+        delta added when the state carries gathered adapter rows for
+        `op_name` (models/lora.py: ``y + x @ A[slot] @ B[slot]``, slot 0
+        all-zeros = base). The delta inherits the base output's tp layout
+        from B's sharded out axis (no new collectives)."""
+        y = layer(x)
+        lora = self.state.lora
+        if lora is None or op_name not in lora:
+            return y
+        from ..models.lora import apply_adapter_rows
+
+        a_rows, b_rows = lora[op_name]
+        delta = apply_adapter_rows(x._array, a_rows, b_rows, self.layer)
+        return Tensor._from_op(y._array + delta)
+
+    def row_parallel(self, layer, x, op_name):
+        """RowParallel output projection `layer(x)`: routed through the
+        EQuARX-quantized collective (serving/sharded.py
+        `quantized_row_parallel`: int8 payload + per-shard scale instead
+        of the f32 psum) when the state gates `op_name` on. GSPMD's
+        implicit training-mesh all-reduce has no jnp-level seam to
+        quantize, which is why this is the cache's job."""
+        st = self.state
+        if st.mesh is not None and op_name in st.quant_collectives:
+            from .sharded import quantized_row_parallel
+
+            return Tensor._from_op(quantized_row_parallel(
+                x._array, layer.weight._array,
+                None if layer.bias is None else layer.bias._array,
+                st.mesh))
+        return layer(x)
 
 
 class PagedState:
@@ -135,7 +212,8 @@ class PagedState:
     and lets `constrain` pin traced activations to the tp layout.
     `quant_collectives` (static frozenset) names the RowParallel output
     projections whose tp all-reduce runs quantized (serving/sharded.py
-    `quantized_row_parallel`); models/gpt.py consults it per op.
+    `quantized_row_parallel`); `PagedLayerView.row_parallel` consults it
+    per op.
     """
 
     is_paged = True
@@ -162,7 +240,7 @@ class PagedState:
         # per-row LoRA adapters (models/lora.py), already gathered for
         # THIS step's lanes: {target op -> (a_rows [B,L,in,r],
         # b_rows [B,L,r,out])} or None (no adapters in the program).
-        # models/gpt.py's column-parallel hook consults it per op.
+        # `PagedLayerView.column_parallel` consults it per op.
         self.lora = lora
 
     def layer(self, i):
@@ -276,45 +354,6 @@ def _quantize_scatter(arena, scales, layer, new, slots, offs, touched,
     qn = jnp.clip(jnp.round(new.astype(jnp.float32) / tok_sc[..., None]),
                   -127, 127)
     return _append(arena, layer, qn, slots, offs, mesh), scales
-
-
-def paged_attention(q, k_new, v_new, view, scale=None):
-    """Append `k_new`/`v_new` into the arena and attend `q` through the
-    block table. All shapes static; returns [B, S, heads, head_dim].
-
-    q, k_new, v_new: [B, S, heads, head_dim] jnp arrays. The attention
-    itself is ops/pallas/paged_attention.py's dispatch: ragged Pallas
-    kernel over live blocks on TPU, padded XLA gather elsewhere.
-    """
-    from ..ops.pallas.paged_attention import paged_attention_arrays
-
-    st, layer = view.state, view.layer
-    if st.mesh is not None:
-        # tensor-parallel serving: pin the step's new K/V (and q) to the
-        # head sharding BEFORE the append, so each chip quantizes and
-        # writes its own head slab of the arena and GSPMD invents no gather
-        q = st.constrain(q, None, None, "tp", None)
-        k_new = st.constrain(k_new, None, None, "tp", None)
-        v_new = st.constrain(v_new, None, None, "tp", None)
-    if st.k_scale is not None:
-        # int8 arena: quantize at the scatter, scales growing per touched
-        # block (dequant happens inside the Pallas kernel / before the
-        # XLA fallback's einsum — ops/pallas/paged_attention.py)
-        st.k, st.k_scale = _quantize_scatter(
-            st.k, st.k_scale, layer, k_new, st.slots, st.offs,
-            st.touched, st.touch_idx, st.mesh)
-        st.v, st.v_scale = _quantize_scatter(
-            st.v, st.v_scale, layer, v_new, st.slots, st.offs,
-            st.touched, st.touch_idx, st.mesh)
-    else:
-        st.k = _append(st.k, layer, k_new, st.slots, st.offs, st.mesh)
-        st.v = _append(st.v, layer, v_new, st.slots, st.offs, st.mesh)
-    return paged_attention_arrays(
-        q, st.k, st.v, layer, st.block_tables, st.qpos,
-        q_start=st.q_start, kv_live=st.kv_live, q_lens=st.q_lens,
-        scale=scale, mesh=st.mesh,
-        k_scale=st.k_scale, v_scale=st.v_scale,
-    )
 
 
 class BlockPool:

@@ -207,10 +207,9 @@ def _adaround_model_int8(model, calib_prompts, iters=300):
 class LLMEngine:
     def __init__(self, model, block_size=16, num_blocks=None, max_batch=4,
                  prefill_chunk=None, token_budget=None, max_seq_len=None,
-                 prefill_buckets=None, prefill_interval=None, seed=0,
-                 prefix_cache=None, spec_decoding=None, num_spec_tokens=4,
-                 spec_max_ngram=3, spec_min_ngram=1, trace=None,
-                 trace_buffer=None, request_log=None, mesh=None,
+                 seed=0, prefix_cache=None, spec_decoding=None,
+                 num_spec_tokens=4, spec_max_ngram=3, spec_min_ngram=1,
+                 trace=None, trace_buffer=None, request_log=None, mesh=None,
                  kv_hbm_bytes=None, slo=None, postmortem_dir=None,
                  postmortem_keep=None, width_buckets=None,
                  host_kv_blocks=None, host_swap_chunk=4,
@@ -351,10 +350,6 @@ class LLMEngine:
         if num_blocks is None:
             # enough for a full decode batch of max-length sequences (+null)
             num_blocks = self.max_batch * self.max_blocks + 1
-        # prefill_buckets/prefill_interval are accepted for API compatibility
-        # with the bucketed engine and ignored: chunked prefill replaced the
-        # per-bucket programs with one mixed program
-        del prefill_buckets
         if prefill_chunk is None:
             prefill_chunk = min(128, self.max_seq_len)
         self.prefill_chunk = max(1, min(int(prefill_chunk), self.max_seq_len))
@@ -591,8 +586,7 @@ class LLMEngine:
         self.scheduler = Scheduler(
             self.pool, max_batch=self.max_batch,
             token_budget=int(token_budget),
-            prefill_chunk=self.prefill_chunk,
-            prefill_interval=prefill_interval, metrics=self.metrics,
+            prefill_chunk=self.prefill_chunk, metrics=self.metrics,
             prefix_cache=self.prefix_cache, drafter=drafter,
             tracer=self.tracer, slo=self.slo,
             width_buckets=self.width_buckets, policy=self.policy,

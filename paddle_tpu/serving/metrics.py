@@ -3,7 +3,7 @@
 Kept deliberately framework-free (plain dicts/floats) so three consumers can
 read them without adapters:
 
-- `snapshot()`  — flat JSON-able dict for `bench.py` and log shipping;
+- `snapshot()`  — flat JSON-able dict for log shipping;
 - `schedule_view()` — the SAME dict shape `profiler.xplane.schedule_analysis`
   emits per plane (span/busy/idle/utilization/top_gaps), so
   `xplane.print_schedule_analysis` renders engine schedules exactly like

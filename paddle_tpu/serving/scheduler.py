@@ -213,7 +213,7 @@ class Request:
 
 class Scheduler:
     def __init__(self, pool, max_batch=8, token_budget=2048,
-                 prefill_chunk=None, prefill_interval=None, metrics=None,
+                 prefill_chunk=None, metrics=None,
                  prefix_cache=True, drafter=None, tracer=None, slo=None,
                  width_buckets=None, policy=None):
         self.pool = pool
@@ -230,9 +230,6 @@ class Scheduler:
         )
         if self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
-        # prefill_interval is accepted for API compatibility with the
-        # bucketed engine; mixed batching made it moot (decode rows ride in
-        # every step, so prefill never needs rationing to protect latency)
         self.metrics = metrics
         self.prefix_cache = bool(prefix_cache)
         # speculative decoding: a drafter (serving/spec.py NgramDrafter)

@@ -2,7 +2,7 @@
 ET-head with DFL box regression, matrix-NMS postprocess).
 
 Reference parity: the PP-YOLOE architecture served by the reference's
-inference stack (BASELINE config 4: dynamic-shape AnalysisPredictor latency;
+inference stack (dynamic-shape AnalysisPredictor latency;
 ops matrix_nms_op.cc / the detection suite in
 /root/reference/paddle/fluid/operators/detection/). The model definition
 itself lives in the PaddleDetection model zoo, not the core repo — this is a
@@ -12,7 +12,7 @@ TPU-first: static shapes end to end, decode + matrix NMS compiled into the
 same XLA program as the network, variable image sizes handled by the
 predictor's shape buckets rather than dynamic shapes.
 
-Scope note: this is the inference vertical (the BASELINE config). Training
+Scope note: this is the inference vertical. Training
 utilities stop at a simple per-grid-cell assignment loss (`simple_loss`) —
 the full task-aligned assigner (TAL) of the paper is not implemented.
 """

@@ -49,7 +49,7 @@ int main(int argc, char** argv) {
   data(p, 0, out);
   for (int64_t i = 0; i < ne; ++i) printf("%.6f\n", (double)out[i]);
   free(out);
-  /* ADVICE r5 regression: an out-of-range output idx must return -1 AND
+  /* advisor-finding regression (fixed in PR 1): an out-of-range output idx must return -1 AND
      set the thread-local error (the early returns used to skip
      g_last_error, so callers printed a stale/empty message). */
   int64_t bad = numel(p, 99);
@@ -103,7 +103,7 @@ def test_c_consumer_matches_python_predictor():
 
 
 def test_goapi_run_keepalive_and_bounds_guards():
-    """ADVICE r5 regression (source contract — the image ships no Go
+    """Advisor-finding regression, fixed in PR 1 (source contract — the image ships no Go
     toolchain, so the guards are pinned at the source level): `Run` must
     KeepAlive the Predictor past the cgo call (the NewPredictor finalizer
     may otherwise Destroy the handle while a Run is in flight) and must

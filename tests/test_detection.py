@@ -238,7 +238,7 @@ class TestPPYOLOE:
 
 class TestPredictorDetection:
     def test_shape_buckets_e2e(self):
-        """The BASELINE-config-4 capability: variable batch through the
+        """Dynamic-shape inference: variable batch through the
         bucket-AOT predictor on a real detection model."""
         from paddle_tpu.inference import Config, create_predictor
         from paddle_tpu.vision.models import ppyoloe_s

@@ -236,7 +236,7 @@ def test_to_static_kwargs_in_cache_key():
 
 def test_to_static_tensor_kwargs_are_runtime_values():
     """Two same-shape Tensor kwargs hit the same compiled entry but must
-    use their OWN values (ADVICE medium: the kwarg's concrete array was
+    use their OWN values (advisor finding, fixed in PR 1: the kwarg's concrete array was
     baked into the traced closure, silently replaying the first mask)."""
 
     def f(x, mask=None):

@@ -1,4 +1,4 @@
-"""Model.fit over a fleet mesh (the BASELINE north star: hapi + Fleet
+"""Model.fit over a fleet mesh (hapi + Fleet
 sharding; reference hapi/model.py auto fleet integration). 8-device CPU
 mesh via conftest."""
 import numpy as np
@@ -64,7 +64,7 @@ def test_model_save_after_distributed_fit(clean_mesh, tmp_path):
 
 def test_bert_model_fit_sharded(clean_mesh):
     """BERT-tiny via Model.fit on a dp x sharding mesh — the ERNIE-pretrain
-    shape of BASELINE config 3 at test scale."""
+    shape at test scale."""
     from paddle_tpu.models.bert import Bert, BertConfig
 
     init_mesh({"dp": 2, "sharding": 2, "mp": 2})

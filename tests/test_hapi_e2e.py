@@ -1,5 +1,5 @@
 """End-to-end hapi Model tests (the reference's north-star config 1:
-LeNet/MNIST via Model.fit — BASELINE.json)."""
+LeNet/MNIST via Model.fit)."""
 import os
 
 import numpy as np

@@ -246,15 +246,15 @@ def test_silently_disabled_equarx_gate_trips_the_quantized_budget(
         monkeypatch):
     """The int8 family's IR001 is a REGRESSION tripwire, not just a
     description: if the per-op quantization hook stops firing (here:
-    `_serving_row_parallel` patched back to a plain layer call — the
+    `PagedLayerView.row_parallel` patched back to a plain layer call — the
     shape of a refactor that loses the gate), the engine still REPORTS
     quantized collectives, the budget still expects the all-gather
     pairs, and the now-f32 program must fail the contract instead of
     silently serving unquantized."""
-    from paddle_tpu.models import gpt as gpt_mod
+    from paddle_tpu.serving.block_pool import PagedLayerView
 
-    monkeypatch.setattr(gpt_mod, "_serving_row_parallel",
-                        lambda layer, x, op_name, cache: layer(x))
+    monkeypatch.setattr(PagedLayerView, "row_parallel",
+                        lambda self, layer, x, op_name: layer(x))
     arts = ir.serving_artifacts(tp_degrees=(2,), kinds=["w1"],
                                 kv_dtype="int8", quant_allreduce=True,
                                 prefix="serve_int8")

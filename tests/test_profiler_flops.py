@@ -1,13 +1,10 @@
-"""MFU/goodput accounting (profiler/flops.py), lifted from bench.py.
+"""MFU/goodput accounting (profiler/flops.py).
 
-The acceptance criterion: bench's gpt-train MFU is UNCHANGED after the
-lift — the pre-lift formulas are restated here verbatim as plain
-arithmetic and the module must reproduce them (to well past the 4
-decimal places the BENCH json rounds to), for both bench GPT configs and
+The formulas are restated here by hand as plain arithmetic and the module
+must reproduce them (MFU to 4 decimal places), for two GPT configs and
 every peak-flops registry entry.
 """
 import json
-import os
 import tempfile
 
 import numpy as np
@@ -21,17 +18,17 @@ class _Dev:
         self.device_kind = kind
 
 
-def _pre_lift_flops_per_token(H, L, S, V, Ff):
-    """bench.py's _train_flops_per_token as it stood before the lift."""
+def _flops_per_token_by_hand(H, L, S, V, Ff):
+    """6 x the matmul parameters + causal attention, a token."""
     n_matmul = L * (4 * H * H + 2 * H * Ff) + V * H
     attn = L * 2 * S * H * 3
     return 6.0 * n_matmul + attn
 
 
-def test_gpt_train_flops_matches_pre_lift_formula():
+def test_gpt_train_flops_matches_formula_by_hand():
     from paddle_tpu.models.gpt import GPTConfig
 
-    # both bench_gpt configs: the TPU flagship and the CPU fallback
+    # a mid-size config and a tiny one
     cfgs = [
         GPTConfig(vocab_size=32768, hidden_size=1024, num_layers=12,
                   num_heads=8, max_seq_len=1024),
@@ -39,22 +36,21 @@ def test_gpt_train_flops_matches_pre_lift_formula():
                   num_heads=8, max_seq_len=128),
     ]
     for cfg in cfgs:
-        want = _pre_lift_flops_per_token(
+        want = _flops_per_token_by_hand(
             cfg.hidden_size, cfg.num_layers, cfg.max_seq_len,
             cfg.vocab_size, cfg.intermediate_size)
         assert flops.gpt_train_flops_per_token(cfg) == want
 
 
-def test_bench_mfu_unchanged_to_4_decimals():
-    """End to end: round(tok/s * flops/token / peak, 4) — the exact MFU
-    arithmetic bench.py emits — through the lifted module, at the r03
-    throughput on the flagship config."""
+def test_mfu_matches_arithmetic_to_4_decimals():
+    """End to end: round(tok/s * flops/token / peak, 4) through the
+    module, at a fixed throughput on the mid-size config."""
     from paddle_tpu.models.gpt import GPTConfig
 
     cfg = GPTConfig(vocab_size=32768, hidden_size=1024, num_layers=12,
                     num_heads=8, max_seq_len=1024)
-    tokens_per_sec = 82400.0  # the r03 number
-    fpt = _pre_lift_flops_per_token(1024, 12, 1024, 32768,
+    tokens_per_sec = 82400.0
+    fpt = _flops_per_token_by_hand(1024, 12, 1024, 32768,
                                     cfg.intermediate_size)
     for kind, peak in (("TPU v5e", 197e12), ("TPU v4", 275e12)):
         want = round(tokens_per_sec * fpt / peak, 4)
@@ -64,8 +60,8 @@ def test_bench_mfu_unchanged_to_4_decimals():
         assert got == want
 
 
-def test_peak_flops_registry_matches_pre_lift():
-    pre_lift = {
+def test_peak_flops_registry():
+    by_hand = {
         "TPU v4": 275e12,
         "TPU v5 lite": 197e12,
         "TPU v5e": 197e12,
@@ -73,7 +69,7 @@ def test_peak_flops_registry_matches_pre_lift():
         "TPU v6e": 918e12,
         "TPU v6 lite": 918e12,
     }
-    for kind, want in pre_lift.items():
+    for kind, want in by_hand.items():
         assert flops.peak_flops(_Dev(kind)) == want
         assert flops.peak_flops(kind) == want      # plain strings work too
     # a device the table does not list is an error, not a v5e
@@ -84,28 +80,10 @@ def test_peak_flops_registry_matches_pre_lift():
             flops.hbm_bytes_per_s(unknown)
 
 
-def test_resnet50_flops_matches_pre_lift():
+def test_resnet50_flops_matches_formula_by_hand():
     assert flops.resnet50_train_flops_per_image(224) == 3 * 4.1e9
     assert flops.resnet50_train_flops_per_image(32) == \
         3 * 4.1e9 * (32 / 224) ** 2
-
-
-def test_bench_delegates_to_flops_module():
-    """bench.py is a CONSUMER now: its wrappers must return exactly what
-    the module does (the lift left no second copy of the math)."""
-    import importlib.util
-
-    from paddle_tpu.models.gpt import GPTConfig
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    cfg = GPTConfig(vocab_size=1024, hidden_size=256, num_layers=4,
-                    num_heads=8, max_seq_len=128)
-    assert bench._train_flops_per_token(cfg) == \
-        flops.gpt_train_flops_per_token(cfg)
-    assert bench._peak_flops(_Dev("TPU v5p")) == flops.peak_flops("v5p")
 
 
 # -- goodput over recorded train_step spans ---------------------------------

@@ -259,7 +259,7 @@ def test_generate_and_stream_release_requests(model):
 
 def test_metrics_schedule_view_and_snapshot(model):
     """Metrics export in the shape xplane.print_schedule_analysis consumes
-    and as a flat JSON snapshot for bench.py."""
+    and as a flat JSON snapshot."""
     import io
     import json
 
@@ -272,7 +272,7 @@ def test_metrics_schedule_view_and_snapshot(model):
     json.dumps(snap)  # JSON-able end to end
     assert snap["counters"]["generated_tokens"] == 4
     assert "decode_step" in snap["latency"]
-    assert "ttft" in snap["latency"]  # time-to-first-token, for bench
+    assert "ttft" in snap["latency"]  # time-to-first-token
     assert snap["latency"]["ttft"]["p95_ms"] >= snap["latency"]["ttft"]["p50_ms"]
     view = engine.metrics.schedule_view()
     st = view["serving-engine"]
