@@ -5,9 +5,10 @@
 Drives both hot paths once through the entry points a user calls, at the
 full width of models the repo ships, with seeded random weights:
 
-- kernels: the ragged paged-attention kernel (bf16 and int8 arenas, every
-  width the serve phase compiles) and the three flash kernels (forward,
-  dK/dV, dQ), compiled (not interpreted), against their XLA references;
+- kernels: the ragged paged-attention kernel and the append's block writer
+  (bf16 and int8 arenas, every width the serve phase compiles) and the
+  three flash kernels (forward, dK/dV, dQ), compiled (not interpreted),
+  against their XLA references, the writer bit for bit;
 - serve:   `gpt_1p3b` in bf16 behind `ServingServer` on 127.0.0.1:0, nine
   requests over `/v1/completions`, one of them streamed (SSE);
 - train:   `gpt_small` in bf16, AdamW, five `make_sharded_train_step` steps;
@@ -255,6 +256,67 @@ def check_ragged_kernel(key, arenas, *, quant, width, interpret, batch,
                   f"{'int8' if quant else 'bf16'} arena")
 
 
+def check_append_writer(key, arenas, *, quant, width, interpret, batch,
+                        heads, head_dim, block_size, max_blocks):
+    """The append's block writer (ops/pallas/paged_kv_append.py) against
+    the row scatter it stands in for (`serving/block_pool.py _append`), K
+    and V in one call, into layer 1 of the arenas: every element outside
+    the null block equal, the other layer's included. Rows as
+    `ragged_batch` draws them, their scatter targets from
+    `block_pool.positions_to_slots`, the last lane idle. Returns the blocks
+    written, an arena."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.pallas.paged_kv_append import (
+        append_blocks,
+        paged_kv_append,
+    )
+    from paddle_tpu.serving.block_pool import positions_to_slots
+
+    seed = int(jax.random.randint(key, (), 0, 2**30))
+    m, _ = ragged_batch(seed, batch=batch, width=width,
+                        block_size=block_size, max_blocks=max_blocks)
+    tables, past, counts, live = (np.array(m[k]) for k in (
+        "tables", "q_start", "q_lens", "kv_live"))
+    counts[-1] = 0
+    slots, offs = map(np.stack, zip(*(
+        positions_to_slots(block_size, tables[i, :live[i]], past[i],
+                           counts[i], width)
+        for i in range(batch))))
+    shape = (batch, width, heads, head_dim)
+    kk, kv = jax.random.split(key)
+    if quant:
+        old = arenas["k8"], arenas["v8"]
+        new = tuple(jax.random.randint(x, shape, -127, 128).astype(
+            jnp.float32) for x in (kk, kv))
+    else:
+        old = arenas["k"], arenas["v"]
+        new = tuple(jax.random.normal(x, shape, jnp.bfloat16)
+                    for x in (kk, kv))
+
+    @jax.jit
+    def compare(old, new, slots, offs):
+        got = paged_kv_append(old, 1, new, slots, offs, interpret=interpret)
+        heads_ = jnp.arange(heads, dtype=jnp.int32)
+        same, moved = [], []
+        for g, a, x in zip(got, old, new):
+            want = a.at[1, heads_, slots[..., None], offs[..., None]].set(
+                x.astype(a.dtype))
+            same.append(jnp.all(g[:, :, 1:] == want[:, :, 1:]))
+            moved.append(jnp.sum(want[:, :, 1:] != a[:, :, 1:]))
+        return jnp.stack(same), jnp.stack(moved)
+
+    same, moved = compare(old, new, jnp.asarray(slots), jnp.asarray(offs))
+    what = (f"append writer width {width} "
+            f"{'int8' if quant else 'bf16'} arena")
+    require(int(np.min(moved)) > 0, f"{what}: the scatter moved nothing")
+    require(bool(np.all(same)), f"{what}: differs from the row scatter "
+            f"outside the null block (K, V equal: {np.asarray(same)})")
+    return append_blocks(slots)
+
+
 def check_flash_kernels(key, *, batch, seq, heads, head_dim):
     """The flash forward and both backward kernels against _attention_xla
     and its jax.grad, through the dispatch the model calls."""
@@ -324,6 +386,13 @@ def kernel_phase(*, ragged_shapes, widths, flash_shapes, seed=0):
                 log(f"ragged kernel heads={shape['heads']} width={width} "
                     f"arena={'int8' if quant else 'bf16'}: worst |err| "
                     f"{err:.3g}")
+                key, sub = jax.random.split(key)
+                blocks = check_append_writer(sub, arenas, quant=quant,
+                                             width=width,
+                                             interpret=interpret, **shape)
+                log(f"append writer heads={shape['heads']} width={width} "
+                    f"arena={'int8' if quant else 'bf16'}: {blocks} blocks "
+                    f"equal to the row scatter's")
         del arenas
     for shape in flash_shapes:
         key, sub = jax.random.split(key)

@@ -1,9 +1,11 @@
-"""Pallas TPU kernels for hot ops (flash attention, ragged paged attention).
+"""Pallas TPU kernels for hot ops (flash attention, ragged paged attention,
+the paged arena's append).
 
 Reference parity: the role of paddle/phi/kernels/gpu/flash_attn_kernel.cu +
 dynload/flashattn.cc in /root/reference — except the kernels are written in
 Pallas/Mosaic against VMEM/MXU instead of binding an external CUDA library.
 `_backend.py` holds the shared dispatch gate (TPU compiled / CPU interpret /
 XLA fallback); `paged_attention.py` is the serving engine's ragged
-mixed-batch attention over the paged KV arena.
+mixed-batch attention over the paged KV arena and `paged_kv_append.py` the
+block writer that puts a step's new K/V into it.
 """

@@ -1,4 +1,5 @@
-"""Shared backend gate for the Pallas kernels (flash + ragged paged attn).
+"""Shared backend gate for the Pallas kernels (flash, ragged paged attn and
+the paged arena's append).
 
 One policy, two env knobs, checked in this order:
 

@@ -46,7 +46,9 @@ Kernel design (TPU):
   HBM and then refuses to slice it.
 
 The dispatch (`paged_attention_arrays`) is the seam `serving/block_pool.py`
-calls after scattering the step's new K/V into the arena: Pallas on TPU (or
+calls after appending the step's new K/V to the arena (by the block writer
+of paged_kv_append.py wherever this kernel runs: `paged_kernels_off` is the
+one rule for both): Pallas on TPU (or
 interpreted when PADDLE_TPU_FORCE_PALLAS_INTERPRET is set), XLA gather
 everywhere else. The fallback gathers into the SAME
 ``[rows, seq, heads, head_dim]`` layout and einsum as `models/gpt.py`'s
@@ -398,6 +400,22 @@ def ragged_paged_attention(q, k_arena, v_arena, layer, block_tables,
 # dispatch — the seam serving/block_pool.py calls
 # ---------------------------------------------------------------------------
 
+def paged_kernels_off(head_dim):
+    """Why the paged arena's two kernels, the append's block writer
+    (paged_kv_append.py) and the ragged walk, cannot run here, or None
+    where they can. One rule for both dispatches
+    (`serving/block_pool.py _append`, `paged_attention_arrays`), so an
+    engine never appends by one and attends by another."""
+    if not use_pallas():
+        return "kernels off"
+    if head_dim % 128 and not interpret_mode():
+        # Mosaic (jaxlib 0.9.0) refuses to slice an HBM array whose minor
+        # dimension it pads to the 128 lanes, which both kernels' block
+        # copies do (PERF.md section 7)
+        return "head size not a multiple of 128"
+    return None
+
+
 def ragged_paged_attention_sharded(q, k_arena, v_arena, layer, block_tables,
                                    q_start, kv_live, q_lens=None,
                                    mesh=None, tp_axis="tp",
@@ -471,18 +489,12 @@ def paged_attention_arrays(q, k_arena, v_arena, layer, block_tables, qpos,
     no wrapper — GSPMD partitions the padded gather over the arena's
     head sharding on its own (and its causal qpos mask already discards
     dead query rows, so it ignores q_lens)."""
-    why_xla = None
     if q_start is None or kv_live is None:
         why_xla = "no ragged metadata (q_start / kv_live)"
     elif scale is not None:  # kernel bakes 1/sqrt(D)
         why_xla = "custom softmax scale"
-    elif not use_pallas():
-        why_xla = "kernels off"
-    elif q.shape[-1] % 128 and not interpret_mode():
-        # Mosaic (jaxlib 0.9.0) refuses to slice an HBM array whose minor
-        # dimension it pads to the 128 lanes, which the walk's page copies
-        # do (PERF.md section 7)
-        why_xla = "head size not a multiple of 128"
+    else:
+        why_xla = paged_kernels_off(q.shape[-1])
     if why_xla is not None:
         # off-TPU this is the default path; on a tpu backend it is
         # counted and logged, never quiet

@@ -6,11 +6,13 @@ Attention"): K/V live in ONE fixed-shape, head-major arena
 owns a list of block ids. Head-major is the Pallas-friendly layout: each
 (layer, head, block) slice is a contiguous ``[block_size, head_dim]`` tile
 the ragged kernel DMAs straight from HBM (ops/pallas/paged_attention.py).
-Appending tokens is a fixed-shape ``.at[...].set`` scatter of ``head_dim``
-rows (`_append`: the arena keeps the kernel's layout); attention runs
-through `PagedLayerView.attend`, which dispatches to the ragged Pallas
-kernel on TPU and to an XLA gather of the padded ``[rows, max_blocks]``
-block table everywhere else. Because every device op has a static shape, the whole
+Appending tokens (`_append`) is a fixed-shape write that keeps the arena in
+the kernel's layout: on TPU a Pallas writer of whole blocks
+(ops/pallas/paged_kv_append.py), everywhere else an ``.at[...].set``
+scatter of ``head_dim`` rows; attention runs through
+`PagedLayerView.attend`, which dispatches to the ragged Pallas kernel on
+TPU and to an XLA gather of the padded ``[rows, max_blocks]`` block table
+everywhere else. Because every device op has a static shape, the whole
 mixed prefill+decode serve compiles to two programs — no shape ever depends
 on how many requests are in flight or how long they are.
 
@@ -54,6 +56,22 @@ def blocks_for(num_tokens, block_size):
     exists) both delegate here so admission and construction bounds can
     never drift apart."""
     return max(1, -(-int(num_tokens) // int(block_size)))
+
+
+def positions_to_slots(block_size, blocks, start, count, width):
+    """(slots[width], offs[width]) scatter targets for token positions
+    [start, start+count) of a sequence that owns `blocks`; positions
+    beyond `count` go to the null block. `width` is the padded step
+    width. THE formula the append's writer leans on
+    (ops/pallas/paged_kv_append.py): live tokens are a prefix of the
+    width, at consecutive positions."""
+    pos = np.arange(width)
+    idx = (start + pos) // block_size
+    offs = ((start + pos) % block_size).astype(np.int32)
+    btab = np.asarray(blocks, np.int64)
+    valid = (pos < count) & (idx < len(btab))
+    slots = np.where(valid, btab[np.minimum(idx, len(btab) - 1)], 0)
+    return slots.astype(np.int32), np.where(valid, offs, 0).astype(np.int32)
 
 
 def chain_block_hashes(token_ids, block_size, salt=None):
@@ -126,7 +144,7 @@ class PagedLayerView:
             k_new = st.constrain(k_new, None, None, "tp", None)
             v_new = st.constrain(v_new, None, None, "tp", None)
         if st.k_scale is not None:
-            # int8 arena: quantize at the scatter, scales growing per
+            # int8 arena: quantize at the append, scales growing per
             # touched block (dequant happens inside the Pallas kernel /
             # before the XLA fallback's einsum)
             st.k, st.k_scale = _quantize_scatter(
@@ -136,8 +154,8 @@ class PagedLayerView:
                 st.v, st.v_scale, layer, v_new, st.slots, st.offs,
                 st.touched, st.touch_idx, st.mesh)
         else:
-            st.k = _append(st.k, layer, k_new, st.slots, st.offs, st.mesh)
-            st.v = _append(st.v, layer, v_new, st.slots, st.offs, st.mesh)
+            st.k, st.v = _append((st.k, st.v), layer, (k_new, v_new),
+                                 st.slots, st.offs, st.mesh)
         return Tensor._from_op(paged_attention_arrays(
             q, st.k, st.v, layer, st.block_tables, st.qpos,
             q_start=st.q_start, kv_live=st.kv_live, q_lens=st.q_lens,
@@ -261,45 +279,75 @@ class PagedState:
         )
 
 
-def _append(arena, layer, new, slots, offs, mesh):
-    """Write the step's tokens `new` [B, S, H, D] to their homes
-    ``arena[layer, h, slots, offs, :]``: THE append of the step programs,
-    float and int8 alike.
+def _append(arenas, layer, news, slots, offs, mesh):
+    """Write the step's tokens, each of `news` [B, S, H, D], to their homes
+    ``arena[layer, h, slots, offs, :]`` in its arena of the tuple `arenas`
+    (K and V, or one int8 payload): THE append of the step programs, float
+    and int8 alike. Returns the updated arenas, a tuple.
 
-    The head is an INDEX of the scatter, not part of its window: each
+    Where the paged kernels run (`paged_kernels_off`, the rule
+    `paged_attention_arrays` attends by) the tokens go in as whole blocks
+    through the Pallas writer (ops/pallas/paged_kv_append.py), one call
+    for the arenas it is given; elsewhere, and counted on a tpu backend,
+    through the row scatter below.
+
+    In the scatter the head is an INDEX, not part of its window: each
     update is one ``head_dim`` row at a ``(head, block, offset)``, which is
-    how the ragged kernel addresses the arena too. With a ``[heads,
-    head_dim]`` window the TPU compiler lays the arena out heads-minor for
-    the scatter and row-major for the Pallas call that follows, and relays
-    the whole arena between the two in every layer (77 % of a decode
-    step's device time on a v5e; PERF.md section 6, PR 25); with the
-    ``[head_dim]`` window the scatter runs on a bitcast of the
-    row-major arena, which then keeps one layout from the program's
-    parameter to its donated output.
+    how the kernels address the arena too. With a ``[heads, head_dim]``
+    window the TPU compiler lays the arena out heads-minor for the scatter
+    and row-major for the Pallas call that follows, and relays the whole
+    arena between the two in every layer (77 % of a decode step's device
+    time on a v5e; PERF.md section 6, PR 25); with the ``[head_dim]``
+    window the scatter runs on a bitcast of the row-major arena, which
+    then keeps one layout from the program's parameter to its donated
+    output, as it does under the writer, whose arenas are aliased.
 
     Padded and inactive tokens carry slot 0 (the null block); their rows
-    collide there, which is what the null block is for. Under a tp `mesh`
-    the write runs per shard over the head axis (the specs of
-    `ragged_paged_attention_sharded`): each chip scatters its own heads
-    with the replicated block ids, and GSPMD has no index array to gather.
+    collide there, which is what the null block is for (the writer leaves
+    it alone). Under a tp `mesh` the write runs per shard over the head
+    axis (the specs of `ragged_paged_attention_sharded`): each chip writes
+    its own heads with the replicated block ids, and GSPMD has no index
+    array to gather.
     """
     import jax.numpy as jnp
 
-    def write(arena, new, slots, offs):
-        heads = jnp.arange(arena.shape[1], dtype=jnp.int32)
-        return arena.at[layer, heads, slots[..., None], offs[..., None]].set(
-            new.astype(arena.dtype))
+    from ..ops.pallas._backend import interpret_mode, note_xla_fallback
+    from ..ops.pallas.paged_attention import paged_kernels_off
+    from ..ops.pallas.paged_kv_append import paged_kv_append
 
-    if mesh is not None and mesh.shape.get("tp", 1) > 1:
+    def per_shard(write, n):
+        """`write(*arenas, *news, slots, offs) -> arenas`, `n` arenas, run
+        on each chip's heads."""
+        if mesh is None or mesh.shape.get("tp", 1) == 1:
+            return write
         from jax.sharding import PartitionSpec as P
 
         from ..parallel._compat import shard_map
 
-        write = shard_map(
+        arena, new = P(None, "tp"), P(None, None, "tp", None)
+        return shard_map(
             write, mesh=mesh,
-            in_specs=(P(None, "tp"), P(None, None, "tp", None), P(), P()),
-            out_specs=P(None, "tp"))
-    return write(arena, new, slots, offs)
+            in_specs=(arena,) * n + (new,) * n + (P(), P()),
+            out_specs=(arena,) * n)
+
+    why_xla = paged_kernels_off(arenas[0].shape[-1])
+    if why_xla is None:
+        n, interpret = len(arenas), interpret_mode()
+
+        def blocks(*a):
+            return paged_kv_append(a[:n], layer, a[n:2 * n], *a[2 * n:],
+                                   interpret=interpret)
+
+        return per_shard(blocks, n)(*arenas, *news, slots, offs)
+    note_xla_fallback("paged_kv_append", why_xla)
+
+    def rows(arena, new, slots, offs):
+        heads = jnp.arange(arena.shape[1], dtype=jnp.int32)
+        return (arena.at[layer, heads, slots[..., None],
+                         offs[..., None]].set(new.astype(arena.dtype)),)
+
+    return tuple(per_shard(rows, 1)(arena, new, slots, offs)[0]
+                 for arena, new in zip(arenas, news))
 
 
 def _quantize_scatter(arena, scales, layer, new, slots, offs, touched,
@@ -353,7 +401,8 @@ def _quantize_scatter(arena, scales, layer, new, slots, offs, touched,
     tok_sc = new_sc.T[gidx].reshape(B, S, H)                # [B, S, H]
     qn = jnp.clip(jnp.round(new.astype(jnp.float32) / tok_sc[..., None]),
                   -127, 127)
-    return _append(arena, layer, qn, slots, offs, mesh), scales
+    arena, = _append((arena,), layer, (qn,), slots, offs, mesh)
+    return arena, scales
 
 
 class BlockPool:
@@ -677,13 +726,6 @@ class BlockPool:
         return t
 
     def positions_to_slots(self, blocks, start, count, width):
-        """(slots[width], offs[width]) scatter targets for token positions
-        [start, start+count); positions beyond `count` go to the null
-        block. `width` is the padded step width."""
-        pos = np.arange(width)
-        idx = (start + pos) // self.block_size
-        offs = ((start + pos) % self.block_size).astype(np.int32)
-        btab = np.asarray(blocks, np.int64)
-        valid = (pos < count) & (idx < len(btab))
-        slots = np.where(valid, btab[np.minimum(idx, len(btab) - 1)], 0)
-        return slots.astype(np.int32), np.where(valid, offs, 0).astype(np.int32)
+        """`positions_to_slots` (module level) at this pool's block size."""
+        return positions_to_slots(self.block_size, blocks, start, count,
+                                  width)

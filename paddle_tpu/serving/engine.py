@@ -120,6 +120,7 @@ import numpy as np
 
 from ..core.functional import functional_call, state_dict_arrays
 from ..ops.pallas.paged_attention import ragged_grid_steps
+from ..ops.pallas.paged_kv_append import append_blocks, blocks_per_row
 from . import faults
 from .block_pool import (BlockPool, PagedState, blocks_for,
                          chain_block_hashes)
@@ -1306,10 +1307,11 @@ class LLMEngine:
     def _touched_width(self, W):
         """Columns in the quantized step's per-row ``touched`` block
         list: ``W`` consecutive fed positions straddle at most
-        ``(W + bs - 2) // bs + 1`` arena blocks, plus slot 0 reserved
+        `blocks_per_row` arena blocks (the writer's count, ops/pallas/
+        paged_kv_append.py), plus slot 0 reserved
         for the null block — part of the compiled (B, W) shape key, so
         it must be THE one formula for both tracing and marshalling."""
-        return (W + self.block_size - 2) // self.block_size + 2
+        return blocks_per_row(W, self.block_size) + 1
 
     def expected_program_count(self):
         """THE program-count contract, in one place: the engine compiles
@@ -1868,7 +1870,9 @@ class LLMEngine:
                               last_idx + 1 + spec_lens, 0)
             live, total = ragged_grid_steps(q_lens, a["kv_live"], W,
                                             self.block_size)
-            self._span_args.update(grid_live=live, grid_total=total)
+            # and what the append's writer copies back, an arena a layer
+            self._span_args.update(grid_live=live, grid_total=total,
+                                   append_blocks=append_blocks(a["slots"]))
         t_disp = time.monotonic() if tr is not None else 0.0
         packed_dev = self._run_step(fn, a, last_idx, spec_lens,
                                     step_id=step_id, t_disp=t_disp)

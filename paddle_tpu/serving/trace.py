@@ -20,7 +20,8 @@ exactly those two views as Chrome/Perfetto trace events:
   the sampled tokens), ``emit`` (token emission), tagged with the batch
   composition (decode rows, prefill chunks, spec lanes), program kind
   (mixed/decode/verify), token counts, the ragged kernel's live and
-  total grid steps (``grid_live``/``grid_total``) and the hand-over to
+  total grid steps (``grid_live``/``grid_total``), the blocks the
+  append's writer copies back (``append_blocks``) and the hand-over to
   the device inside ``dispatch`` (``h2d_arrays``/``h2d_ms``). Pool
   evictions land as instants on a ``block-pool`` track.
 

@@ -53,6 +53,24 @@ def test_kernel_phase_catches_a_wrong_kernel(monkeypatch):
             widths=(5,), flash_shapes=[])
 
 
+def test_kernel_phase_catches_a_wrong_writer(monkeypatch):
+    """The writer's check has teeth too: tokens laid one row off their
+    offsets fail the phase."""
+    from paddle_tpu.ops.pallas import paged_kv_append as module
+
+    real = module.paged_kv_append
+
+    def shifted(arenas, layer, news, slots, offs, **kw):
+        return real(arenas, layer, news, slots, (offs + 1) % 16, **kw)
+
+    monkeypatch.setattr(module, "paged_kv_append", shifted)
+    with pytest.raises(chip_smoke.SmokeFailure, match="append writer"):
+        chip_smoke.kernel_phase(
+            ragged_shapes=[dict(batch=3, heads=2, head_dim=32,
+                                block_size=16, max_blocks=4)],
+            widths=(5,), flash_shapes=[])
+
+
 def test_serve_phase_tiny():
     paddle.seed(0)
     chip_smoke.serve_phase(

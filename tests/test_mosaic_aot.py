@@ -7,9 +7,9 @@ never runs, so numerics on the chip stay `chip_smoke.py`'s to check.
 
 It also shows what the device plane will call each kernel: the TPU compiler
 names a Pallas custom call after the last scope in front of it, so the
-compiled text holds `%ragged_paged_attention(.N)`, `%flash_fwd`,
-`%flash_bwd_dkv`, `%flash_bwd_dq`, which is what a reader of a device capture
-matches (`benchmark/layer_metrics/kernel.*`).
+compiled text holds `%ragged_paged_attention(.N)`, `%paged_kv_append(.N)`,
+`%flash_fwd`, `%flash_bwd_dkv`, `%flash_bwd_dq`, which is what a reader of a
+device capture matches (`benchmark/layer_metrics/kernel.*`).
 
 Runs in a subprocess: the topology client is libtpu's, and nothing it does
 should be able to take the test process down with it. Skips when libtpu
@@ -51,6 +51,7 @@ def sds(shape, dtype):
 
 
 NAMES = {}     # case -> {custom call's instruction name, no .N: how many}
+SCATTERS = {}  # case -> scatter instructions in the compiled text
 
 
 def kernel_names(compiled):
@@ -69,6 +70,7 @@ def compile_for_v5e(fn, *args, case=None):
     compiled = lowered.compile()   # the TPU compiler; raises on a refusal
     if case is not None:
         NAMES[case] = kernel_names(compiled)
+        SCATTERS[case] = len(re.findall(r" scatter\(", compiled.as_text()))
     return n
 
 
@@ -93,6 +95,25 @@ for quant in (False, True):
 
         case = f"ragged-{'int8' if quant else 'bf16'}-w{width}"
         assert compile_for_v5e(ragged, *args, case=case) == 1, case
+
+# the append's block writer, K and V in one call as the float step programs
+# make it, at the same shapes; f32 is the tests' arena, int8 takes the
+# quantized tokens as f32
+from paddle_tpu.ops.pallas.paged_kv_append import paged_kv_append
+
+for dt in ("bfloat16", "float32", "int8"):
+    for width in (1, 5, 8, 128):
+        arena = sds((2, H, NB, bs, D), dt)
+        new = sds((B, width, H, D), "bfloat16" if dt == "bfloat16"
+                  else "float32")
+        targets = sds((B, width), "int32")
+
+        def append(k, v, k_new, v_new, slots, offs):
+            return paged_kv_append((k, v), 1, (k_new, v_new), slots, offs)
+
+        case = f"append-{dt}-w{width}"
+        assert compile_for_v5e(append, arena, arena, new, new, targets,
+                               targets, case=case) == 1, case
 
 # heads of 64: Mosaic pads the arena's minor dimension to the 128 lanes in
 # HBM and then refuses the page copies' slices of it, which is why
@@ -164,6 +185,15 @@ for W in engine.width_buckets:
     compile_for_v5e(engine._get_step_fn(B, W), *step_args(engine, W),
                     case=f"engine-step-w{W}")
 
+# heads of 64: the one rule (`paged_kernels_off`) keeps both the walk and the
+# append on their XLA paths, so the program holds no kernel and the scatter
+engine = LLMEngine(
+    GPT(GPTConfig(vocab_size=256, hidden_size=128, num_layers=2, num_heads=2,
+                  max_seq_len=256, dropout=0.0)),
+    block_size=16, max_batch=4, max_seq_len=256, prefill_chunk=32)
+compile_for_v5e(engine._get_step_fn(B, 1), *step_args(engine, 1),
+                case="engine-step-head64-w1")
+
 # the arena through a step program: appended to and read by the kernel in
 # one layout, so the compiler relays it nowhere. Heads of 128 and arenas of
 # 128 MiB: a smaller one the compiler keeps in S(1) when it does relay it,
@@ -193,6 +223,7 @@ for kv_dtype, arena_dtype in ((None, "float32"), ("int8", "int8")):
             "alias_bytes": mem.alias_size_in_bytes}
 print("ARENA_FACTS " + json.dumps(ARENA))
 print("KERNEL_NAMES " + json.dumps(NAMES))
+print("SCATTERS " + json.dumps(SCATTERS))
 print("MOSAIC_OK")
 '''
 
@@ -201,12 +232,17 @@ FLASH = {"flash_fwd": 1, "flash_bwd_dkv": 1, "flash_bwd_dq": 1}
 EXPECTED = {
     **{f"ragged-{dt}-w{w}": {"ragged_paged_attention": 1}
        for dt in ("bf16", "int8") for w in (1, 5, 128)},
+    **{f"append-{dt}-w{w}": {"paged_kv_append": 1}
+       for dt in ("bfloat16", "float32", "int8") for w in (1, 5, 8, 128)},
     "flash-grad-d64": FLASH,
     "flash-grad-d128": FLASH,
     "flash-grad-remat": FLASH,
-    # gpt of two layers; LLMEngine's default width buckets
-    "engine-step-w1": {"ragged_paged_attention": 2},
-    "engine-step-w32": {"ragged_paged_attention": 2},
+    # gpt of two layers; LLMEngine's default width buckets: a layer's K and
+    # V go in by one writer call and are read by one ragged call
+    "engine-step-w1": {"paged_kv_append": 2, "ragged_paged_attention": 2},
+    "engine-step-w32": {"paged_kv_append": 2, "ragged_paged_attention": 2},
+    # heads of 64: no kernel at all (`paged_kernels_off`)
+    "engine-step-head64-w1": {},
 }
 
 
@@ -250,15 +286,27 @@ def test_compiled_text_names_each_kernel(aot, case):
     assert _facts(aot, "KERNEL_NAMES")[case] == EXPECTED[case]
 
 
+@pytest.mark.parametrize("case,scatters", [
+    ("engine-step-w1", 0), ("engine-step-w32", 0),
+    ("engine-step-head64-w1", 4)])
+def test_one_append_ships_a_program(aot, case, scatters):
+    """With heads of 128 the compiled step program holds the writer and no
+    scatter at all; with heads of 64 it keeps the row scatter, K's and V's
+    a layer, and holds no kernel (`test_compiled_text_names_each_kernel`)."""
+    assert _facts(aot, "SCATTERS")[case] == scatters
+
+
 @pytest.mark.parametrize("case", ["float32-w1", "float32-w32",
                                   "int8-w1", "int8-w32"])
 def test_step_program_keeps_the_arena_in_one_layout(aot, case):
-    """The append (`serving/block_pool.py _append`) addresses the arena as
-    the ragged kernel does, so the compiled step program holds no copy of
-    an arena, needs less than one arena of temporaries, and still aliases
-    the donated arenas to its outputs. With the head in the scatter's
-    window (PR 24 and before) it held 6 to 8 copies and up to three arenas
-    of temporaries at this size, 9 GiB beside the benchmark's 6 GiB."""
+    """The append (`serving/block_pool.py _append`: the Pallas block writer
+    here, its arenas aliased; the row scatter before PR 33) addresses the
+    arena as the ragged kernel does, so the compiled step program holds no
+    copy of an arena, needs less than one arena of temporaries, and still
+    aliases the donated arenas to its outputs. With the head in the
+    scatter's window (PR 24 and before) it held 6 to 8 copies and up to
+    three arenas of temporaries at this size, 9 GiB beside the benchmark's
+    6 GiB."""
     f = _facts(aot, "ARENA_FACTS")[case]
     assert f["arena_copies"] == 0, f
     assert f["temp_bytes"] < f["arena_bytes"], f

@@ -349,8 +349,8 @@ def test_append_writes_what_the_windowed_scatter_wrote(kind, tp):
         smesh = build_serving_mesh(tp)
         mesh = smesh.mesh
         arena = jax.device_put(arena, smesh.arena_sharding())
-    got = jax.jit(_append, static_argnums=(1, 5))(
-        arena, layer, new, jnp.asarray(slots), jnp.asarray(offs), mesh)
+    got, = jax.jit(_append, static_argnums=(1, 5))(
+        (arena,), layer, (new,), jnp.asarray(slots), jnp.asarray(offs), mesh)
     assert got.dtype == want.dtype
     assert np.array_equal(np.asarray(got)[:, :, 1:],
                           np.asarray(want)[:, :, 1:])

@@ -5,6 +5,8 @@ scheduler.py `_admit`; ops/pallas/paged_attention.py `ragged_grid_steps`):
 - ``grid_live`` / ``grid_total``: the iterations of the ragged kernel's
   walk and, added to them, the grid steps that ran none, a layer, against a
   brute-force walk of the kernel's own conditions;
+- ``append_blocks``: the blocks the append's writer copies back, an arena a
+  layer, against a count by hand;
 - ``h2d_arrays`` / ``h2d_ms``: the host arrays handed to the device and
   what the hand-over took, inside the ``dispatch`` phase;
 - ``queue_wait``: arrival to first admission, observed like ``ttft``, with
@@ -151,6 +153,26 @@ def test_grid_live_follows_the_rows_contexts(model, monkeypatch):
         assert a["grid_live"] == (7 + n) // 8 + 1 + (15 + n) // 8 + 1
         assert a["grid_total"] == a["grid_live"]
     assert decode[-1]["grid_live"] > decode[0]["grid_live"]
+
+
+def test_append_blocks_is_the_blocks_a_step_writes(model):
+    """`append_blocks`: what the append's writer copies back, an arena a
+    layer (`ops/pallas/paged_kv_append.py append_blocks`), by hand. Blocks
+    of 8: prompts of 7 and 15 tokens from position 0 fill one and two; a
+    decode step writes one token a row, one block each; rows that run out
+    leave lanes that write none."""
+    engine = _engine(model, trace=1.0, max_batch=2, prefill_chunk=16)
+    for prompt, n in zip(_prompts((7, 15), seed=3), (10, 6)):
+        engine.add_request(prompt, max_new_tokens=n)
+    while engine.has_unfinished():
+        engine.step()
+    steps = [s["args"] for s, _ in _steps(engine)]
+    assert steps[0]["kind"] == "mixed" and steps[0]["fed_tokens"] == 22
+    assert steps[0]["append_blocks"] == 1 + 2
+    decode = [a for a in steps if a["kind"] == "decode"]
+    assert {a["rows"] for a in decode} == {1, 2}
+    for a in decode:
+        assert a["append_blocks"] == a["rows"]
 
 
 @pytest.mark.parametrize("trace", [None, 1.0], ids=["untraced", "traced"])
