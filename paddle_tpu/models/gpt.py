@@ -287,6 +287,12 @@ class GPT(nn.Layer):
             )
         return logits, (caches if paged else new_caches)
 
+    @property
+    def kv_slabs(self):
+        """Cache slabs a token's keys and values take in a paged arena
+        (serving/block_pool.py): one a layer."""
+        return self.cfg.num_layers
+
     def init_caches(self, batch_size, max_len, dtype=None):
         """Fixed-size per-layer KV caches for incremental decode. dtype
         defaults to the model's parameter dtype (bf16 models get bf16

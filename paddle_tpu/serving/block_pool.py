@@ -200,7 +200,9 @@ class PagedState:
     """Traced arena + step metadata threaded through GPT.forward.
 
     Arrays (all fixed-shape, jnp):
-      k, v          [layers, heads, num_blocks, block_size, head_dim]
+      k, v          [slabs, heads, num_blocks, block_size, head_dim]; a slab
+                    is one layer's cache (a looped model's: one (pass,
+                    layer)'s, the model's `kv_slabs`)
       block_tables  [B, max_blocks] int32 (padded with 0 = null block)
       slots         [B, S] int32 — destination block id of each new token
       offs          [B, S] int32 — destination offset inside that block
@@ -262,7 +264,23 @@ class PagedState:
         self.lora = lora
 
     def layer(self, i):
+        """The view of cache slab `i` (a layer's; a looped model's (pass,
+        layer)'s). `i` may be traced."""
         return PagedLayerView(self, i)
+
+    def arenas(self):
+        """The arena arrays a layer's `attend` replaces, as a tuple: what a
+        model that runs its layers inside a `lax` loop carries through it
+        (models/looped.py), without knowing which arrays an engine has."""
+        if self.k_scale is None:
+            return (self.k, self.v)
+        return (self.k, self.v, self.k_scale, self.v_scale)
+
+    def set_arenas(self, arenas):
+        if self.k_scale is None:
+            self.k, self.v = arenas
+        else:
+            self.k, self.v, self.k_scale, self.v_scale = arenas
 
     def constrain(self, arr, *spec):
         """`with_sharding_constraint` on the serving mesh — the explicit
@@ -420,6 +438,11 @@ class BlockPool:
     double-free that would alias two sequences onto one block — raises.
     `positions_to_slots` maps token positions to (block, offset) scatter
     targets for a sequence's block list.
+
+    `num_layers` is the arena's leading axis, the model's `kv_slabs`: one
+    slab a layer, or a looped model's (pass, layer). The bookkeeping here
+    is per LOGICAL block whatever that count: a block id names the same
+    `block_size` token slots in every slab.
     """
 
     def __init__(self, num_blocks, num_layers, block_size, num_heads,

@@ -140,6 +140,19 @@ def _env_flag(name, default):
     return v.strip().lower() not in ("0", "false", "off", "no", "")
 
 
+def _refuse_option(model, option, asked, why):
+    """Three of the engine's options are written against GPT's modules:
+    "tp" (`validate_model` and the Megatron placement), "quantize"
+    (AdaRound's calibration walks `blk.attn.qkv`) and "lora" (targets named
+    after GPT's projections). A model that names the ones it takes
+    (`serving_options`) is refused the others here, with a sentence and not
+    an AttributeError from inside them; a model that names none is GPT."""
+    takes = getattr(model, "serving_options", None)
+    if takes is not None and option not in takes:
+        raise ValueError(
+            f"{asked} is not available for {type(model).__name__}: {why}")
+
+
 def _adaround_model_int8(model, calib_prompts, iters=300):
     """Int8 weight quantization for a GPT serving model: AdaRound
     (quantization/adaround.py `learn_rounding`) on every tp-parallel
@@ -235,7 +248,14 @@ class LLMEngine:
             mesh = env_tp if env_tp > 1 else None
         self._smesh = as_serving_mesh(mesh)
         if self._smesh is not None:
+            _refuse_option(
+                model, "tp", "mesh= (tensor-parallel serving)",
+                "the tp placement and its collective budget are written "
+                "for GPT's fused-QKV block; serve it on one chip")
             self._smesh.validate_model(cfg)
+        # cache slabs a token's K/V take: the model says (a GPT: one a
+        # layer; a looped model: one a (pass, layer))
+        self.kv_slabs = int(model.kv_slabs)
         # int8 KV arena (`kv_dtype="int8"` / PADDLE_TPU_KV_DTYPE): payload
         # bytes quarter (vs f32) and the SAME kv_hbm_bytes budget admits
         # ~4x the blocks — behind the parity/perplexity quality gates in
@@ -256,6 +276,10 @@ class LLMEngine:
             if quantize != "int8":
                 raise ValueError(
                     f"quantize={quantize!r} not supported — only 'int8'")
+            _refuse_option(
+                model, "quantize", "quantize='int8'",
+                "AdaRound's calibration walks GPT's qkv/proj/fc1/fc2 "
+                "modules (kv_dtype='int8' does not depend on the model)")
             if checkpoint_path is not None:
                 raise ValueError(
                     "checkpoint_path and quantize are mutually exclusive: "
@@ -323,11 +347,10 @@ class LLMEngine:
             # An int8 arena prices blocks at itemsize 1 plus the f32
             # scale-sidecar overhead — this is where the same budget
             # starts admitting ~4x (f32) / ~2x (bf16) the sequences.
-            dt_probe = model.wte.weight._array.dtype
             num_blocks = kv_capacity_blocks(
-                kv_hbm_bytes, cfg.num_layers, cfg.num_heads,
-                self.block_size, cfg.hidden_size // cfg.num_heads,
-                1 if self.kv_quantized else dt_probe.itemsize,
+                kv_hbm_bytes, self.kv_slabs, cfg.num_heads,
+                self.block_size, self._head_dim(),
+                1 if self.kv_quantized else self._weight_dtype().itemsize,
                 tp_degree=(1 if self._smesh is None
                            else self._smesh.tp_degree),
                 scale_itemsize=4 if self.kv_quantized else 0,
@@ -539,10 +562,9 @@ class LLMEngine:
                     "fit one chip. Serve it from a sharded checkpoint "
                     "(LLMEngine(skeleton, checkpoint_path=..., mesh=N)) "
                     "so no chip ever materializes the full tree")
-        dt = model.wte.weight._array.dtype
         self.pool = BlockPool(
-            num_blocks, cfg.num_layers, self.block_size, cfg.num_heads,
-            cfg.hidden_size // cfg.num_heads, dtype=dt,
+            num_blocks, self.kv_slabs, self.block_size, cfg.num_heads,
+            self._head_dim(), dtype=self._weight_dtype(),
             metrics=self.metrics, tracer=self.tracer,
             sharding=(None if self._smesh is None
                       else self._smesh.arena_sharding()),
@@ -577,6 +599,10 @@ class LLMEngine:
         # reports blocks
         self.metrics.set_gauge("kv_bytes_per_block",
                                self.pool.bytes_per_block())
+        self.metrics.set_gauge("kv_slabs", self.kv_slabs)
+        self.metrics.set_gauge(
+            "kv_bytes_per_token",
+            self.pool.bytes_per_block() / self.block_size)
         self.metrics.set_info("kv", {"dtype": self.pool.kv_dtype})
         # scheduling policy (serving/policy.py): priority classes,
         # windowed tenant fairness, deadline early-reject. None (the
@@ -610,6 +636,10 @@ class LLMEngine:
         if self.lora_slots:
             from ..models import lora as lora_mod
 
+            _refuse_option(
+                model, "lora", "lora_slots",
+                "the adapter tables are laid out a GPT layer and target "
+                "GPT's projections (models/lora.py LORA_TARGETS)")
             if self.lora_rank < 1:
                 raise ValueError("lora_rank must be >= 1 with lora_slots")
             self.lora_targets = tuple(lora_targets
@@ -650,6 +680,16 @@ class LLMEngine:
                           + (" + programs compiled" if warmup else ""))
 
     # -- construction helpers ----------------------------------------------
+
+    def _weight_dtype(self):
+        """The type the model's weights are served in, which a float arena
+        takes: the first parameter's (a skeleton's abstract one too)."""
+        return self.model.parameters()[0]._array.dtype
+
+    def _head_dim(self):
+        cfg = self.model.cfg
+        return getattr(cfg, "head_dim", None) \
+            or cfg.hidden_size // cfg.num_heads
 
     def _stream_params_from_checkpoint(self, path):
         """Stream weights from a sharded checkpoint straight onto their
@@ -1692,6 +1732,14 @@ class LLMEngine:
                 "spec_lanes": sum(1 for r in rows if r.draft),
                 "fed_tokens": sum(r.count + len(r.draft) for r in rows),
                 "emitted_tokens": len(outs),
+                # every fed token runs the layers `loop_steps` times and
+                # writes `cache_slabs` slabs; a GPT: 1 and its layers
+                "loop_steps": getattr(self.model.cfg, "loop_steps", 1),
+                "cache_slabs": self.kv_slabs,
+                # tokens of context the rows attend over, this step's
+                # included: what attention reads of every slab
+                "kv_tokens": sum(r.start + r.count + len(r.draft)
+                                 for r in rows),
                 **self._span_args,
             })
         self.metrics.inc(f"{kind}_steps")

@@ -127,6 +127,10 @@ _HELP = {
     "spec_mean_accepted_len": "Accepted draft tokens per drafted row",
     "jit_retraces": "Re-traces of already-compiled step programs "
                     "(recompile sentinel; 0 in steady state)",
+    "kv_slabs": "Cache slabs a token's K/V take: layers, times the "
+                "passes of a looped model",
+    "kv_bytes_per_token": "Device bytes of KV one cached token costs, "
+                          "all slabs",
     "pool_kv_bytes_per_block": "Device bytes one KV block costs in the "
                                "active KV dtype (int8 arenas include the "
                                "f32 scale sidecars)",

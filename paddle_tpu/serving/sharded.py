@@ -255,7 +255,10 @@ def serving_collective_budget(cfg, tp_degree, quant_collectives=()):
 def kv_capacity_blocks(kv_bytes, num_layers, num_heads, block_size,
                        head_dim, dtype_itemsize, tp_degree=1,
                        scale_itemsize=0):
-    """KV blocks a PER-CHIP byte budget buys. The arena is head-sharded
+    """KV blocks a PER-CHIP byte budget buys. `num_layers` is the arena's
+    leading axis, the model's `kv_slabs`: a GPT's layers, a looped model's
+    (pass, layer) pairs, so a block of it costs that many times a layer's
+    block. The arena is head-sharded
     over tp, so one chip stores ``num_heads / tp_degree`` heads per block
     — the same budget holds ``tp_degree``x the blocks of the naive
     logical-head-count formula. Admission (`LLMEngine.validate`, and the

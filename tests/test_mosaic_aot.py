@@ -194,6 +194,26 @@ engine = LLMEngine(
 compile_for_v5e(engine._get_step_fn(B, 1), *step_args(engine, 1),
                 case="engine-step-head64-w1")
 
+# a looped decoder (models/looped.py): two layers run twice inside one
+# `lax.fori_loop`, the slab index traced. Both kernels take it as a
+# prefetched scalar, so the program holds a layer's pair once, not once a pass
+from paddle_tpu.models.looped import LoopedConfig, LoopedLM
+
+
+def looped(hidden, heads):
+    return LoopedLM(LoopedConfig(
+        vocab_size=256, hidden_size=hidden, num_layers=2, num_heads=heads,
+        head_dim=128, intermediate_size=2 * hidden, max_seq_len=256,
+        loop_steps=2))
+
+
+engine = LLMEngine(looped(256, 2), block_size=16, max_batch=4,
+                   max_seq_len=256, prefill_chunk=32)
+assert engine.pool.k.shape[0] == 4
+for W in engine.width_buckets:
+    compile_for_v5e(engine._get_step_fn(B, W), *step_args(engine, W),
+                    case=f"looped-step-w{W}")
+
 # the arena through a step program: appended to and read by the kernel in
 # one layout, so the compiler relays it nowhere. Heads of 128 and arenas of
 # 128 MiB: a smaller one the compiler keeps in S(1) when it does relay it,
@@ -202,21 +222,27 @@ ARENA = {}     # case -> what the compiled program says of the arena
 L, H, D, bs = 2, 4, 128, 16
 model = GPT(GPTConfig(vocab_size=256, hidden_size=H * D, num_layers=L,
                       num_heads=H, max_seq_len=256, dropout=0.0))
-for kv_dtype, arena_dtype in ((None, "float32"), ("int8", "int8")):
-    block_bytes = L * H * bs * D * jnp.dtype(arena_dtype).itemsize
-    engine = LLMEngine(model, block_size=bs, max_batch=4, max_seq_len=256,
+# (the looped decoder carries the arenas through its loop over passes: there
+# too they stay where they are)
+for served, kv_dtype, arena_dtype in ((model, None, "float32"),
+                                      (model, "int8", "int8"),
+                                      (looped(H * D, H), None, "float32")):
+    block_bytes = served.kv_slabs * H * bs * D \
+        * jnp.dtype(arena_dtype).itemsize
+    engine = LLMEngine(served, block_size=bs, max_batch=4, max_seq_len=256,
                        prefill_chunk=32, kv_dtype=kv_dtype,
                        num_blocks=(128 << 20) // block_bytes)
     k = engine.pool.k
     assert str(k.dtype) == arena_dtype and k.nbytes == 128 << 20, (
         k.dtype, k.shape)
     relaid = re.compile(r"= \w+\[%s\]\S* copy\(" % ",".join(map(str, k.shape)))
+    kind = "looped-" if served is not model else ""
     for W in engine.width_buckets:
         compiled = engine._get_step_fn(engine.max_batch, W).trace(
             *step_args(engine, W)).lower(
                 lowering_platforms=("tpu",)).compile()
         mem = compiled.memory_analysis()
-        ARENA[f"{arena_dtype}-w{W}"] = {
+        ARENA[f"{kind}{arena_dtype}-w{W}"] = {
             "arena_bytes": k.nbytes,
             "arena_copies": len(relaid.findall(compiled.as_text())),
             "temp_bytes": mem.temp_size_in_bytes,
@@ -243,6 +269,10 @@ EXPECTED = {
     "engine-step-w32": {"paged_kv_append": 2, "ragged_paged_attention": 2},
     # heads of 64: no kernel at all (`paged_kernels_off`)
     "engine-step-head64-w1": {},
+    # a looped decoder of two layers and two passes: the loop's body is
+    # compiled once, so two pairs and not four
+    "looped-step-w1": {"paged_kv_append": 2, "ragged_paged_attention": 2},
+    "looped-step-w32": {"paged_kv_append": 2, "ragged_paged_attention": 2},
 }
 
 
@@ -297,7 +327,8 @@ def test_one_append_ships_a_program(aot, case, scatters):
 
 
 @pytest.mark.parametrize("case", ["float32-w1", "float32-w32",
-                                  "int8-w1", "int8-w32"])
+                                  "int8-w1", "int8-w32",
+                                  "looped-float32-w1", "looped-float32-w32"])
 def test_step_program_keeps_the_arena_in_one_layout(aot, case):
     """The append (`serving/block_pool.py _append`: the Pallas block writer
     here, its arenas aliased; the row scatter before PR 33) addresses the

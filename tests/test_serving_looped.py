@@ -1,0 +1,287 @@
+"""The looped decoder served through `LLMEngine`: prefill in chunks and then
+decode through the paged arena, against the plain reference's full forward
+(`benchmark/reference/ouro_loop.py`), on logits and not on tokens.
+
+The logits are the step programs' own: the model's forward is wrapped so
+that every step hands its `[rows, width, vocab]` logits (and the exit gate's
+probabilities) to the host, and the rows the scheduler planned say which
+request and position each belongs to.
+
+Tolerance. Float32 weights and a float32 arena, so the served logits and
+the reference's differ only in the order of float32 sums (the paged path
+attends block by block): 1e-4 on logits whose spread is about 0.5, measured
+1e-5. `tests/test_looped_model.py` shows that a dropped pass and a bf16
+residual stream both fail a tolerance of that size.
+"""
+import hashlib
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+
+import paddle_tpu as paddle
+from paddle_tpu.serving import LLMEngine
+
+from _looped_common import (VOCAB, build, load_reference, params_of,
+                            published_keys)
+
+REPO = Path(__file__).resolve().parents[1]
+TOL = 1e-4
+ref = load_reference()
+
+
+class Served:
+    """An engine over `model` whose every step's logits are kept:
+    `logits[request][position]` is the `[vocab]` row that chose the token at
+    `position + 1`, `gates[request][position]` the exit gate's probability
+    after every pass."""
+
+    def __init__(self, model, **engine_kw):
+        self.model = model
+        self.logits, self.gates, self._plans, self._seen = {}, {}, [], []
+        plain = model.forward
+
+        def forward(ids, caches=None):
+            logits, caches, gates = plain(ids, caches=caches,
+                                          return_gates=True)
+            jax.debug.callback(
+                lambda lg, g: self._seen.append((np.asarray(lg),
+                                                 np.asarray(g))),
+                logits._array, gates._array)
+            return logits, caches
+
+        model.forward = forward
+        self.engine = LLMEngine(model, **engine_kw)
+        run_rows = self.engine._run_rows
+
+        def planned(rows, W, step_id=0):
+            self._plans.append([(r.req.request_id, r.start, r.count)
+                                for r in rows])
+            return run_rows(rows, W, step_id)
+
+        self.engine._run_rows = planned
+
+    def step(self):
+        outs = self.engine.step()
+        jax.effects_barrier()
+        for plan, (lg, gates) in zip(self._plans, self._seen):
+            for row, (rid, start, count) in enumerate(plan):
+                for j in range(count):
+                    self.logits.setdefault(rid, {})[start + j] = lg[row, j]
+                    self.gates.setdefault(rid, {})[start + j] = \
+                        gates[:, row, j]
+        assert len(self._plans) == len(self._seen)
+        self._plans, self._seen = [], []
+        return outs
+
+    def run(self):
+        while self.engine.has_unfinished():
+            self.step()
+
+
+def _prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, VOCAB, n).tolist() for n in lengths]
+
+
+def _held_to_the_reference(served, rid, prompt):
+    """Largest gap between the served logits of one request and the
+    reference's full forward over its prompt and answer."""
+    model = served.model
+    ids = prompt + list(served.engine.get_request(rid).output_ids)
+    want = np.asarray(ref.sequence_logits(params_of(model), ids[:-1],
+                                          published_keys(model)))
+    got = served.logits[rid]
+    # every position fed (the last sampled token is never fed)
+    assert sorted(got) == list(range(len(ids) - 1))
+    return max(np.abs(got[p] - want[p]).max() for p in got)
+
+
+@pytest.mark.parametrize("path,loop_steps", [
+    ("xla", 2), ("xla", 4), ("interpret", 2)])
+def test_chunked_prefill_then_decode_is_the_references_forward(
+        monkeypatch, path, loop_steps):
+    monkeypatch.delenv("PADDLE_TPU_DISABLE_PALLAS", raising=False)
+    if path == "interpret":
+        monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("PADDLE_TPU_FORCE_PALLAS_INTERPRET",
+                           raising=False)
+    served = Served(build(loop_steps), block_size=8, max_batch=3,
+                    max_seq_len=96, prefill_chunk=16, prefix_cache=False)
+    engine = served.engine
+    assert engine.pool.k.shape[0] == loop_steps * 2 == engine.kv_slabs
+    # 37 tokens: three chunks; 5: one; a third joins the decoding two
+    prompts = _prompts((37, 5, 20))
+    rids = [engine.add_request(p, max_new_tokens=6) for p in prompts[:2]]
+    for _ in range(4):
+        served.step()
+    rids.append(engine.add_request(prompts[2], max_new_tokens=6))
+    served.run()
+    for rid, prompt in zip(rids, prompts):
+        assert _held_to_the_reference(served, rid, prompt) < TOL
+    # one program a width, each traced once, whatever the passes
+    assert engine.metrics.counters["jit_traces"] == 2
+    assert engine.metrics.gauges["kv_slabs"] == loop_steps * 2
+    assert engine.metrics.gauges["kv_bytes_per_token"] == \
+        2 * loop_steps * 2 * 2 * 64 * 4
+
+
+@pytest.mark.parametrize("corrupt_pass", [0, 1, 2])
+def test_pass_t_reads_its_own_slabs_and_no_other(corrupt_pass):
+    """Three passes over two layers: six slabs. With the keys of slab
+    `(corrupt_pass, layer 1)` spoiled after the prefill, the next decode
+    step's exit gate is bit for bit the clean run's after every pass before
+    `corrupt_pass` and differs after that pass and every later one (a pass
+    feeds the next): pass t of layer l reads slab t * L + l, no pass reads
+    another's."""
+    layers, prompt = 2, _prompts((19,))[0]
+
+    def decode_gates(spoil):
+        served = Served(build(3, num_layers=layers), block_size=8,
+                        max_batch=2, max_seq_len=64, prefill_chunk=32,
+                        prefix_cache=False)
+        rid = served.engine.add_request(prompt, max_new_tokens=4)
+        served.step()                       # the prompt, one chunk
+        if spoil is not None:
+            pool = served.engine.pool
+            pool.k = pool.k.at[spoil].multiply(-3.0)
+        served.step()                       # one decode step
+        return served.gates[rid][len(prompt)]
+
+    clean = decode_gates(None)
+    spoiled = decode_gates(corrupt_pass * layers + 1)
+    for t in range(3):
+        if t < corrupt_pass:
+            assert spoiled[t] == clean[t], t
+        else:
+            assert abs(spoiled[t] - clean[t]) > 1e-6, t
+
+
+def test_a_preempted_request_resumes_to_the_same_logits():
+    prompt = _prompts((21,))[0]
+    straight = Served(build(2), block_size=8, max_batch=2, max_seq_len=64,
+                      prefill_chunk=16)
+    rid = straight.engine.add_request(prompt, max_new_tokens=8)
+    straight.run()
+    resumed = Served(build(2), block_size=8, max_batch=2, max_seq_len=64,
+                     prefill_chunk=16)
+    rid2 = resumed.engine.add_request(prompt, max_new_tokens=8)
+    for _ in range(5):
+        resumed.step()
+    assert resumed.engine.requeue(rid2)     # its blocks go back to the pool
+    assert resumed.engine.get_request(rid2).preemptions == 1
+    resumed.run()
+    assert resumed.engine.get_request(rid2).output_ids == \
+        straight.engine.get_request(rid).output_ids
+    # the replay recomputes prompt and answer in chunks: float32 sums in
+    # another order, nothing more
+    assert _held_to_the_reference(resumed, rid2, prompt) < TOL
+    for pos, row in straight.logits[rid].items():
+        assert np.abs(resumed.logits[rid2][pos] - row).max() < TOL
+    pool = resumed.engine.pool
+    assert pool.num_free == pool.num_blocks - 1
+
+
+def test_an_int8_arena_carries_its_scales_through_the_passes():
+    """The loop carries whatever arrays the paged state has: with an int8
+    arena the scale sidecars too. Held to the reference at int8's own
+    error: a cached value keeps 7 bits, which moved a logit by 0.05 here;
+    the bound is 0.1, a fifth of the logits' spread, and scales lost
+    between passes or slabs would read several times that."""
+    served = Served(build(2), block_size=8, max_batch=2, max_seq_len=64,
+                    prefill_chunk=16, kv_dtype="int8", prefix_cache=False)
+    prompt = _prompts((21,))[0]
+    rid = served.engine.add_request(prompt, max_new_tokens=6)
+    served.run()
+    assert served.engine.pool.k_scale.shape[0] == 4
+    assert _held_to_the_reference(served, rid, prompt) < 0.1
+
+
+def test_speculation_gives_the_plain_engines_tokens():
+    """Drafted tokens are only more fed positions of a row: the looped
+    block sees nothing of them, and greedy output stays token for token."""
+    prompts = [[1, 2, 3] * 7, list(range(5, 25))]
+    kw = dict(block_size=8, max_batch=2, max_seq_len=96, prefill_chunk=16)
+    plain = LLMEngine(build(2), **kw).generate(prompts, max_new_tokens=12)
+    drafting = LLMEngine(build(2), spec_decoding=True, **kw)
+    assert drafting.generate(prompts, max_new_tokens=12) == plain
+    assert drafting.metrics.counters["spec_proposed_tokens"] > 0
+
+
+@pytest.mark.parametrize("option,kw", [
+    ("mesh=", dict(mesh=2)),
+    ("quantize=", dict(quantize="int8")),
+    ("lora_slots", dict(lora_slots=2)),
+])
+def test_options_written_for_gpt_are_refused_with_a_sentence(option, kw):
+    with pytest.raises(ValueError, match="not available for LoopedLM"):
+        LLMEngine(build(2), block_size=8, max_batch=2, max_seq_len=64, **kw)
+
+
+def test_gpt_step_programs_are_textually_the_parents():
+    """PR 32's six set-ups (`tests/test_append_dispatch.py`): every lowered
+    GPT step program has the text it had before the arena's leading axis
+    became the model's to state (sha256 of `as_text()`, written down from
+    the parent commit with this jax)."""
+    from test_append_dispatch import SETUPS, _programs
+
+    want = json.loads((REPO / "tests/gpt_step_programs_pr33.json")
+                      .read_text())
+    assert sorted(want) == sorted(SETUPS)
+    for setup in sorted(SETUPS):
+        got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()
+               for name, low in _programs(setup).items()}
+        assert got == want[setup], setup
+
+
+def test_the_benchmark_has_the_configuration_and_its_cell():
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    entry, = [c for c in bench["configs"] if c["name"] == "ouro-2p6b-serve"]
+    assert entry["reduced"] == []
+    assert entry["source"] == ("https://huggingface.co/ByteDance/Ouro-2.6B/"
+                               "blob/main/config.json")
+    cell, = [w for w in bench["workloads"]
+             if w["name"] == "ouro-2p6b-serve.think"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("ouro-2p6b-serve", "think", 1)
+    config = json.loads((REPO / entry["file"]).read_text())
+    for named in (entry["file"], config["reference"],
+                  "benchmark/traffic/think.json",
+                  "benchmark/reduce/looped.py",
+                  *(f"benchmark/layer_metrics/{m['name']}.py"
+                    for m in bench["per_layer"]
+                    if "ouro-2p6b-serve.think" in m.get("workloads", ()))):
+        assert (REPO / named).is_file(), named
+    # published widths and depth, nothing cut
+    assert [config[k] for k in (
+        "hidden_size", "num_hidden_layers", "num_attention_heads",
+        "head_dim", "intermediate_size", "total_ut_steps", "vocab_size")] \
+        == [2048, 48, 16, 128, 5632, 4, 49152]
+    out_tok_s, = [m for m in bench["end_to_end"] if m["name"] == "out_tok_s"]
+    assert "ouro-2p6b-serve.think" in out_tok_s["workloads"]
+    new = {m["name"] for m in bench["per_layer"]
+           if m.get("workloads") == ["ouro-2p6b-serve.think"]}
+    assert new == {"step.loop_mfu", "loop.decode_stream_share",
+                   "kernel.loop_ragged_decode_roofline"}
+
+
+def test_the_cells_rehearsal_ends_correct():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(REPO / ".jax_cache"))
+    proc = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload",
+         "ouro-2p6b-serve.think", "--seed", "2147483659", "--seconds", "30",
+         "--trace", "0", "--platform", "cpu"], cwd=REPO, env=env,
+        capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-3000:]
+    assert proc.stderr.strip().splitlines()[-1] == "correct: True"
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["compared"]["logit_deficit"]["value"] <= 0.06
