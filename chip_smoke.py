@@ -495,10 +495,12 @@ def serve_phase(model, *, max_batch, max_seq_len, prompt_lens, max_new,
     if _on_tpu():
         # read which path ran from the lowered programs, not from a config
         for name, low in lowered.items():
+            # a layer's ragged attention call and its append's block
+            # writer (one call for K and V in a float arena)
             n = low.as_text().count("tpu_custom_call")
-            require(n == cfg.num_layers,
+            require(n == 2 * cfg.num_layers,
                     f"step program {name}: {n} tpu_custom_call for "
-                    f"{cfg.num_layers} layers")
+                    f"{cfg.num_layers} layers, expected two a layer")
         for name, arr in (("params", next(iter(engine._params.values()))),
                           ("arena", engine.pool.k)):
             plats = {d.platform for d in arr.devices()}

@@ -108,8 +108,10 @@ pointer test per hook site.
 """
 from __future__ import annotations
 
+import functools
 import json
 import logging
+import math
 import os
 import threading
 import time
@@ -131,6 +133,50 @@ from .scheduler import WAITING, Request, Scheduler
 _request_log = logging.getLogger("paddle_tpu.serving.request")
 
 StepOutput = namedtuple("StepOutput", ["request_id", "token", "finished"])
+
+
+@functools.lru_cache(maxsize=None)
+def step_input_layout(B, W, max_blocks, touched_width=None):
+    """THE layout of a step's host inputs: ``({name: (offset, shape,
+    dtype)}, words)``. What the host hands the device for a step is ONE
+    int32 array of ``words`` elements, and every named input is a section
+    of it, in the order below — a pure function of what the (B, W)
+    program's shape already depends on (``touched_width`` is the int8
+    arena's `_touched_width`, None for a float arena). `_row_arrays` makes
+    its views from this table and the jitted step (`unpack_step_input`)
+    its slices, so the two cannot drift apart. The float32 sections are
+    views of the same words (``ndarray.view`` on the host,
+    ``lax.bitcast_convert_type`` in the program): bit for bit."""
+    i32, f32 = np.dtype(np.int32), np.dtype(np.float32)
+    sections = [
+        ("ids", (B, W), i32), ("tables", (B, max_blocks), i32),
+        ("slots", (B, W), i32), ("offs", (B, W), i32), ("qpos", (B, W), i32),
+        ("q_start", (B,), i32), ("kv_live", (B,), i32),
+        *([("touched", (B, touched_width), i32), ("touch_idx", (B, W), i32)]
+          if touched_width else []),
+        ("adapter_slots", (B,), i32), ("last_idx", (B,), i32),
+        ("spec_lens", (B,), i32), ("temps", (B,), f32), ("top_ks", (B,), i32),
+        ("top_ps", (B,), f32),
+    ]
+    fields, words = {}, 0
+    for name, shape, dtype in sections:
+        fields[name] = (words, shape, dtype)
+        words += math.prod(shape)
+    return fields, words
+
+
+def unpack_step_input(packed, fields):
+    """`{name: array}` out of the packed step input, inside the program:
+    static slices and reshapes of `step_input_layout`'s sections."""
+    import jax
+
+    out = {}
+    for name, (off, shape, dtype) in fields.items():
+        x = jax.lax.slice(packed, (off,), (off + math.prod(shape),))
+        if dtype != packed.dtype:
+            x = jax.lax.bitcast_convert_type(x, dtype)
+        out[name] = x.reshape(shape)
+    return out
 
 
 def _env_flag(name, default):
@@ -660,7 +706,15 @@ class LLMEngine:
         # mid-flight (the PR 16 documented hazard) — `_guard_thread`
         # raises a pointed RuntimeError instead of corrupting
         self._engine_thread = None
-        self._key = jax.random.PRNGKey(seed)
+        # where a step's packed host input and the sampling key go: the
+        # default device, or every chip of the mesh
+        self._rep = (None if self._smesh is None
+                     else self._smesh.replicated())
+        # the sampling key lives on the device: the step program splits it
+        # and hands the advanced key back (`_run_step`). Under a mesh it
+        # starts out replicated, as the program returns it, so the first
+        # step and every later one offer the same kind of array
+        self._key = jax.device_put(jax.random.PRNGKey(seed), self._rep)
         # fault injection (serving/faults.py): arm the PADDLE_TPU_FAULTS
         # plan if one is configured; with no plan every hook site below is
         # a single module-attribute pointer test (same discipline as the
@@ -1266,69 +1320,62 @@ class LLMEngine:
             )
             return packed
 
-        if quantized:
-            # int8 arena variant: the scale sidecars ride the signature
-            # as donated state right after the payload arenas, and the
-            # scatter's touched-block lists ride the host marshalling —
-            # ONE kv_dtype switch, same (B, W) keying, kinds still don't
-            # key programs
-            def step(params, buffers, k_arena, v_arena, k_scale, v_scale,
-                     lora_tables, ids, block_tables, slots, offs, qpos,
-                     q_start, kv_live, touched, touch_idx, adapter_slots,
-                     last_idx, spec_lens, temps, top_ks, top_ps, key):
-                q_lens = last_idx + 1 + spec_lens
-                logits, state = forward(
-                    params, buffers, k_arena, v_arena, lora_tables,
-                    adapter_slots, ids, block_tables, slots, offs, qpos,
-                    q_start, kv_live, q_lens,
-                    k_scale=k_scale, v_scale=v_scale, touched=touched,
-                    touch_idx=touch_idx)
-                packed = _decide(logits, state, ids, last_idx, spec_lens,
-                                 temps, top_ks, top_ps, key)
-                return (packed, state.k, state.v, state.k_scale,
-                        state.v_scale)
-        else:
-            def step(params, buffers, k_arena, v_arena, lora_tables, ids,
-                     block_tables, slots, offs, qpos, q_start, kv_live,
-                     adapter_slots, last_idx, spec_lens, temps, top_ks,
-                     top_ps, key):
-                # per-row live width for the ragged kernel: chunk tokens
-                # through last_idx plus the drafted candidates
-                q_lens = last_idx + 1 + spec_lens
-                logits, state = forward(params, buffers, k_arena, v_arena,
-                                        lora_tables, adapter_slots, ids,
-                                        block_tables, slots, offs, qpos,
-                                        q_start, kv_live, q_lens)
-                packed = _decide(logits, state, ids, last_idx, spec_lens,
-                                 temps, top_ks, top_ps, key)
-                return packed, state.k, state.v
+        fields, _ = self._step_layout(W)
+        n_arena = 4 if quantized else 2
+
+        # ONE signature for both arena kinds: (params, buffers, *arenas,
+        # lora_tables, packed, key). The arenas are the payload pair, plus
+        # the f32 scale sidecars right after it when the arena is int8;
+        # `packed` is the step's whole host input (`step_input_layout`),
+        # `key` the engine's sampling key, which lives on the device
+        def step(params, buffers, *rest):
+            arenas, (lora_tables, packed, key) = rest[:n_arena], rest[n_arena:]
+            a = unpack_step_input(packed, fields)
+            # the engine's stream: one split a step, the new key handed
+            # back as the program's LAST output
+            key, sub = jax.random.split(key)
+            last_idx, spec_lens = a["last_idx"], a["spec_lens"]
+            # per-row live width for the ragged kernel: chunk tokens
+            # through last_idx plus the drafted candidates
+            q_lens = last_idx + 1 + spec_lens
+            int8 = {}
+            if quantized:
+                # the scatter's touched-block lists ride the host input
+                int8 = dict(k_scale=arenas[2], v_scale=arenas[3],
+                            touched=a["touched"], touch_idx=a["touch_idx"])
+            logits, state = forward(
+                params, buffers, arenas[0], arenas[1], lora_tables,
+                a["adapter_slots"], a["ids"], a["tables"], a["slots"],
+                a["offs"], a["qpos"], a["q_start"], a["kv_live"], q_lens,
+                **int8)
+            out = _decide(logits, state, a["ids"], last_idx, spec_lens,
+                          a["temps"], a["top_ks"], a["top_ps"], sub)
+            return (out, *state.arenas(), key)
 
         # donated arena state: payload arenas, plus the f32 scale
-        # sidecars when the arena is int8
-        arena_args = (2, 3, 4, 5) if quantized else (2, 3)
+        # sidecars when the arena is int8. The key is NOT donated: a
+        # launch that raises leaves `self._key` the key it was
+        arena_args = tuple(range(2, 2 + n_arena))
         if smesh is None:
             fn = jax.jit(step,
                          # jaxlint: disable=JL004 -- single-device arena donation, deliberately ungated (gating would copy the whole arena every step on CPU); the aliasing it relies on is machine-checked by IR contract IR002 (analysis/contracts.py) on the lowered tp=1 programs
                          donate_argnums=arena_args)
         else:
             # mesh-aware program, same (B, W) keying: weights and arenas
-            # pinned to their tp shardings, every host-marshalled step
-            # input (and the packed result out) replicated. Arena
-            # donation routes through the JL004 gate — the host-platform
-            # CPU mesh miscompiles donated sharded buffers, so donation
-            # is off exactly there and in-place on real accelerators.
+            # pinned to their tp shardings, the packed host input and the
+            # key (and the packed result and the new key out) replicated.
+            # Arena donation routes through the JL004 gate — the
+            # host-platform CPU mesh miscompiles donated sharded buffers,
+            # so donation is off exactly there and in-place on real
+            # accelerators.
             from ..parallel.spmd import mesh_donate_argnums
 
             rep = smesh.replicated()
             arena = smesh.arena_sharding()
-            n_arena = len(arena_args)
-            # ids..top_ps marshalling + adapter_slots + PRNG key
-            # (+ touched/touch_idx when quantized)
-            host_in = (rep,) * (16 if quantized else 14)
             in_sh = (self._param_shardings, self._buffer_shardings,
                      ) + (arena,) * n_arena + (self._lora_shardings,
-                     ) + host_in
-            out_sh = (rep,) + (arena,) * n_arena
+                     rep, rep)
+            out_sh = (rep,) + (arena,) * n_arena + (rep,)
             fn = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
                          donate_argnums=mesh_donate_argnums(arena_args))
         self._step_fns[(B, W)] = fn
@@ -1352,6 +1399,12 @@ class LLMEngine:
         for the null block — part of the compiled (B, W) shape key, so
         it must be THE one formula for both tracing and marshalling."""
         return blocks_per_row(W, self.block_size) + 1
+
+    def _step_layout(self, W):
+        """`step_input_layout` of this engine's width-``W`` program."""
+        return step_input_layout(
+            self.max_batch, W, self.max_blocks,
+            self._touched_width(W) if self.pool.quantized else None)
 
     def expected_program_count(self):
         """THE program-count contract, in one place: the engine compiles
@@ -1394,41 +1447,43 @@ class LLMEngine:
         Lowering re-traces outside the jit dispatch cache, so the
         ``jit_traces`` counter is snapshotted and restored — the
         recompile sentinel must never blame an analysis pass."""
-        import jax
-        import jax.numpy as jnp
-
         shapes = self.step_program_shapes()
         if kinds is not None:
             shapes = {k: shapes[k] for k in kinds}
         snap = self.metrics.counters.get("jit_traces", 0)
-        h = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt)
         lowered = {}
-        quantized = self.pool.quantized
         try:
             for name, (B, W) in shapes.items():
-                fn = self._get_step_fn(B, W)
-                arenas = (self.pool.k, self.pool.v)
-                mid = ()
-                if quantized:
-                    arenas += (self.pool.k_scale, self.pool.v_scale)
-                    mid = (h((B, self._touched_width(W))),  # touched
-                           h((B, W)))                       # touch_idx
-                lowered[name] = fn.lower(
-                    self._params, self._buffers, *arenas,
-                    self._lora_tables,
-                    h((B, W)), h((B, self.max_blocks)), h((B, W)), h((B, W)),
-                    h((B, W)), h((B,)), h((B,)), *mid,
-                    h((B,)),                      # adapter_slots
-                    h((B,)),                      # last_idx
-                    h((B,)),                      # spec_lens
-                    h((B,), jnp.float32), h((B,)), h((B,), jnp.float32),
-                    jax.ShapeDtypeStruct(self._key.shape, self._key.dtype),
-                )
+                lowered[name] = self._get_step_fn(B, W).lower(
+                    *self.step_program_args(W))
         finally:
             # restore even when a lower() raises mid-loop: the recompile
             # sentinel must never blame serving for analysis traces
             self.metrics.counters["jit_traces"] = snap
         return lowered
+
+    def _arenas(self):
+        """The pool's arena arrays in the step program's order."""
+        pool = self.pool
+        if pool.quantized:
+            return (pool.k, pool.v, pool.k_scale, pool.v_scale)
+        return (pool.k, pool.v)
+
+    def step_program_args(self, W):
+        """THE statement of the width-``W`` step program's arguments, in
+        its order ``(params, buffers, *arenas, lora_tables, packed, key)``:
+        weights, arenas and adapter tables as their real placed arrays,
+        the packed host input (`step_input_layout`) and the sampling key
+        as ShapeDtypeStructs. `lowered_step_programs` lowers with exactly
+        these; tests and AOT tools map them to shapes of their own."""
+        import jax
+        import jax.numpy as jnp
+
+        _, words = self._step_layout(W)
+        return (self._params, self._buffers, *self._arenas(),
+                self._lora_tables,
+                jax.ShapeDtypeStruct((words,), jnp.int32),
+                jax.ShapeDtypeStruct(self._key.shape, self._key.dtype))
 
     def step_program_spec(self):
         """Flat-signature facts the donation contract (IR002) checks the
@@ -1438,9 +1493,10 @@ class LLMEngine:
         on this engine (single-chip engines donate unconditionally; mesh
         engines route through `parallel.spmd.mesh_donate_argnums`, which
         turns donation off on the cpu host platform). The unified program
-        returns ``(packed, k_arena, v_arena)`` — plus the two f32 scale
-        sidecars when the arena is int8 — so the arena state lands at
-        outputs (1, 2[, 3, 4]) for every width."""
+        returns ``(packed, k_arena, v_arena, key)`` — the two f32 scale
+        sidecars after the arenas when the arena is int8, the advanced
+        sampling key always LAST — so the arena state lands at outputs
+        (1, 2[, 3, 4]) for every width."""
         import jax
 
         n_state = (len(jax.tree_util.tree_leaves(self._params))
@@ -1549,41 +1605,37 @@ class LLMEngine:
         return jax.profiler.TraceAnnotation(
             self.tracer.step_annotation(step_id))
 
-    def _run_step(self, fn, a, last_idx, spec_lens, step_id=0, t_disp=0.0):
-        """Dispatch the unified step program; returns the DEVICE packed
-        array (the caller's single np.asarray on it is the step's ONE
-        host sync). While tracing, the step span's ``h2d_arrays`` is the
-        number of host arrays handed over here and ``h2d_ms`` the time
-        from ``t_disp`` (the start of the ``dispatch`` phase) to just
-        before the program is launched: the hand-overs and the key
-        split, so ``dispatch - h2d_ms`` is the launch."""
+    def _run_step(self, fn, packed, step_id=0, t_disp=0.0):
+        """Hand the step's packed host input to the device and dispatch
+        the unified step program; returns the DEVICE result array (the
+        caller's single np.asarray on it is the step's ONE host sync).
+        The program splits the sampling key itself and returns the
+        advanced one, stored here without being read; a launch that
+        raises assigns nothing, so the key (never donated) stays the one
+        the failed step was offered and a retry samples with it again.
+        While tracing, the step span's ``h2d_arrays`` is the number of
+        host arrays handed over (one, on every step of every engine),
+        ``h2d_bytes`` its size and ``h2d_ms`` the time from ``t_disp``
+        (the start of the ``dispatch`` phase) to just before the program
+        is launched: that one transfer, so ``dispatch - h2d_ms`` is the
+        launch."""
         import jax
-        import jax.numpy as jnp
 
-        self._key, sub = jax.random.split(self._key)
-        pool = self.pool
-        arenas = (pool.k, pool.v)
-        mid = ()
-        if pool.quantized:
-            arenas += (pool.k_scale, pool.v_scale)
-            mid = (a["touched"], a["touch_idx"])
-        # every host array of the step, in the program's argument order
-        host = (a["ids"], a["tables"], a["slots"], a["offs"], a["qpos"],
-                a["q_start"], a["kv_live"], *mid, a["adapter_slots"],
-                last_idx, spec_lens, a["temps"], a["top_ks"], a["top_ps"])
-        args = (self._params, self._buffers, *arenas, self._lora_tables,
-                *map(jnp.asarray, host), sub)
+        dev = jax.device_put(packed, self._rep)
         if self.tracer is not None:
             self._span_args.update(
-                h2d_arrays=len(host),
+                h2d_arrays=1, h2d_bytes=packed.nbytes,
                 h2d_ms=(time.monotonic() - t_disp) * 1e3)
+        pool = self.pool
         with self._annotation(step_id):
-            if pool.quantized:
-                (packed, pool.k, pool.v,
-                 pool.k_scale, pool.v_scale) = fn(*args)
-            else:
-                packed, pool.k, pool.v = fn(*args)
-        return packed
+            out, *arenas, self._key = fn(
+                self._params, self._buffers, *self._arenas(),
+                self._lora_tables, dev, self._key)
+        if pool.quantized:
+            pool.k, pool.v, pool.k_scale, pool.v_scale = arenas
+        else:
+            pool.k, pool.v = arenas
+        return out
 
     # -- fault hooks (serving/faults.py; armed plans only) -----------------
 
@@ -1822,35 +1874,22 @@ class LLMEngine:
         return outs
 
     def _row_arrays(self, S):
-        """Zeroed per-step host marshalling arrays for the unified
-        ragged step (one dict so fill sites cannot drift apart on a
-        future per-row field)."""
-        B = self.max_batch
-        return {
-            "ids": np.zeros((B, S), np.int32),
-            "qpos": np.zeros((B, S), np.int32),
-            "slots": np.zeros((B, S), np.int32),
-            "offs": np.zeros((B, S), np.int32),
-            "tables": np.zeros((B, self.max_blocks), np.int32),
-            "temps": np.zeros(B, np.float32),
-            "top_ks": np.zeros(B, np.int32),
-            "top_ps": np.ones(B, np.float32),
-            "q_start": np.zeros(B, np.int32),
-            # idle lanes have no live block: the ragged kernel runs no
-            # iteration there
-            "kv_live": np.zeros(B, np.int32),
-            # idle/pad lanes read the all-zeros base slot 0
-            "adapter_slots": np.zeros(B, np.int32),
-            **({
-                # int8 arena: per-row touched-block list (slot 0 = the
-                # null block, so zeroed rows are inert) + each token's
-                # index into it — block_pool._quantize_scatter's
-                # scatter-max targets
-                "touched": np.zeros(
-                    (B, self._touched_width(S)), np.int32),
-                "touch_idx": np.zeros((B, S), np.int32),
-            } if self.pool.quantized else {}),
-        }
+        """A fresh packed host input for the unified ragged step and its
+        named views (one dict so fill sites cannot drift apart on a
+        future per-row field): ``(packed, {name: view})``. Every section
+        starts zeroed — idle lanes have no live block (``kv_live`` 0: the
+        ragged kernel runs no iteration there), read the all-zeros
+        adapter slot 0 and, with an int8 arena, touch only the null block
+        (``touched`` slot 0, ``touch_idx`` 0) — but ``top_ps``, which
+        reads 1.0. A buffer a step was launched with is never written
+        again: a `device_put` on the CPU backend may alias it."""
+        fields, words = self._step_layout(S)
+        packed = np.zeros(words, np.int32)
+        a = {name: packed[off:off + math.prod(shape)].view(dtype)
+                   .reshape(shape)
+             for name, (off, shape, dtype) in fields.items()}
+        a["top_ps"][:] = 1.0
+        return packed, a
 
     def _fill_row(self, a, i, req, start, w, S):
         """Everything about row `i` that does not depend on WHICH tokens
@@ -1891,9 +1930,8 @@ class LLMEngine:
         reserved blocks return to the pool via `reclaim_spec_blocks`."""
         tr = self.tracer
         t_build = time.monotonic() if tr is not None else 0.0
-        a = self._row_arrays(W)
-        last_idx = np.zeros(self.max_batch, np.int32)
-        spec_lens = np.zeros(self.max_batch, np.int32)
+        packed, a = self._row_arrays(W)
+        last_idx, spec_lens = a["last_idx"], a["spec_lens"]
         for i, row in enumerate(rows):
             req, start, count, k = row.req, row.start, row.count, len(row.draft)
             if start == req.num_tokens - 1:
@@ -1922,15 +1960,14 @@ class LLMEngine:
             self._span_args.update(grid_live=live, grid_total=total,
                                    append_blocks=append_blocks(a["slots"]))
         t_disp = time.monotonic() if tr is not None else 0.0
-        packed_dev = self._run_step(fn, a, last_idx, spec_lens,
-                                    step_id=step_id, t_disp=t_disp)
+        out_dev = self._run_step(fn, packed, step_id=step_id, t_disp=t_disp)
         t_sync = time.monotonic() if tr is not None else 0.0
         # THE host sync: one packed [B, K+3] transfer carries the emitted
         # runs, accept lengths, and row-finite flags for the whole step
-        packed = np.asarray(packed_dev)
+        out = np.asarray(out_dev)
         self.metrics.inc("host_syncs")
-        run, n_accs, row_ok = (packed[:, :K + 1], packed[:, K + 1],
-                               packed[:, K + 2])
+        run, n_accs, row_ok = (out[:, :K + 1], out[:, K + 1],
+                               out[:, K + 2])
         if faults._PLAN is not None:
             row_ok = self._corrupt_row_ok(rows, row_ok)
         t_emit = time.monotonic() if tr is not None else 0.0

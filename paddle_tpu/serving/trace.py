@@ -22,7 +22,9 @@ exactly those two views as Chrome/Perfetto trace events:
   (mixed/decode/verify), token counts, the ragged kernel's live and
   total grid steps (``grid_live``/``grid_total``), the blocks the
   append's writer copies back (``append_blocks``) and the hand-over to
-  the device inside ``dispatch`` (``h2d_arrays``/``h2d_ms``). Pool
+  the device inside ``dispatch``: ``h2d_arrays`` (one: the step's packed
+  host input), ``h2d_bytes`` (its size) and ``h2d_ms`` (that one
+  transfer: ``dispatch``'s start to just before the launch). Pool
   evictions land as instants on a ``block-pool`` track.
 
 The ring buffer, clocks, export, and the xplane join annotation are the

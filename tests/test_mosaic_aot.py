@@ -160,24 +160,12 @@ model = GPT(GPTConfig(vocab_size=256, hidden_size=256, num_layers=2,
 engine = LLMEngine(model, block_size=16, max_batch=4, max_seq_len=256,
                    prefill_chunk=32)
 like = lambda a: sds(a.shape, a.dtype)
-h = lambda shape, dt="int32": sds(shape, dt)
 
 
 def step_args(engine, W):
-    """The step program's arguments as shapes on the described chip, in
-    `LLMEngine.lowered_step_programs`' order."""
-    B, pool = engine.max_batch, engine.pool
-    arenas, mid = [pool.k, pool.v], []
-    if pool.quantized:
-        arenas += [pool.k_scale, pool.v_scale]
-        mid = [h((B, engine._touched_width(W))), h((B, W))]
-    return [
-        {k: like(v) for k, v in engine._params.items()},
-        {k: like(v) for k, v in engine._buffers.items()},
-        *map(like, arenas), engine._lora_tables,
-        h((B, W)), h((B, engine.max_blocks)), h((B, W)), h((B, W)),
-        h((B, W)), h((B,)), h((B,)), *mid, h((B,)), h((B,)), h((B,)),
-        h((B,), "float32"), h((B,)), h((B,), "float32"), like(engine._key)]
+    """The step program's arguments (`LLMEngine.step_program_args`) as
+    shapes on the described chip."""
+    return jax.tree.map(like, engine.step_program_args(W))
 
 
 B = engine.max_batch

@@ -227,12 +227,21 @@ def test_options_written_for_gpt_are_refused_with_a_sentence(option, kw):
 
 def test_gpt_step_programs_are_textually_the_parents():
     """PR 32's six set-ups (`tests/test_append_dispatch.py`): every lowered
-    GPT step program has the text it had before the arena's leading axis
-    became the model's to state (sha256 of `as_text()`, written down from
-    the parent commit with this jax)."""
+    GPT step program has the text written down for it (sha256 of
+    `as_text()`, with this jax). PR 34 kept PR 33's thirteen hashes: the
+    arena's leading axis became the model's to state and no program moved.
+    **PR 35 wrote all thirteen down anew**, because the signature changed
+    (one packed host input and the key in place of 14 or 16 arguments, the
+    new key a last output), so every hash moved. Below the unpacking the
+    text is PR 34's: with value numbers and the helper functions' numeric
+    suffixes folded, every line of each parent program stands in its
+    successor but `main`'s signature and its `return`, and what the
+    successor adds is 127-132 lines: the slices, reshapes and two
+    `bitcast_convert`s of `unpack_step_input` and the key's split, which
+    PR 34 ran as a program of its own (compared on all thirteen, PR 35)."""
     from test_append_dispatch import SETUPS, _programs
 
-    want = json.loads((REPO / "tests/gpt_step_programs_pr33.json")
+    want = json.loads((REPO / "tests/gpt_step_programs_pr35.json")
                       .read_text())
     assert sorted(want) == sorted(SETUPS)
     for setup in sorted(SETUPS):

@@ -7,8 +7,9 @@ scheduler.py `_admit`; ops/pallas/paged_attention.py `ragged_grid_steps`):
   brute-force walk of the kernel's own conditions;
 - ``append_blocks``: the blocks the append's writer copies back, an arena a
   layer, against a count by hand;
-- ``h2d_arrays`` / ``h2d_ms``: the host arrays handed to the device and
-  what the hand-over took, inside the ``dispatch`` phase;
+- ``h2d_arrays`` / ``h2d_bytes`` / ``h2d_ms``: the host arrays handed to
+  the device (one: the packed step input), its size by the layout's own
+  count, and what the hand-over took, inside the ``dispatch`` phase;
 - ``queue_wait``: arrival to first admission, observed like ``ttft``, with
   tracing on or off.
 """
@@ -108,9 +109,9 @@ def _steps(engine):
             if e["name"].startswith("step[")]
 
 
-@pytest.mark.parametrize("kv_dtype,arrays", [(None, 13), ("int8", 15)],
+@pytest.mark.parametrize("kv_dtype", [None, "int8"],
                          ids=["bf16-arena", "int8-arena"])
-def test_every_step_span_says_grid_and_handover(model, kv_dtype, arrays):
+def test_every_step_span_says_grid_and_handover(model, kv_dtype):
     """Mixed, decode and (with speculation) verify steps alike."""
     engine = _engine(model, prefill_chunk=8, trace=1.0, kv_dtype=kv_dtype,
                      spec_decoding=True)
@@ -128,7 +129,12 @@ def test_every_step_span_says_grid_and_handover(model, kv_dtype, arrays):
         assert a["rows"] <= a["grid_live"] <= a["grid_total"]
         assert a["grid_total"] >= engine.max_batch * nq
         assert a["grid_total"] - a["grid_live"] <= engine.max_batch * nq
-        assert a["h2d_arrays"] == arrays
+        # one packed buffer a step, whatever the arena: its sections are
+        # the step's thirteen host arrays (fifteen with an int8 arena)
+        fields, words = engine._step_layout(a["width"])
+        assert a["h2d_arrays"] == 1
+        assert len(fields) == (15 if kv_dtype else 13)
+        assert a["h2d_bytes"] == 4 * words
         # ts/dur are rounded to 3 decimals of a microsecond
         assert 0.0 <= a["h2d_ms"] <= phases["dispatch"]["dur"] / 1e3 + 1e-5
     assert engine.metrics.counters["host_syncs"] == len(steps)
