@@ -36,6 +36,9 @@ Kernel design (TPU):
   (chunk tokens are consecutive), key positions follow from a page's place
   in the table; ``qpos >= kpos`` also discards the garbage tail of a
   partially filled last block and the slots of a last fetch past it.
+- Grouped-query attention: the arena has the model's key/value heads,
+  fewer than the query heads; a fetch brings each K/V head once and the
+  query heads of its group are multiplied against it.
 - Head-major arena so each (head, block) tile is a 2-D ``(block_size,
   head_dim)`` slab whose minor two dims a copy takes whole: Mosaic tiles
   the minor two dims of an array by (8, 128) (x2 rows for bf16, x4 for
@@ -76,7 +79,9 @@ def paged_attention_xla(q, k_arena, v_arena, layer, block_tables, qpos,
                         scale=None, k_scale=None, v_scale=None):
     """Reference paged attention: gather the full padded block table.
 
-    q: [B, S, H, D]; arenas: [layers, H, num_blocks, block_size, D];
+    q: [B, S, H, D]; arenas: [layers, Hkv, num_blocks, block_size, D], Hkv
+    dividing H (grouped-query attention: query head i reads K/V head
+    ``i // (H / Hkv)``; the gathered keys are repeated by group here);
     block_tables: [B, max_blocks] int32 (0 = null block); qpos: [B, S]
     absolute query positions (padding rows/cols carry 0 and are discarded
     by the caller). `k_scale`/`v_scale` [layers, H, num_blocks] dequantize
@@ -96,11 +101,15 @@ def paged_attention_xla(q, k_arena, v_arena, layer, block_tables, qpos,
         v_seq = v_seq.astype(jnp.float32) * vsc[..., None, None]
     nb, bs = k_seq.shape[2], k_seq.shape[3]
     L = nb * bs
+    Hkv = k_seq.shape[0]
     # back to the [B, L, H, D] layout of models/gpt.py's contiguous-cache
     # path so the einsum below is the exact same contraction (bit-parity
     # with GPT.generate is a serving acceptance criterion)
-    k_seq = jnp.transpose(k_seq, (1, 2, 3, 0, 4)).reshape(B, L, H, D)
-    v_seq = jnp.transpose(v_seq, (1, 2, 3, 0, 4)).reshape(B, L, H, D)
+    k_seq = jnp.transpose(k_seq, (1, 2, 3, 0, 4)).reshape(B, L, Hkv, D)
+    v_seq = jnp.transpose(v_seq, (1, 2, 3, 0, 4)).reshape(B, L, Hkv, D)
+    if Hkv != H:
+        k_seq = jnp.repeat(k_seq, H // Hkv, axis=2)
+        v_seq = jnp.repeat(v_seq, H // Hkv, axis=2)
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k_seq, preferred_element_type=jnp.float32
     ) * scale
@@ -179,7 +188,11 @@ def _ragged_kernel(layer_ref, bt_ref, qs_ref, kl_ref, ql_ref, q_ref,
     i = pl.program_id(0)   # batch row
     qb = pl.program_id(1)  # query tile
     rows, tiles = pl.num_programs(0), pl.num_programs(1)
-    H, _, _, D = kbuf.shape[1:]
+    D = kbuf.shape[-1]
+    # grouped-query attention: the arena holds H / G key/value heads, each
+    # fetched once and multiplied against the G query heads of its group
+    H = q_ref.shape[1]
+    G = H // kbuf.shape[1]
     T = pages * bs
     layer = layer_ref[0]
 
@@ -253,13 +266,13 @@ def _ragged_kernel(layer_ref, bt_ref, qs_ref, kl_ref, ql_ref, q_ref,
         live = qp >= j * T + kp0
         for h in range(H):
             q = q_ref[0, h]                                   # [qt, D]
-            kt = kbuf[slot, h].astype(q.dtype).reshape(T, D)
+            kt = kbuf[slot, h // G].astype(q.dtype).reshape(T, D)
             s = jax.lax.dot_general(
                 q, kt, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             if quant:
                 keys = pl.ds(pl.multiple_of(j * T, T), T)
-                s = s * ksc_ref[0, pl.ds(h, 1), keys]
+                s = s * ksc_ref[0, pl.ds(h // G, 1), keys]
             s = jnp.where(live, s, _NEG_INF)
             m_prev = m_ref[h]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -267,8 +280,8 @@ def _ragged_kernel(layer_ref, bt_ref, qs_ref, kl_ref, ql_ref, q_ref,
             alpha = jnp.exp(m_prev - m_new)
             l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
             if quant:
-                p = p * vsc_ref[0, pl.ds(h, 1), keys]
-            vt = vbuf[slot, h].astype(q.dtype).reshape(T, D)
+                p = p * vsc_ref[0, pl.ds(h // G, 1), keys]
+            vt = vbuf[slot, h // G].astype(q.dtype).reshape(T, D)
             acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
                 p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -300,7 +313,8 @@ def ragged_grid_steps(q_lens, kv_live, width, block_size):
 
 
 @functools.lru_cache(maxsize=None)
-def _build_ragged(B, H, sq, d, bs, nk, dtype_name, kv_dtype_name, interpret):
+def _build_ragged(B, H, Hkv, sq, d, bs, nk, dtype_name, kv_dtype_name,
+                  interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -317,10 +331,10 @@ def _build_ragged(B, H, sq, d, bs, nk, dtype_name, kv_dtype_name, interpret):
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     if quant:
-        row = pl.BlockSpec((1, H, _padded(nk, bs) * bs),
+        row = pl.BlockSpec((1, Hkv, _padded(nk, bs) * bs),
                            lambda i, qb, *_: (i, 0, 0))
         in_specs += [row, row]
-    kv_buf = pltpu.VMEM((2, H, pages, bs, d), jnp.dtype(kv_dtype_name))
+    kv_buf = pltpu.VMEM((2, Hkv, pages, bs, d), jnp.dtype(kv_dtype_name))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(B, sq // qt),
@@ -353,7 +367,9 @@ def ragged_paged_attention(q, k_arena, v_arena, layer, block_tables,
     """Pallas ragged paged attention over live KV pages — and live
     QUERY tiles — only.
 
-    q: [B, S, H, D]; arenas: [layers, H, num_blocks, bs, D];
+    q: [B, S, H, D]; arenas: [layers, Hkv, num_blocks, bs, D], Hkv dividing
+    H (grouped-query attention: query head i reads K/V head
+    ``i // (H / Hkv)``, which is fetched once for its whole group);
     block_tables: [B, max_blocks]; q_start: [B] first query position per
     row; kv_live: [B] number of live KV blocks per row; q_lens: [B] live
     query tokens per row (ragged widths — a decode row riding a wide
@@ -369,8 +385,8 @@ def ragged_paged_attention(q, k_arena, v_arena, layer, block_tables,
     B, S, H, D = q.shape
     bs = k_arena.shape[3]
     nk = block_tables.shape[1]
-    fn = _build_ragged(B, H, S, D, bs, nk, str(q.dtype), str(k_arena.dtype),
-                       bool(interpret))
+    fn = _build_ragged(B, H, k_arena.shape[1], S, D, bs, nk, str(q.dtype),
+                       str(k_arena.dtype), bool(interpret))
     if q_lens is None:
         q_lens = jnp.full((B,), S, jnp.int32)
     operands = (jnp.transpose(q, (0, 2, 1, 3)), k_arena, v_arena)  # [B,H,S,D]

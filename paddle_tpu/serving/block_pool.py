@@ -2,8 +2,9 @@
 
 The TPU-native answer to vLLM's PagedAttention (PAPERS.md "Ragged Paged
 Attention"): K/V live in ONE fixed-shape, head-major arena
-``[layers, heads, num_blocks, block_size, head_dim]`` and every sequence
-owns a list of block ids. Head-major is the Pallas-friendly layout: each
+``[layers, heads, num_blocks, block_size, head_dim]`` (`heads` the model's
+key/value heads: all of them, or fewer than its query heads under
+grouped-query attention) and every sequence owns a list of block ids. Head-major is the Pallas-friendly layout: each
 (layer, head, block) slice is a contiguous ``[block_size, head_dim]`` tile
 the ragged kernel DMAs straight from HBM (ops/pallas/paged_attention.py).
 Appending tokens (`_append`) is a fixed-shape write that keeps the arena in
@@ -227,6 +228,16 @@ class PagedState:
       touch_idx     [B, S] int32 — each fed token's index into its row's
                     `touched` list (0 = the null slot)
 
+    Lane state (a model that keeps state a lane which no position
+    addresses, e.g. a state-space layer's; models/nemotron_h.py) adds:
+      lane_state    {name: [layers, B, ...]} — the pool's arrays of that
+                    state, one row a lane; the model reads and replaces
+                    them, and they travel with the arenas (`arenas()`)
+      fresh         [B] int32 — 1 where the row starts a sequence (its first
+                    chunk, also after preemption): it starts from zeros
+    and the model leaves on `step_stats` the int32 vector of counts its
+    `step_stat_names` names, which the engine hands back to the host.
+
     `mesh` (static, not an array) is the tensor-parallel serving mesh
     (serving/sharded.py) or None: it selects the per-shard Pallas dispatch
     and lets `constrain` pin traced activations to the tp layout.
@@ -241,7 +252,8 @@ class PagedState:
     def __init__(self, k, v, block_tables, slots, offs, qpos,
                  q_start=None, kv_live=None, q_lens=None, mesh=None,
                  k_scale=None, v_scale=None, touched=None, touch_idx=None,
-                 quant_collectives=frozenset(), lora=None):
+                 quant_collectives=frozenset(), lora=None, lane_state=None,
+                 fresh=None):
         self.k = k
         self.v = v
         self.block_tables = block_tables
@@ -262,6 +274,18 @@ class PagedState:
         # b_rows [B,L,r,out])} or None (no adapters in the program).
         # `PagedLayerView.column_parallel` consults it per op.
         self.lora = lora
+        self.lane_state = lane_state
+        self.fresh = fresh
+        self.step_stats = None
+
+    def live_slots(self, width):
+        """`[B, width]` bool: which slots of the step carry a token. A row's
+        live tokens are a prefix (`q_lens`); a lane without a request has no
+        live block (`kv_live` 0) and carries none."""
+        import jax.numpy as jnp
+
+        return ((jnp.arange(width)[None, :] < self.q_lens[:, None])
+                & (self.kv_live[:, None] > 0))
 
     def layer(self, i):
         """The view of cache slab `i` (a layer's; a looped model's (pass,
@@ -272,11 +296,17 @@ class PagedState:
         """The arena arrays a layer's `attend` replaces, as a tuple: what a
         model that runs its layers inside a `lax` loop carries through it
         (models/looped.py), without knowing which arrays an engine has."""
-        if self.k_scale is None:
-            return (self.k, self.v)
-        return (self.k, self.v, self.k_scale, self.v_scale)
+        kv = ((self.k, self.v) if self.k_scale is None
+              else (self.k, self.v, self.k_scale, self.v_scale))
+        if self.lane_state is None:
+            return kv
+        return kv + tuple(self.lane_state.values())
 
     def set_arenas(self, arenas):
+        if self.lane_state is not None:
+            n = len(arenas) - len(self.lane_state)
+            self.lane_state = dict(zip(self.lane_state, arenas[n:]))
+            arenas = arenas[:n]
         if self.k_scale is None:
             self.k, self.v = arenas
         else:
@@ -442,12 +472,21 @@ class BlockPool:
     `num_layers` is the arena's leading axis, the model's `kv_slabs`: one
     slab a layer, or a looped model's (pass, layer). The bookkeeping here
     is per LOGICAL block whatever that count: a block id names the same
-    `block_size` token slots in every slab.
+    `block_size` token slots in every slab. `num_heads` is the arena's
+    head count, the model's `kv_heads` (fewer than its query heads under
+    grouped-query attention).
+
+    `lane_state` (`{name: (shape, dtype)}`, the model's `lane_state_spec`)
+    is state a LANE and not a block holds: one array a name, `shape` with
+    the `lanes` axis put in second, zeros. The pool owns them beside the
+    arenas (`arenas()` hands all of it to the step program, donated, and
+    `set_arenas` takes it back); nothing here addresses them by position,
+    so prefix hits, copy-on-write and the host tier do not carry them.
     """
 
     def __init__(self, num_blocks, num_layers, block_size, num_heads,
                  head_dim, dtype=None, metrics=None, tracer=None,
-                 sharding=None, kv_dtype=None):
+                 sharding=None, kv_dtype=None, lane_state=None, lanes=0):
         import jax.numpy as jnp
 
         if num_blocks < 2:
@@ -497,6 +536,10 @@ class BlockPool:
                 sc_zeros = _sharded_zeros_fn(sc_shape, "float32", sharding)
                 self.k_scale = sc_zeros()
                 self.v_scale = sc_zeros()
+        self.lane_state = {
+            name: jnp.zeros((shape[0], int(lanes)) + tuple(shape[1:]),
+                            dt_ or dtype or jnp.float32)
+            for name, (shape, dt_) in (lane_state or {}).items()}
         # block 0 reserved as the null/scratch block
         self._free = list(range(self.num_blocks - 1, 0, -1))
         self._refcount = {}           # block -> holders (held blocks only)
@@ -508,6 +551,26 @@ class BlockPool:
         self.tracer = tracer          # serving/trace.py EngineTracer or None
         self._copy_fn = None          # jitted donated block-copy (lazy)
         self.tier = None              # host-memory tier (serving/kv_tier.py)
+
+    def arenas(self):
+        """Everything the step program is handed donated and hands back, in
+        its order: the K/V arenas, the int8 scale sidecars, the lane
+        state."""
+        kv = ((self.k, self.v, self.k_scale, self.v_scale) if self.quantized
+              else (self.k, self.v))
+        return kv + tuple(self.lane_state.values())
+
+    def set_arenas(self, arenas):
+        n = 4 if self.quantized else 2
+        if self.quantized:
+            self.k, self.v, self.k_scale, self.v_scale = arenas[:n]
+        else:
+            self.k, self.v = arenas[:n]
+        self.lane_state = dict(zip(self.lane_state, arenas[n:]))
+
+    def state_bytes_per_lane(self):
+        """Device bytes of lane state one lane holds, all layers."""
+        return sum(a.nbytes // a.shape[1] for a in self.lane_state.values())
 
     def attach_tier(self, tier):
         """Install the host-memory tier (serving/kv_tier.py): evicted

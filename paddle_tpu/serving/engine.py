@@ -136,13 +136,16 @@ StepOutput = namedtuple("StepOutput", ["request_id", "token", "finished"])
 
 
 @functools.lru_cache(maxsize=None)
-def step_input_layout(B, W, max_blocks, touched_width=None):
+def step_input_layout(B, W, max_blocks, touched_width=None,
+                      lane_state=False):
     """THE layout of a step's host inputs: ``({name: (offset, shape,
     dtype)}, words)``. What the host hands the device for a step is ONE
     int32 array of ``words`` elements, and every named input is a section
     of it, in the order below — a pure function of what the (B, W)
     program's shape already depends on (``touched_width`` is the int8
-    arena's `_touched_width`, None for a float arena). `_row_arrays` makes
+    arena's `_touched_width`, None for a float arena; ``lane_state`` says
+    that the pool holds state a lane, and adds the section ``fresh``: 1
+    where a row starts its sequence from zeros). `_row_arrays` makes
     its views from this table and the jitted step (`unpack_step_input`)
     its slices, so the two cannot drift apart. The float32 sections are
     views of the same words (``ndarray.view`` on the host,
@@ -154,6 +157,7 @@ def step_input_layout(B, W, max_blocks, touched_width=None):
         ("q_start", (B,), i32), ("kv_live", (B,), i32),
         *([("touched", (B, touched_width), i32), ("touch_idx", (B, W), i32)]
           if touched_width else []),
+        *([("fresh", (B,), i32)] if lane_state else []),
         ("adapter_slots", (B,), i32), ("last_idx", (B,), i32),
         ("spec_lens", (B,), i32), ("temps", (B,), f32), ("top_ks", (B,), i32),
         ("top_ps", (B,), f32),
@@ -293,15 +297,25 @@ class LLMEngine:
             env_tp = int(os.environ.get("PADDLE_TPU_TP", "1") or 1)
             mesh = env_tp if env_tp > 1 else None
         self._smesh = as_serving_mesh(mesh)
+        # heads of a cache slab: the model's key/value heads (fewer than
+        # its query heads under grouped-query attention). THE one place the
+        # arena's head count is taken from
+        self.kv_heads = int(getattr(model, "kv_heads", cfg.num_heads))
         if self._smesh is not None:
             _refuse_option(
                 model, "tp", "mesh= (tensor-parallel serving)",
                 "the tp placement and its collective budget are written "
                 "for GPT's fused-QKV block; serve it on one chip")
-            self._smesh.validate_model(cfg)
+            self._smesh.validate_model(cfg, self.kv_heads)
         # cache slabs a token's K/V take: the model says (a GPT: one a
         # layer; a looped model: one a (pass, layer))
         self.kv_slabs = int(model.kv_slabs)
+        # state a lane holds that no position addresses (a state-space
+        # layer's recurrent state): the model says what, the pool owns it.
+        # What hands a request blocks WITHOUT the tokens that made them
+        # cannot rebuild such state, so those options are refused
+        spec = getattr(model, "lane_state_spec", None)
+        self._lane_spec = spec() if spec is not None else {}
         # int8 KV arena (`kv_dtype="int8"` / PADDLE_TPU_KV_DTYPE): payload
         # bytes quarter (vs f32) and the SAME kv_hbm_bytes budget admits
         # ~4x the blocks — behind the parity/perplexity quality gates in
@@ -394,7 +408,7 @@ class LLMEngine:
             # scale-sidecar overhead — this is where the same budget
             # starts admitting ~4x (f32) / ~2x (bf16) the sequences.
             num_blocks = kv_capacity_blocks(
-                kv_hbm_bytes, self.kv_slabs, cfg.num_heads,
+                kv_hbm_bytes, self.kv_slabs, self.kv_heads,
                 self.block_size, self._head_dim(),
                 1 if self.kv_quantized else self._weight_dtype().itemsize,
                 tp_degree=(1 if self._smesh is None
@@ -432,8 +446,12 @@ class LLMEngine:
         # prefix caching: constructor arg wins, then the env kill switch
         self.prefix_cache = (
             _env_flag("PADDLE_TPU_PREFIX_CACHE", True)
+            and not self._lane_spec       # lane state: off by default
             if prefix_cache is None else bool(prefix_cache)
         )
+        self._refuse_for_lane_state(
+            self.prefix_cache, "prefix_cache=True",
+            "a prefix hit hands a request blocks and no state")
         # speculative decoding: default OFF; constructor arg wins over the
         # PADDLE_TPU_SPEC_DECODE env gate. num_spec_tokens fixes the verify
         # program's width (per-request knobs can only lower the draft cap)
@@ -441,6 +459,9 @@ class LLMEngine:
             _env_flag("PADDLE_TPU_SPEC_DECODE", False)
             if spec_decoding is None else bool(spec_decoding)
         )
+        self._refuse_for_lane_state(
+            self.spec_decoding, "spec_decoding=True",
+            "a rejected draft has already moved the state")
         self.num_spec_tokens = int(num_spec_tokens)
         drafter = None
         if self.spec_decoding:
@@ -609,12 +630,13 @@ class LLMEngine:
                     "(LLMEngine(skeleton, checkpoint_path=..., mesh=N)) "
                     "so no chip ever materializes the full tree")
         self.pool = BlockPool(
-            num_blocks, self.kv_slabs, self.block_size, cfg.num_heads,
+            num_blocks, self.kv_slabs, self.block_size, self.kv_heads,
             self._head_dim(), dtype=self._weight_dtype(),
             metrics=self.metrics, tracer=self.tracer,
             sharding=(None if self._smesh is None
                       else self._smesh.arena_sharding()),
             kv_dtype=self.kv_dtype,
+            lane_state=self._lane_spec, lanes=self.max_batch,
         )
         # host-memory KV tier (serving/kv_tier.py): `host_kv_blocks` host
         # block slots make evicted cached prefixes swap-back-able instead
@@ -625,6 +647,9 @@ class LLMEngine:
             host_kv_blocks = int(
                 os.environ.get("PADDLE_TPU_HOST_KV_BLOCKS", "0") or 0)
         self.tier = None
+        self._refuse_for_lane_state(
+            host_kv_blocks, "host_kv_blocks (the host KV tier)",
+            "a swap carries blocks and no state")
         if host_kv_blocks:
             from .kv_tier import KVTier
 
@@ -646,6 +671,18 @@ class LLMEngine:
         self.metrics.set_gauge("kv_bytes_per_block",
                                self.pool.bytes_per_block())
         self.metrics.set_gauge("kv_slabs", self.kv_slabs)
+        self.metrics.set_gauge("kv_heads", self.kv_heads)
+        self.metrics.set_gauge("state_bytes_per_lane",
+                               self.pool.state_bytes_per_lane())
+        self.metrics.set_gauge(
+            "state_layers",
+            max((a.shape[0] for a in self.pool.lane_state.values()),
+                default=0))
+        for name, value in getattr(model, "serving_gauges", dict)().items():
+            self.metrics.set_gauge(name, value)
+        # counts the model's step leaves on the paged state
+        # (`step_stats`), which ride the packed result to the step span
+        self._stat_names = tuple(getattr(model, "step_stat_names", ()))
         self.metrics.set_gauge(
             "kv_bytes_per_token",
             self.pool.bytes_per_block() / self.block_size)
@@ -739,6 +776,13 @@ class LLMEngine:
         """The type the model's weights are served in, which a float arena
         takes: the first parameter's (a skeleton's abstract one too)."""
         return self.model.parameters()[0]._array.dtype
+
+    def _refuse_for_lane_state(self, asked, option, why):
+        if asked and self._lane_spec:
+            raise ValueError(
+                f"{option} is not available for "
+                f"{type(self.model).__name__}, which keeps state a lane "
+                f"({', '.join(self._lane_spec)}) that no block holds: {why}")
 
     def _head_dim(self):
         cfg = self.model.cfg
@@ -1244,7 +1288,8 @@ class LLMEngine:
         def forward(params, buffers, k_arena, v_arena, lora_tables,
                     adapter_slots, ids, block_tables, slots, offs, qpos,
                     q_start, kv_live, q_lens, k_scale=None, v_scale=None,
-                    touched=None, touch_idx=None):
+                    touched=None, touch_idx=None, lane_state=None,
+                    fresh=None):
             # runs at TRACE time only — the test's recompile alarm
             metrics.inc("jit_traces")
             state = PagedState(k_arena, v_arena, block_tables, slots, offs,
@@ -1259,7 +1304,8 @@ class LLMEngine:
                                # engine has no adapter slots, keeping the
                                # trace byte-identical to the pre-LoRA one
                                lora=gather_adapter_rows(lora_tables,
-                                                        adapter_slots))
+                                                        adapter_slots),
+                               lane_state=lane_state, fresh=fresh)
             # mask the process-global TRAINING mesh for the trace (thread-
             # local — a concurrent training trace on another thread keeps
             # its mesh): the serving step's sharding is fully explicit
@@ -1321,11 +1367,15 @@ class LLMEngine:
             return packed
 
         fields, _ = self._step_layout(W)
-        n_arena = 4 if quantized else 2
+        n_kv = 4 if quantized else 2
+        lane_names = tuple(self.pool.lane_state)
+        n_arena = n_kv + len(lane_names)
+        stats = bool(self._stat_names)
 
         # ONE signature for both arena kinds: (params, buffers, *arenas,
         # lora_tables, packed, key). The arenas are the payload pair, plus
-        # the f32 scale sidecars right after it when the arena is int8;
+        # the f32 scale sidecars right after it when the arena is int8,
+        # plus the pool's lane state after those where a model keeps any;
         # `packed` is the step's whole host input (`step_input_layout`),
         # `key` the engine's sampling key, which lives on the device
         def step(params, buffers, *rest):
@@ -1338,18 +1388,25 @@ class LLMEngine:
             # per-row live width for the ragged kernel: chunk tokens
             # through last_idx plus the drafted candidates
             q_lens = last_idx + 1 + spec_lens
-            int8 = {}
+            more = {}
             if quantized:
                 # the scatter's touched-block lists ride the host input
-                int8 = dict(k_scale=arenas[2], v_scale=arenas[3],
+                more = dict(k_scale=arenas[2], v_scale=arenas[3],
                             touched=a["touched"], touch_idx=a["touch_idx"])
+            if lane_names:
+                more.update(lane_state=dict(zip(lane_names, arenas[n_kv:])),
+                            fresh=a["fresh"])
             logits, state = forward(
                 params, buffers, arenas[0], arenas[1], lora_tables,
                 a["adapter_slots"], a["ids"], a["tables"], a["slots"],
                 a["offs"], a["qpos"], a["q_start"], a["kv_live"], q_lens,
-                **int8)
+                **more)
             out = _decide(logits, state, a["ids"], last_idx, spec_lens,
                           a["temps"], a["top_ks"], a["top_ps"], sub)
+            if stats:
+                # the model's counts behind the rows' results: still ONE
+                # array for the host to read
+                out = jnp.concatenate([out.reshape(-1), state.step_stats])
             return (out, *state.arenas(), key)
 
         # donated arena state: payload arenas, plus the f32 scale
@@ -1404,7 +1461,8 @@ class LLMEngine:
         """`step_input_layout` of this engine's width-``W`` program."""
         return step_input_layout(
             self.max_batch, W, self.max_blocks,
-            self._touched_width(W) if self.pool.quantized else None)
+            self._touched_width(W) if self.pool.quantized else None,
+            bool(self.pool.lane_state))
 
     def expected_program_count(self):
         """THE program-count contract, in one place: the engine compiles
@@ -1464,10 +1522,7 @@ class LLMEngine:
 
     def _arenas(self):
         """The pool's arena arrays in the step program's order."""
-        pool = self.pool
-        if pool.quantized:
-            return (pool.k, pool.v, pool.k_scale, pool.v_scale)
-        return (pool.k, pool.v)
+        return self.pool.arenas()
 
     def step_program_args(self, W):
         """THE statement of the width-``W`` step program's arguments, in
@@ -1512,7 +1567,7 @@ class LLMEngine:
             # in tests/test_ir_contracts.py patches the gate ungated and
             # must fail the contract)
             donation_on = jax.default_backend() != "cpu"
-        n_arena = 4 if self.pool.quantized else 2
+        n_arena = len(self.pool.arenas())
         return {
             "arena_param_indices": tuple(
                 range(n_state, n_state + n_arena)),
@@ -1626,15 +1681,11 @@ class LLMEngine:
             self._span_args.update(
                 h2d_arrays=1, h2d_bytes=packed.nbytes,
                 h2d_ms=(time.monotonic() - t_disp) * 1e3)
-        pool = self.pool
         with self._annotation(step_id):
             out, *arenas, self._key = fn(
                 self._params, self._buffers, *self._arenas(),
                 self._lora_tables, dev, self._key)
-        if pool.quantized:
-            pool.k, pool.v, pool.k_scale, pool.v_scale = arenas
-        else:
-            pool.k, pool.v = arenas
+        self.pool.set_arenas(arenas)
         return out
 
     # -- fault hooks (serving/faults.py; armed plans only) -----------------
@@ -1672,7 +1723,7 @@ class LLMEngine:
                 else f"injected step fault ({fp.exc})",
             )
 
-    def _corrupt_row_ok(self, rows, row_ok):
+    def _corrupt_row_ok(self, placed, row_ok):
         """``step_nonfinite_logits``: report the matched rows' logits as
         non-finite, driving the containment path below exactly as a real
         numerically-poisoned forward would. Only reached when a plan is
@@ -1680,7 +1731,7 @@ class LLMEngine:
         plan = faults._PLAN
         # np.asarray of a device array is typically a read-only view
         row_ok = np.array(row_ok)
-        for i, row in enumerate(rows):
+        for i, row in placed:
             fp = plan.match("step_nonfinite_logits", step=self.step_count,
                             request_ids=(row.req.request_id,))
             if fp is not None:
@@ -1932,7 +1983,12 @@ class LLMEngine:
         t_build = time.monotonic() if tr is not None else 0.0
         packed, a = self._row_arrays(W)
         last_idx, spec_lens = a["last_idx"], a["spec_lens"]
-        for i, row in enumerate(rows):
+        # which row of the program each planned row takes: the next one, or,
+        # where the pool holds state a lane, the request's own lane, every
+        # step (lanes between them idle: no live block, nothing moves)
+        at = ([row.req.lane for row in rows] if self.pool.lane_state
+              else range(len(rows)))
+        for i, row in zip(at, rows):
             req, start, count, k = row.req, row.start, row.count, len(row.draft)
             if start == req.num_tokens - 1:
                 # decode fast path: the single pending token is always the
@@ -1946,14 +2002,17 @@ class LLMEngine:
                 a["ids"][i, count:count + k] = row.draft
             last_idx[i] = count - 1
             spec_lens[i] = k
+            if self.pool.lane_state:
+                # position 0 has no history: the first chunk, also of a
+                # request re-admitted after preemption
+                a["fresh"][i] = start == 0
             self._fill_row(a, i, req, start, count + k, W)
         fn = self._get_step_fn(self.max_batch, W)
         K = self._draft_capacity(W)
         if tr is not None:
             # what the ragged kernel walks for this step, a head a layer:
             # lanes past the planned rows carry no request (q_len 0)
-            q_lens = np.where(np.arange(self.max_batch) < len(rows),
-                              last_idx + 1 + spec_lens, 0)
+            q_lens = np.where(a["kv_live"] > 0, last_idx + 1 + spec_lens, 0)
             live, total = ragged_grid_steps(q_lens, a["kv_live"], W,
                                             self.block_size)
             # and what the append's writer copies back, an arena a layer
@@ -1966,13 +2025,19 @@ class LLMEngine:
         # runs, accept lengths, and row-finite flags for the whole step
         out = np.asarray(out_dev)
         self.metrics.inc("host_syncs")
+        if self._stat_names:
+            n = len(self._stat_names)
+            if tr is not None:
+                self._span_args.update(
+                    zip(self._stat_names, map(int, out[-n:])))
+            out = out[:-n].reshape(self.max_batch, K + 3)
         run, n_accs, row_ok = (out[:, :K + 1], out[:, K + 1],
                                out[:, K + 2])
         if faults._PLAN is not None:
-            row_ok = self._corrupt_row_ok(rows, row_ok)
+            row_ok = self._corrupt_row_ok(zip(at, rows), row_ok)
         t_emit = time.monotonic() if tr is not None else 0.0
         outs = []
-        for i, row in enumerate(rows):
+        for i, row in zip(at, rows):
             req, k = row.req, len(row.draft)
             if not row_ok[i]:
                 # NaN/Inf logits: abort this row only — its KV and token

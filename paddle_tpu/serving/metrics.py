@@ -131,6 +131,15 @@ _HELP = {
                 "passes of a looped model",
     "kv_bytes_per_token": "Device bytes of KV one cached token costs, "
                           "all slabs",
+    "kv_heads": "Heads of a cache slab: the model's key/value heads "
+                "(fewer than its query heads under grouped-query "
+                "attention)",
+    "state_bytes_per_lane": "Device bytes of state a lane holds that no "
+                            "position addresses (a state-space layer's "
+                            "recurrent state), all layers; 0: none",
+    "state_layers": "Layers that keep such state a lane",
+    "experts_held": "Routed experts of an expert layer this chip holds",
+    "experts_total": "Routed experts the router chooses among",
     "pool_kv_bytes_per_block": "Device bytes one KV block costs in the "
                                "active KV dtype (int8 arenas include the "
                                "f32 scale sidecars)",

@@ -18,7 +18,10 @@ token only when it reaches the sequence's last pending position — replayed
 chunks after a preemption emit nothing until the replay catches up, so
 recompute never re-emits tokens.
 
-Admission is FCFS into free lanes (``max_batch`` rows). KV blocks are
+Admission is FCFS into free lanes (``max_batch`` rows); a request keeps
+the lane it is given (`Request.lane`) until it finishes, is aborted or is
+preempted, which is what binds state a lane holds (a state-space layer's,
+block_pool.py) to it. KV blocks are
 allocated chunk-by-chunk as rows are planned, oldest sequence first; when
 the pool runs dry a row preempts the youngest running sequence that holds
 blocks (vLLM's recompute policy, FCFS priority: older may reclaim from
@@ -161,6 +164,11 @@ class Request:
         self.adapter = None if adapter is None else str(adapter)[:64]
         # device table row the engine resolved `adapter` to (0 = base)
         self.adapter_slot = 0
+        # the lane (row of the step program) the request holds while it
+        # runs: given at admission, given back when it finishes, is aborted
+        # or preempted. State a lane holds (block_pool.py `lane_state`) is
+        # this request's for that long
+        self.lane = None
         self.deadline_s = None if deadline_s is None else float(deadline_s)
         if self.deadline_s is not None and self.deadline_s <= 0:
             raise ValueError("deadline_s must be > 0 (or None)")
@@ -261,6 +269,7 @@ class Scheduler:
         self.policy_rejects = []
         self.waiting = deque()
         self.running = []
+        self._free_lanes = list(range(self.max_batch - 1, -1, -1))
 
     def _precedence(self, req):
         """The planning/preemption total order: the policy's
@@ -319,11 +328,17 @@ class Scheduler:
         req.num_cached = 0
         req.num_matched_blocks = 0
 
+    def _leave(self, req):
+        """Out of the running set, its lane free again."""
+        if req in self.running:
+            self.running.remove(req)
+            self._free_lanes.append(req.lane)
+            req.lane = None
+
     def finish(self, req):
         req.state = FINISHED
         self._release_blocks(req)
-        if req in self.running:
-            self.running.remove(req)
+        self._leave(req)
 
     def abort(self, req):
         """Remove a request from the scheduler in ANY live state — queued
@@ -336,8 +351,7 @@ class Scheduler:
             return
         req.state = ABORTED
         self._release_blocks(req)
-        if req in self.running:
-            self.running.remove(req)
+        self._leave(req)
         try:
             self.waiting.remove(req)
         except ValueError:
@@ -369,8 +383,7 @@ class Scheduler:
             self.slo.transition(req, "preempted", req.wait_since)
         if self.tracer is not None and req.traced:
             self.tracer.request_instant(req, "preempt")
-        if req in self.running:
-            self.running.remove(req)
+        self._leave(req)
         self.waiting.appendleft(req)
         if self.metrics is not None:
             self.metrics.inc("preemptions")
@@ -551,6 +564,7 @@ class Scheduler:
                 else "decode_compute", now)
         if self.tracer is not None and req.traced:
             self.tracer.request_admitted(req, now)
+        req.lane = self._free_lanes.pop()
         self.running.append(req)
 
     def schedule(self, only=None):

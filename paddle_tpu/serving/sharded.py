@@ -137,11 +137,14 @@ class ServingMesh:
         per = num_heads // tp
         return [(i * per, (i + 1) * per) for i in range(tp)]
 
-    def validate_model(self, cfg):
+    def validate_model(self, cfg, kv_heads=None):
         """Reject a model the tp degree cannot shard evenly: attention
         heads, FFN columns, and the (vocab-parallel) embedding rows must
         all divide, or GSPMD would silently pad — and the head-sharded
-        arena would not tile. One loud error at engine construction."""
+        arena would not tile. One loud error at engine construction.
+        `kv_heads` is the arena's head count (the engine's; the model's
+        query heads when None): a tp degree above it leaves chips with no
+        head of the cache to hold."""
         tp = self.tp_degree
         for name, dim in (("num_heads", cfg.num_heads),
                           ("intermediate_size", cfg.intermediate_size),
@@ -151,6 +154,12 @@ class ServingMesh:
                     f"tp_degree {tp} does not divide {name} {dim} — pick "
                     "a tp degree that divides the head/FFN/vocab dims"
                 )
+        kv_heads = cfg.num_heads if kv_heads is None else int(kv_heads)
+        if tp > kv_heads or kv_heads % tp:
+            raise ValueError(
+                f"tp_degree {tp} cannot shard a KV arena of {kv_heads} "
+                "key/value heads: the arena is sharded by head, so the tp "
+                "degree must divide the model's kv_heads and not exceed it")
 
     def info(self):
         """Topology facts for /healthz and the mesh gauges."""

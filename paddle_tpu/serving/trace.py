@@ -24,7 +24,14 @@ exactly those two views as Chrome/Perfetto trace events:
   append's writer copies back (``append_blocks``) and the hand-over to
   the device inside ``dispatch``: ``h2d_arrays`` (one: the step's packed
   host input), ``h2d_bytes`` (its size) and ``h2d_ms`` (that one
-  transfer: ``dispatch``'s start to just before the launch). Pool
+  transfer: ``dispatch``'s start to just before the launch). A model
+  with expert layers and state a lane (models/nemotron_h.py) adds the
+  counts its step program makes, which ride the step's one packed
+  result: ``moe_pairs`` (picks of live tokens), ``moe_pairs_local``
+  (those naming an expert held here, computed), ``moe_experts_hit``
+  (held experts with a token), all three summed over the expert
+  layers, ``moe_max_load`` (the largest load of one expert of one
+  layer) and ``state_rows`` (lanes whose state moved). Pool
   evictions land as instants on a ``block-pool`` track.
 
 The ring buffer, clocks, export, and the xplane join annotation are the

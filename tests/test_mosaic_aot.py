@@ -115,6 +115,20 @@ for dt in ("bfloat16", "float32", "int8"):
         assert compile_for_v5e(append, arena, arena, new, new, targets,
                                targets, case=case) == 1, case
 
+# grouped-query attention at the hybrid configuration's sizes (PR 36): 32
+# query heads over an arena of 2 K/V heads of 128, 32 rows; the walk fetches
+# a K/V head once for its 16 query heads, the writer writes two heads
+GB, GH, GKV = 32, 32, 2
+for width in (1, 128):
+    arena = sds((1, GKV, NB, bs, D), "bfloat16")
+    args = [sds((GB, width, GH, D), "bfloat16"), arena, arena,
+            sds((GB, nk), "int32")] + [sds((GB,), "int32")] * 3
+    assert compile_for_v5e(ragged, *args, case=f"ragged-gqa-w{width}") == 1
+    new = sds((GB, width, GKV, D), "bfloat16")
+    targets = sds((GB, width), "int32")
+    assert compile_for_v5e(append, arena, arena, new, new, targets, targets,
+                           case=f"append-gqa-w{width}") == 1
+
 # heads of 64: Mosaic pads the arena's minor dimension to the 128 lanes in
 # HBM and then refuses the page copies' slices of it, which is why
 # `paged_attention_arrays` keeps such a model on the XLA path
@@ -202,6 +216,26 @@ for W in engine.width_buckets:
     compile_for_v5e(engine._get_step_fn(B, W), *step_args(engine, W),
                     case=f"looped-step-w{W}")
 
+# the hybrid block (models/nemotron_h.py): one Mamba, one attention and one
+# expert layer; the arena has the model's 2 K/V heads under 4 query heads,
+# the pool's lane state rides beside it, and the grouped product of the
+# expert layer (`jax.lax.ragged_dot`) goes through the TPU compiler
+from paddle_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+
+engine = LLMEngine(
+    NemotronH(NemotronHConfig(
+        vocab_size=256, hidden_size=256, pattern="M*E", num_heads=4,
+        num_kv_heads=2, head_dim=128, mamba_num_heads=8, mamba_head_dim=64,
+        ssm_state_size=128, n_groups=2, chunk_size=32, moe_latent_size=128,
+        moe_intermediate_size=256, shared_intermediate_size=256,
+        router_experts=32, experts_held=8, experts_per_tok=4,
+        max_seq_len=256)),
+    block_size=16, max_batch=4, max_seq_len=256, prefill_chunk=32)
+assert engine.pool.k.shape[:2] == (1, 2)
+for W in engine.width_buckets:
+    compile_for_v5e(engine._get_step_fn(B, W), *step_args(engine, W),
+                    case=f"hybrid-step-w{W}")
+
 # the arena through a step program: appended to and read by the kernel in
 # one layout, so the compiler relays it nowhere. Heads of 128 and arenas of
 # 128 MiB: a smaller one the compiler keeps in S(1) when it does relay it,
@@ -261,7 +295,22 @@ EXPECTED = {
     # compiled once, so two pairs and not four
     "looped-step-w1": {"paged_kv_append": 2, "ragged_paged_attention": 2},
     "looped-step-w32": {"paged_kv_append": 2, "ragged_paged_attention": 2},
+    # grouped-query attention: 32 query heads over 2 K/V heads of 128
+    **{f"ragged-gqa-w{w}": {"ragged_paged_attention": 1} for w in (1, 128)},
+    **{f"append-gqa-w{w}": {"paged_kv_append": 1} for w in (1, 128)},
 }
+
+
+def test_hybrid_step_programs_hold_one_kernel_pair(aot):
+    """The hybrid block's one attention layer: one writer call and one
+    ragged call a program, under the names the readers match; the other
+    custom calls of the program are the compiler's own (the expert layer's
+    grouped products, the router's top-k)."""
+    names = _facts(aot, "KERNEL_NAMES")
+    for w in (1, 32):
+        got = names[f"hybrid-step-w{w}"]
+        assert got.get("paged_kv_append") == 1, got
+        assert got.get("ragged_paged_attention") == 1, got
 
 
 @pytest.fixture(scope="module")

@@ -250,6 +250,28 @@ def test_gpt_step_programs_are_textually_the_parents():
         assert got == want[setup], setup
 
 
+LOOPED_SETUPS = {"default": {}, "int8": {"kv_dtype": "int8"},
+                 "spec": {"spec_decoding": True}}
+
+
+def test_looped_step_programs_are_textually_the_parents():
+    """The same witness for the looped decoder (PR 36, which gave the pool
+    state a lane, the arena the model's key/value heads and the step input
+    a section that a model with such state adds): the seven lowered step
+    programs of three engines over `build(2)` have the text they had at PR
+    35 (`tests/looped_step_programs_pr35.json`, hashed there from a copy of
+    that commit with this jax)."""
+    want = json.loads((REPO / "tests/looped_step_programs_pr35.json")
+                      .read_text())
+    assert sorted(want) == sorted(LOOPED_SETUPS)
+    for setup, kw in sorted(LOOPED_SETUPS.items()):
+        engine = LLMEngine(build(2), block_size=8, max_batch=2,
+                           max_seq_len=64, prefill_chunk=16, **kw)
+        got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()
+               for name, low in engine.lowered_step_programs().items()}
+        assert got == want[setup], setup
+
+
 def test_the_benchmark_has_the_configuration_and_its_cell():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     entry, = [c for c in bench["configs"] if c["name"] == "ouro-2p6b-serve"]

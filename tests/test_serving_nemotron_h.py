@@ -1,0 +1,283 @@
+"""The hybrid block served through `LLMEngine`: prefill in chunks and then
+decode, through the paged arena of its attention layer AND the state a lane
+its Mamba layers keep in the pool, against the plain reference's full
+forward (`benchmark/reference/nemotron_h_block.py`), on logits.
+
+The logits are the step programs' own, kept as `tests/test_serving_looped.py`
+keeps them: the model's forward hands every step's `[rows, width, vocab]`
+logits to the host, and the planned rows say which request and position each
+belongs to; a row of the program is the request's LANE here.
+
+Tolerance. Float32 weights, arena and state: served logits and the
+reference's differ in the order of float32 sums (the chunk form, the paged
+walk): 1e-4 on logits whose spread is about 0.6, measured 3e-6.
+"""
+import json
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+
+from paddle_tpu.serving import LLMEngine
+from paddle_tpu.serving.engine import step_input_layout
+
+from _nemotron_common import (VOCAB, build, load_reference, params_of,
+                              published_keys)
+
+REPO = Path(__file__).resolve().parents[1]
+TOL = 1e-4
+ref = load_reference()
+KW = dict(block_size=4, max_batch=3, max_seq_len=96, prefill_chunk=8)
+
+
+class Served:
+    """An engine over `model` whose every step's logits are kept:
+    `logits[request][position]` is the `[vocab]` row that chose the token at
+    `position + 1`; `plans` the steps' rows `(request, lane, start, count)`."""
+
+    def __init__(self, model, **engine_kw):
+        self.model = model
+        self.logits, self.plans, self._plans, self._seen = {}, [], [], []
+        plain = model.forward
+
+        def forward(ids, caches=None):
+            logits, caches = plain(ids, caches=caches)
+            jax.debug.callback(lambda lg: self._seen.append(np.asarray(lg)),
+                               logits._array)
+            return logits, caches
+
+        model.forward = forward
+        self.engine = LLMEngine(model, **engine_kw)
+        run_rows = self.engine._run_rows
+
+        def planned(rows, W, step_id=0):
+            self._plans.append([(r.req.request_id, r.req.lane, r.start,
+                                 r.count) for r in rows])
+            return run_rows(rows, W, step_id)
+
+        self.engine._run_rows = planned
+
+    def step(self, only=None):
+        outs = self.engine.step(only=only)
+        jax.effects_barrier()
+        assert len(self._plans) == len(self._seen)
+        for plan, lg in zip(self._plans, self._seen):
+            for rid, lane, start, count in plan:
+                for j in range(count):
+                    self.logits.setdefault(rid, {})[start + j] = lg[lane, j]
+        self.plans += self._plans
+        self._plans, self._seen = [], []
+        return outs
+
+    def run(self):
+        while self.engine.has_unfinished():
+            self.step()
+
+
+def _prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [[int(t) for t in rng.integers(0, VOCAB, n)] for n in lengths]
+
+
+def _worst(served, rid, prompt):
+    """Largest gap between the served logits of `rid` at every position it
+    was fed and the reference's full forward over prompt + answer."""
+    ids = (prompt + list(served.outputs[rid]))[:-1]
+    want = np.asarray(ref.sequence_logits(
+        params_of(served.model), ids, published_keys(served.model)))
+    got = served.logits[rid]
+    assert sorted(got) == list(range(len(ids)))
+    return max(np.abs(got[p] - want[p]).max() for p in got)
+
+
+def _serve(served, prompts, max_new_tokens):
+    rids = [served.engine.add_request(p, max_new_tokens=max_new_tokens)
+            for p in prompts]
+    reqs = [served.engine.get_request(r) for r in rids]
+    served.run()
+    served.outputs = {r: list(q.output_ids) for r, q in zip(rids, reqs)}
+    return rids
+
+
+def test_chunked_prefill_then_decode_gives_the_references_logits():
+    """Four requests through three lanes: prompts of 19, 5, 11 and 9 tokens
+    in chunks of 8 (the state and the window cross chunk edges), rows in
+    different phases in one step (a prompt's second chunk beside decode
+    rows), and the fourth request in a lane the second left."""
+    served = Served(build(experts_held=4, experts_from=4), **KW)
+    prompts = _prompts((19, 5, 11, 9))
+    rids = _serve(served, prompts, 7)
+    for rid, prompt in zip(rids, prompts):
+        assert _worst(served, rid, prompt) < TOL
+    mixed = [p for p in served.plans
+             if any(c > 1 for *_, c in p) and any(c == 1 and s > 0
+                                                  for _, _, s, c in p)]
+    assert mixed, "no step held a chunk row beside a decode row"
+    lanes = {}
+    for plan in served.plans:
+        for rid, lane, *_ in plan:
+            lanes.setdefault(rid, set()).add(lane)
+    assert all(len(v) == 1 for v in lanes.values())     # bound to a lane
+    assert lanes[rids[3]] <= lanes[rids[0]] | lanes[rids[1]] | lanes[rids[2]]
+    m = served.engine.metrics
+    assert m.counters["jit_traces"] == 2 and m.gauges["jit_retraces"] == 0
+    assert m.gauges["kv_heads"] == 2 and m.gauges["state_layers"] == 2
+    assert m.gauges["experts_held"] == 4 and m.gauges["experts_total"] == 16
+    cfg = served.model.cfg
+    assert m.gauges["state_bytes_per_lane"] == 2 * 4 * (
+        cfg.mamba_num_heads * cfg.mamba_head_dim * cfg.ssm_state_size
+        + (cfg.conv_kernel - 1) * cfg.conv_channels)
+
+
+def test_preemption_by_recompute_starts_the_state_from_zeros():
+    """An arena too small for three requests at once: the youngest is
+    preempted, re-admitted (into whatever lane is free, over whatever state
+    that lane's last tenant left) and replayed from position 0; its logits
+    are the reference's all the same."""
+    served = Served(build(), num_blocks=14, **KW)
+    prompts = _prompts((17, 14, 12), seed=3)
+    rids = _serve(served, prompts, 12)
+    assert served.engine.metrics.counters["preemptions"] >= 1
+    for rid, prompt in zip(rids, prompts):
+        assert _worst(served, rid, prompt) < TOL
+    assert served.engine.pool.num_free == served.engine.pool.num_blocks - 1
+
+
+def test_a_held_still_lane_keeps_its_state():
+    """`step(only=...)`, the supervisor's probe: a request that is not in the
+    probe keeps its lane and gets no row for a step, and goes on from the
+    state it had (an idle lane moves nothing)."""
+    served = Served(build(), **KW)
+    prompts = _prompts((9, 10), seed=5)
+    a, b = (served.engine.add_request(p, max_new_tokens=6) for p in prompts)
+    reqs = {r: served.engine.get_request(r) for r in (a, b)}
+    for _ in range(3):
+        served.step()
+    for _ in range(2):
+        served.step(only={a})              # b holds still
+        assert [rid for rid, *_ in served.plans[-1]] == [a]
+    served.run()
+    served.outputs = {r: list(q.output_ids) for r, q in reqs.items()}
+    ids = (prompts[1] + served.outputs[b])[:-1]
+    want = np.asarray(ref.sequence_logits(
+        params_of(served.model), ids, published_keys(served.model)))
+    got = served.logits[b]
+    assert max(np.abs(got[p] - want[p]).max() for p in got) < TOL
+
+
+@pytest.mark.parametrize("pattern,lane_state", [
+    ("MEM*E", True),
+    # a stack with expert layers and no state-space layer keeps no state a
+    # lane; its counts still ride the packed result to the spans
+    ("E*E", False)])
+def test_step_spans_carry_the_routing_and_state_counts(pattern, lane_state):
+    served = Served(build(pattern=pattern, experts_held=4), trace=True, **KW)
+    assert bool(served.engine.pool.lane_state) == lane_state
+    _serve(served, _prompts((12, 6)), 4)
+    spans = [ev["args"] for ev in
+             served.engine.tracer.chrome_trace()["traceEvents"]
+             if ev.get("name", "").startswith("step[")]
+    assert spans
+    for args in spans:
+        assert args["h2d_arrays"] == 1
+        assert args["moe_pairs"] == 2 * 4 * args["fed_tokens"]  # 2 E layers
+        assert 0 <= args["moe_pairs_local"] <= args["moe_pairs"]
+        assert args["moe_experts_hit"] <= 2 * 4
+        assert args["moe_max_load"] <= args["fed_tokens"]
+        assert args["state_rows"] == args["rows"]
+    assert sum(a["moe_pairs_local"] for a in spans) > 0
+
+
+def test_the_step_input_gains_one_section_and_only_for_lane_state():
+    plain, n = step_input_layout(3, 8, 24)
+    lane, m = step_input_layout(3, 8, 24, None, True)
+    assert set(lane) - set(plain) == {"fresh"} and m == n + 3
+    engine = LLMEngine(build(), **KW)
+    assert "fresh" in engine._step_layout(1)[0]
+    assert [a.shape for a in engine.pool.lane_state.values()] == [
+        (2, 3, 8, 16, 16), (2, 3, 3, 192)]
+    assert len(engine.step_program_spec()["arena_param_indices"]) == 4
+
+
+@pytest.mark.parametrize("option,kw", [
+    ("prefix_cache=True", dict(prefix_cache=True)),
+    ("spec_decoding=True", dict(spec_decoding=True)),
+    ("host_kv_blocks", dict(host_kv_blocks=8)),
+])
+def test_options_that_carry_no_state_are_refused_with_a_sentence(option, kw):
+    with pytest.raises(ValueError, match="keeps state a lane"):
+        LLMEngine(build(), **KW, **kw)
+
+
+@pytest.mark.parametrize("option,kw", [
+    ("mesh=", dict(mesh=2)),
+    ("quantize=", dict(quantize="int8")),
+    ("lora_slots", dict(lora_slots=2)),
+])
+def test_options_written_for_gpt_are_refused_with_a_sentence(option, kw):
+    with pytest.raises(ValueError, match="not available for NemotronH"):
+        LLMEngine(build(), **KW, **kw)
+
+
+def test_prefix_cache_is_off_by_default_for_this_model():
+    engine = LLMEngine(build(), **KW)
+    assert engine.prefix_cache is False
+    assert engine.scheduler.prefix_cache is False
+
+
+def test_tp_above_the_kv_heads_is_refused_with_a_sentence():
+    from paddle_tpu.models.gpt import GPTConfig
+    from paddle_tpu.serving.sharded import build_serving_mesh
+
+    cfg = GPTConfig(vocab_size=256, hidden_size=64, num_layers=1,
+                    num_heads=8, max_seq_len=32)
+    smesh = build_serving_mesh(4)
+    smesh.validate_model(cfg)                 # 8 heads of cache: fine
+    with pytest.raises(ValueError, match="2 key/value heads"):
+        smesh.validate_model(cfg, kv_heads=2)
+
+
+def test_the_benchmark_has_the_configuration_and_its_cell():
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    entry, = [c for c in bench["configs"]
+              if c["name"] == "nemotron3s-ep4-serve"]
+    assert entry["reduced"] == ["num_hidden_layers",
+                                "hybrid_override_pattern",
+                                "n_routed_experts", "vocab_size"]
+    cell, = [w for w in bench["workloads"]
+             if w["name"] == "nemotron3s-ep4-serve.turns"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("nemotron3s-ep4-serve", "turns", 1)
+    config = json.loads((REPO / entry["file"]).read_text())
+    mine = [m["name"] for m in bench["per_layer"]
+            if m.get("workloads") == [cell["name"]]]
+    assert sorted(mine) == sorted([
+        "step.hybrid_mfu", "hybrid.decode_stream_share",
+        "moe.local_pair_share", "moe.experts_hit_share",
+        "moe.load_max_over_mean", "pool.state_hbm_share"])
+    for named in (entry["file"], config["reference"],
+                  "benchmark/traffic/turns.json",
+                  "benchmark/reduce/hybrid.py",
+                  "benchmark/init_rules_hybrid.py",
+                  *(f"benchmark/layer_metrics/{m}.py" for m in mine)):
+        assert (REPO / named).is_file(), named
+    # every width as published: the catalog row's numbers stand in the file
+    # under their keys, but the four that `reduced` lists
+    published = {"hidden_size": 4096, "mamba_num_heads": 128,
+                 "mamba_head_dim": 64, "ssm_state_size": 128, "n_groups": 8,
+                 "conv_kernel": 4, "chunk_size": 128,
+                 "num_attention_heads": 32, "num_key_value_heads": 2,
+                 "head_dim": 128, "moe_latent_size": 1024,
+                 "moe_intermediate_size": 2688,
+                 "moe_shared_expert_intermediate_size": 5376,
+                 "num_experts_per_tok": 22, "routed_scaling_factor": 5}
+    assert {k: config[k] for k in published} == published
+    assert config["published"]["n_routed_experts"] == 512 \
+        and config["router_experts"] == 512 \
+        and config["n_routed_experts"] == 128
+    assert len(config["hybrid_override_pattern"]) == \
+        config["num_hidden_layers"] == 11
+    assert config["hybrid_override_pattern"] == \
+        config["published"]["hybrid_override_pattern"][27:38]
