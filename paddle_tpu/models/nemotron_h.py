@@ -77,6 +77,8 @@ block is not wired (no tape is recorded).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -461,6 +463,31 @@ class NemotronH(nn.Layer):
             outs.append(out)
         return jnp.concatenate(outs, axis=1)
 
+    @staticmethod
+    def _mamba_paged(mixer, i, st, g, a):
+        """Mamba layer `i` over one group `g` of a step's rows, `a` `[rows,
+        width, hidden]`: the rows' state out of the pool's `st` and back.
+        A group whose row i is lane i (`g.lanes` None) uses the state in
+        place; another gathers its rows' lanes and scatters them back (an
+        idle row names no lane: its update is dropped)."""
+        live = g.live_slots()
+        n_live = jnp.sum(live, axis=1).astype(jnp.int32)
+        S_in, window = st["ssm"][i], st["conv"][i]
+        if g.lanes is not None:
+            S_in = jnp.take(S_in, g.lanes, axis=0, mode="clip")
+            window = jnp.take(window, g.lanes, axis=0, mode="clip")
+        fresh = g.fresh != 0
+        part, S_out, window = mixer(
+            a, jnp.where(fresh[:, None, None, None], 0, S_in),
+            jnp.where(fresh[:, None, None], 0, window), live, n_live)
+        if g.lanes is None:
+            st["ssm"] = st["ssm"].at[i].set(S_out)
+            st["conv"] = st["conv"].at[i].set(window)
+        else:
+            st["ssm"] = st["ssm"].at[i, g.lanes].set(S_out, mode="drop")
+            st["conv"] = st["conv"].at[i, g.lanes].set(window, mode="drop")
+        return part
+
     def forward(self, input_ids, caches=None):
         """Logits `[b, s, vocab]` float32; with a paged `caches`,
         `(logits, caches)` as GPT's paged path returns them, and the step's
@@ -472,13 +499,8 @@ class NemotronH(nn.Layer):
         if caches is not None and not getattr(caches, "is_paged", False):
             raise ValueError(
                 "NemotronH takes a paged cache (serving) or none")
-        if caches is None:
-            live = jnp.ones((b, s), bool)
-        else:
-            live = caches.live_slots(s)
-            n_live = jnp.sum(live, axis=1).astype(jnp.int32)
-            st = caches.lane_state
-            fresh = None if st is None else caches.fresh != 0
+        live = jnp.ones((b, s), bool) if caches is None \
+            else caches.live_slots()
         h = jnp.take(self.embed.weight._array, ids.astype(jnp.int32),
                      axis=0)
         h = h.astype(RESIDUAL_DTYPE)
@@ -490,14 +512,9 @@ class NemotronH(nn.Layer):
                     if caches is None:
                         part = self._mamba_plain(layer.mixer, a)
                     else:
-                        S_in = jnp.where(fresh[:, None, None, None], 0,
-                                         st["ssm"][ssm_layer])
-                        window = jnp.where(fresh[:, None, None], 0,
-                                           st["conv"][ssm_layer])
-                        part, S_out, window = layer.mixer(
-                            a, S_in, window, live, n_live)
-                        st["ssm"] = st["ssm"].at[ssm_layer].set(S_out)
-                        st["conv"] = st["conv"].at[ssm_layer].set(window)
+                        part = caches.per_group(functools.partial(
+                            self._mamba_paged, layer.mixer, ssm_layer,
+                            caches.lane_state), a)
                         ssm_layer += 1
             elif layer.kind == "*":
                 with jax.named_scope("gqa_attention"):
@@ -521,5 +538,6 @@ class NemotronH(nn.Layer):
         caches.step_stats = jnp.concatenate([
             jnp.sum(per_layer[:, :3], axis=0),
             jnp.max(per_layer[:, 3:], axis=0, initial=0),
-            jnp.sum(n_live > 0, keepdims=True).astype(jnp.int32)])
+            sum(jnp.sum(jnp.any(g.live_slots(), axis=1), keepdims=True)
+                for g in caches.groups).astype(jnp.int32)])
         return Tensor._from_op(logits), caches

@@ -128,13 +128,12 @@ class PagedLayerView:
 
     def attend(self, q, k_new, v_new):
         """Append `k_new`/`v_new` into the arena and attend `q` through
-        the block table. Tensors [B, S, heads, head_dim] in and out, all
-        shapes static. The attention itself is
-        ops/pallas/paged_attention.py's dispatch: ragged Pallas kernel
+        the block table, a group of the step's rows at a time (one group:
+        the tensors as they are). Tensors [b, s, heads, head_dim] in the
+        step's layout in and out, all shapes static. The attention itself
+        is ops/pallas/paged_attention.py's dispatch: ragged Pallas kernel
         over live blocks on TPU, padded XLA gather elsewhere."""
-        from ..ops.pallas.paged_attention import paged_attention_arrays
-
-        st, layer = self.state, self.layer
+        st = self.state
         q, k_new, v_new = q._array, k_new._array, v_new._array
         if st.mesh is not None:
             # tensor-parallel serving: pin the step's new K/V (and q) to
@@ -144,24 +143,35 @@ class PagedLayerView:
             q = st.constrain(q, None, None, "tp", None)
             k_new = st.constrain(k_new, None, None, "tp", None)
             v_new = st.constrain(v_new, None, None, "tp", None)
+        # a later group reads an arena an earlier one has written, which
+        # is harmless: a request is in one group a step
+        return Tensor._from_op(st.per_group(self._attend_group,
+                                            q, k_new, v_new))
+
+    def _attend_group(self, g, q, k_new, v_new):
+        """One group's append and attention: arrays `[rows, width, heads,
+        head_dim]` beside the group's own row metadata."""
+        from ..ops.pallas.paged_attention import paged_attention_arrays
+
+        st, layer = self.state, self.layer
         if st.k_scale is not None:
             # int8 arena: quantize at the append, scales growing per
             # touched block (dequant happens inside the Pallas kernel /
             # before the XLA fallback's einsum)
             st.k, st.k_scale = _quantize_scatter(
-                st.k, st.k_scale, layer, k_new, st.slots, st.offs,
-                st.touched, st.touch_idx, st.mesh)
+                st.k, st.k_scale, layer, k_new, g.slots, g.offs,
+                g.touched, g.touch_idx, st.mesh)
             st.v, st.v_scale = _quantize_scatter(
-                st.v, st.v_scale, layer, v_new, st.slots, st.offs,
-                st.touched, st.touch_idx, st.mesh)
+                st.v, st.v_scale, layer, v_new, g.slots, g.offs,
+                g.touched, g.touch_idx, st.mesh)
         else:
             st.k, st.v = _append((st.k, st.v), layer, (k_new, v_new),
-                                 st.slots, st.offs, st.mesh)
-        return Tensor._from_op(paged_attention_arrays(
-            q, st.k, st.v, layer, st.block_tables, st.qpos,
-            q_start=st.q_start, kv_live=st.kv_live, q_lens=st.q_lens,
+                                 g.slots, g.offs, st.mesh)
+        return paged_attention_arrays(
+            q, st.k, st.v, layer, g.block_tables, g.qpos,
+            q_start=g.q_start, kv_live=g.kv_live, q_lens=g.q_lens,
             mesh=st.mesh, k_scale=st.k_scale, v_scale=st.v_scale,
-        ))
+        )
 
     def column_parallel(self, layer, x, op_name):
         """ColumnParallel projection `layer(x)`, with each lane's LoRA
@@ -170,13 +180,14 @@ class PagedLayerView:
         all-zeros = base). The delta inherits the base output's tp layout
         from B's sharded out axis (no new collectives)."""
         y = layer(x)
-        lora = self.state.lora
-        if lora is None or op_name not in lora:
+        st = self.state
+        if st.groups[0].lora is None or op_name not in st.groups[0].lora:
             return y
         from ..models.lora import apply_adapter_rows
 
-        a_rows, b_rows = lora[op_name]
-        delta = apply_adapter_rows(x._array, a_rows, b_rows, self.layer)
+        delta = st.per_group(
+            lambda g, xg: apply_adapter_rows(xg, *g.lora[op_name],
+                                             self.layer), x._array)
         return Tensor._from_op(y._array + delta)
 
     def row_parallel(self, layer, x, op_name):
@@ -197,44 +208,109 @@ class PagedLayerView:
         return layer(x)
 
 
+class PagedGroup:
+    """One group of a step's rows: a rectangle `[rows, width]` of token
+    slots at `offset` on the step's token axis, with the row metadata the
+    parts that mix along a sequence need (all fixed-shape, jnp):
+
+      block_tables  [rows, max_blocks] int32 (padded with 0 = null block)
+      slots         [rows, width] int32 — destination block id of each
+                    new token
+      offs          [rows, width] int32 — destination offset inside it
+      qpos          [rows, width] int32 — absolute position of each query
+                    token (also the model's position-embedding indices)
+      q_start       [rows] int32 — first live query position per row
+                    (ragged kernel metadata; chunk tokens are consecutive)
+      kv_live       [rows] int32 — live KV blocks per row (0: an idle
+                    row); the ragged kernel walks exactly this many
+      q_lens        [rows] int32 — live query tokens per row (ragged
+                    widths: a decode row riding a wide launch declares 1
+                    and the kernel computes one query tile)
+
+    An int8 arena (``kv_dtype="int8"`` on the pool) adds:
+      touched       [rows, T] int32 — the block ids this step's scatter
+                    can write per row, slot 0 reserved for the null block
+                    (padded tokens route their scale updates there)
+      touch_idx     [rows, width] int32 — each fed token's index into its
+                    row's `touched` list (0 = the null slot)
+    LoRA (models/lora.py) adds `lora`, the adapter rows already gathered
+    for THIS group's rows: {target op -> (a_rows [rows, L, in, r], b_rows
+    [rows, L, r, out])}, or None (no adapters in the program).
+    State a lane holds (`PagedState.lane_state`) adds:
+      fresh         [rows] int32 — 1 where the row starts a sequence (its
+                    first chunk, also after preemption): it starts from
+                    zeros
+    and a group behind the decode group has:
+      lanes         [rows] int32 — the decode group's row each row stands
+                    in for: where its request is decided (the engine's
+                    sampler) and, where lanes hold state, the lane whose
+                    state it carries (an idle row: one past the last lane,
+                    which a scatter drops). None in the decode group: row
+                    i IS lane i, and the state is used in place
+    """
+
+    def __init__(self, offset, rows, width, block_tables, slots, offs, qpos,
+                 q_start, kv_live, q_lens, touched=None, touch_idx=None,
+                 lora=None, fresh=None, lanes=None):
+        self.offset, self.rows, self.width = offset, rows, width
+        self.block_tables = block_tables
+        self.slots = slots
+        self.offs = offs
+        self.qpos = qpos
+        self.q_start = q_start
+        self.kv_live = kv_live
+        self.q_lens = q_lens
+        self.touched = touched
+        self.touch_idx = touch_idx
+        self.lora = lora
+        self.fresh = fresh
+        self.lanes = lanes
+
+    def live_slots(self):
+        """`[rows, width]` bool: which slots carry a token. A row's live
+        tokens are a prefix (`q_lens`); a row without a request has no live
+        block (`kv_live` 0) and carries none."""
+        import jax.numpy as jnp
+
+        return ((jnp.arange(self.width)[None, :] < self.q_lens[:, None])
+                & (self.kv_live[:, None] > 0))
+
+
 class PagedState:
     """Traced arena + step metadata threaded through GPT.forward.
+
+    A step's tokens lie on ONE token axis made of `groups` (`PagedGroup`),
+    each a small rectangle of rows with metadata of its own: the decode
+    group (the rows that feed one token, or one and their drafts) and,
+    in a step that carries a prompt chunk, the chunk group behind it.
+    What is a function of one token (embedding, norms, projections,
+    feed-forward, experts, the head) the model computes once over the
+    whole axis, in the step's LAYOUT: `[rows, width, ...]` where there
+    is one group (the rectangle itself, no operation added), `[1, T,
+    ...]` with the groups' slots side by side where there are more. What
+    mixes along a sequence runs a group at a time (`per_group`) on that
+    group's static slice reshaped to `[rows, width, ...]`: attention
+    (`PagedLayerView.attend`), LoRA's per-row adapters, a state-space
+    layer's scan.
 
     Arrays (all fixed-shape, jnp):
       k, v          [slabs, heads, num_blocks, block_size, head_dim]; a slab
                     is one layer's cache (a looped model's: one (pass,
                     layer)'s, the model's `kv_slabs`)
-      block_tables  [B, max_blocks] int32 (padded with 0 = null block)
-      slots         [B, S] int32 — destination block id of each new token
-      offs          [B, S] int32 — destination offset inside that block
-      qpos          [B, S] int32 — absolute position of each query token
-                    (also the model's position-embedding indices)
-      q_start       [B] int32 — first live query position per row (ragged
-                    kernel metadata; chunk tokens are consecutive)
-      kv_live       [B] int32 — live KV blocks per row (0: an idle
-                    lane); the ragged kernel walks exactly this many
-      q_lens        [B] int32 — live query tokens per row (ragged widths:
-                    a decode row riding a wide unified-step launch
-                    declares 1 and the kernel computes one query tile;
-                    None = every row full-width)
-
-    Int8 KV (``kv_dtype="int8"`` on the pool) adds four more:
-      k_scale, v_scale  [layers, heads, num_blocks] float32 — per-block
-                    per-head dequant scales (the head-major arena's
-                    natural sidecar). None on f32 engines.
-      touched       [B, T] int32 — the block ids this step's scatter can
-                    write per row, slot 0 reserved for the null block
-                    (padded tokens route their scale updates there)
-      touch_idx     [B, S] int32 — each fed token's index into its row's
-                    `touched` list (0 = the null slot)
+      qpos          int32, in the step's layout — absolute position of each
+                    token (the model's position-embedding indices)
+      k_scale, v_scale  [layers, heads, num_blocks] float32 — an int8
+                    arena's per-block per-head dequant scales (the
+                    head-major arena's natural sidecar). None on float
+                    engines.
 
     Lane state (a model that keeps state a lane which no position
     addresses, e.g. a state-space layer's; models/nemotron_h.py) adds:
-      lane_state    {name: [layers, B, ...]} — the pool's arrays of that
-                    state, one row a lane; the model reads and replaces
-                    them, and they travel with the arenas (`arenas()`)
-      fresh         [B] int32 — 1 where the row starts a sequence (its first
-                    chunk, also after preemption): it starts from zeros
+      lane_state    {name: [layers, lanes, ...]} — the pool's arrays of
+                    that state, one row a lane; the model reads and replaces
+                    them (a group says whose rows it carries,
+                    `PagedGroup.lanes`), and they travel with the arenas
+                    (`arenas()`)
     and the model leaves on `step_stats` the int32 vector of counts its
     `step_stat_names` names, which the engine hands back to the host.
 
@@ -249,43 +325,46 @@ class PagedState:
 
     is_paged = True
 
-    def __init__(self, k, v, block_tables, slots, offs, qpos,
-                 q_start=None, kv_live=None, q_lens=None, mesh=None,
-                 k_scale=None, v_scale=None, touched=None, touch_idx=None,
-                 quant_collectives=frozenset(), lora=None, lane_state=None,
-                 fresh=None):
+    def __init__(self, k, v, groups, mesh=None, k_scale=None, v_scale=None,
+                 quant_collectives=frozenset(), lane_state=None):
         self.k = k
         self.v = v
-        self.block_tables = block_tables
-        self.slots = slots
-        self.offs = offs
-        self.qpos = qpos
-        self.q_start = q_start
-        self.kv_live = kv_live
-        self.q_lens = q_lens
+        self.groups = tuple(groups)
         self.mesh = mesh
         self.k_scale = k_scale
         self.v_scale = v_scale
-        self.touched = touched
-        self.touch_idx = touch_idx
         self.quant_collectives = quant_collectives
-        # per-row LoRA adapters (models/lora.py), already gathered for
-        # THIS step's lanes: {target op -> (a_rows [B,L,in,r],
-        # b_rows [B,L,r,out])} or None (no adapters in the program).
-        # `PagedLayerView.column_parallel` consults it per op.
-        self.lora = lora
         self.lane_state = lane_state
-        self.fresh = fresh
         self.step_stats = None
+        self.qpos = self.join([g.qpos for g in self.groups])
 
-    def live_slots(self, width):
-        """`[B, width]` bool: which slots of the step carry a token. A row's
-        live tokens are a prefix (`q_lens`); a lane without a request has no
-        live block (`kv_live` 0) and carries none."""
+    def cut(self, g, x):
+        """Group `g`'s slots of `x` (the step's layout) as `[rows, width,
+        ...]`."""
+        if len(self.groups) == 1:
+            return x
+        n = g.rows * g.width
+        return x[0, g.offset:g.offset + n].reshape(
+            (g.rows, g.width) + x.shape[2:])
+
+    def join(self, parts):
+        """The step's layout out of one `[rows, width, ...]` a group."""
+        if len(self.groups) == 1:
+            return parts[0]
         import jax.numpy as jnp
 
-        return ((jnp.arange(width)[None, :] < self.q_lens[:, None])
-                & (self.kv_live[:, None] > 0))
+        return jnp.concatenate(
+            [x.reshape((1, -1) + x.shape[2:]) for x in parts], axis=1)
+
+    def per_group(self, fn, *xs):
+        """`fn(group, *xs cut to the group)` for every group in turn, the
+        results joined: how a part that mixes along a sequence runs."""
+        return self.join([fn(g, *(self.cut(g, x) for x in xs))
+                          for g in self.groups])
+
+    def live_slots(self):
+        """Bool, in the step's layout: which slots carry a token."""
+        return self.join([g.live_slots() for g in self.groups])
 
     def layer(self, i):
         """The view of cache slab `i` (a layer's; a looped model's (pass,

@@ -14,8 +14,17 @@ program — the scheduler's mixed plan is the only program shape — keyed by
   compiled width is the smallest bucket covering its widest row — by
   default ``{1, 1 + num_spec_tokens (spec engines), prefill_chunk}``, so
   the dominant all-decode steps run at width 1 and never pay chunk-width
-  compute, while the Pallas kernel's per-row ragged query lengths keep a
-  narrow row cheap inside a wide launch;
+  compute;
+- **a step's tokens lie on one flat axis made of groups** (`step_groups`):
+  the decode group ``[max_batch, 1 + K]`` (K the draft capacity) holds the
+  rows that feed one token and their drafts, and a bucket wider than that
+  is the width of the chunk group ``[C, W]`` behind it, ``C = token_budget
+  // prefill_chunk`` rows. What is a function of one token runs once over
+  the whole axis; attention, the append and whatever else mixes along a
+  sequence run a group at a time through the same kernels
+  (block_pool.py `PagedState`). So a step that carries a prompt chunk
+  computes the chunk's slots and ONE slot a decoding lane, not ``max_batch``
+  chunks;
 - **sampling runs inside the program**: temperature / top-k / top-p via
   the one-descending-sort formulation in serving/spec.py (greedy argmax
   and the per-row isfinite containment check included), on logit rows
@@ -137,19 +146,24 @@ StepOutput = namedtuple("StepOutput", ["request_id", "token", "finished"])
 
 @functools.lru_cache(maxsize=None)
 def step_input_layout(B, W, max_blocks, touched_width=None,
-                      lane_state=False):
-    """THE layout of a step's host inputs: ``({name: (offset, shape,
-    dtype)}, words)``. What the host hands the device for a step is ONE
-    int32 array of ``words`` elements, and every named input is a section
-    of it, in the order below — a pure function of what the (B, W)
+                      lane_state=False, lanes=False):
+    """THE layout of a step's host inputs, a group of rows at a time:
+    ``({name: (offset, shape, dtype)}, words)`` for one group of ``B`` rows
+    of ``W`` slots (`LLMEngine.step_groups`). What the host hands the
+    device for a step is ONE int32 array, the groups' ``words`` one after
+    the other (`LLMEngine._step_layout`), and every named input is a
+    section of it, in the order below — a pure function of what the
     program's shape already depends on (``touched_width`` is the int8
     arena's `_touched_width`, None for a float arena; ``lane_state`` says
     that the pool holds state a lane, and adds the section ``fresh``: 1
-    where a row starts its sequence from zeros). `_row_arrays` makes
-    its views from this table and the jitted step (`unpack_step_input`)
-    its slices, so the two cannot drift apart. The float32 sections are
-    views of the same words (``ndarray.view`` on the host,
-    ``lax.bitcast_convert_type`` in the program): bit for bit."""
+    where a row starts its sequence from zeros; ``lanes`` adds the section
+    of that name for a group behind the decode group: the decode group's
+    row each of its rows stands in for, where the request is decided and
+    where state a lane holds lives). `_row_arrays` makes its views from
+    this table and the jitted step (`unpack_step_input`) its slices, so the
+    two cannot drift apart. The float32 sections are views of the same
+    words (``ndarray.view`` on the host, ``lax.bitcast_convert_type`` in
+    the program): bit for bit."""
     i32, f32 = np.dtype(np.int32), np.dtype(np.float32)
     sections = [
         ("ids", (B, W), i32), ("tables", (B, max_blocks), i32),
@@ -158,6 +172,7 @@ def step_input_layout(B, W, max_blocks, touched_width=None,
         *([("touched", (B, touched_width), i32), ("touch_idx", (B, W), i32)]
           if touched_width else []),
         *([("fresh", (B,), i32)] if lane_state else []),
+        *([("lanes", (B,), i32)] if lanes else []),
         ("adapter_slots", (B,), i32), ("last_idx", (B,), i32),
         ("spec_lens", (B,), i32), ("temps", (B,), f32), ("top_ks", (B,), i32),
         ("top_ps", (B,), f32),
@@ -438,10 +453,24 @@ class LLMEngine:
             prefill_chunk = min(128, self.max_seq_len)
         self.prefill_chunk = max(1, min(int(prefill_chunk), self.max_seq_len))
         if token_budget is None:
-            # default: every lane may carry a full chunk, so the mixed
-            # step's fixed (max_batch, chunk) width is fully usable; set a
-            # smaller budget to bound per-step prefill work instead
-            token_budget = self.max_batch * self.prefill_chunk
+            # default: ONE chunk of prefill a step beside the decode rows
+            # (the chunk group is `token_budget // prefill_chunk` rows,
+            # `step_groups`). A chunk row that no request fills is computed
+            # all the same: its slots ride the step's weight stream (a v5e
+            # reads a bf16 weight in the time of about 240 tokens' products
+            # on it, 197 TFLOP/s over 819 GB/s), but what mixes along a
+            # sequence, and a model's passes and experts, cost their own
+            # time: measured on the chip, a second row that stays empty
+            # costs a chunk-carrying step 1-2 ms in a 1.3B GPT, 4 ms in the
+            # hybrid and 17 ms in the looped decoder (PERF.md section 6,
+            # PR 37), and in a closed loop it nearly always stays empty:
+            # requests end one at a time. The price: where MANY rows
+            # prefill at once (a burst, long documents behind one
+            # another) every chunk takes a step of its own and pays the
+            # step's fixed cost; such a deployment raises the budget, a
+            # row a chunk, up to `max_batch * prefill_chunk` (every lane
+            # may carry a full chunk a step)
+            token_budget = self.prefill_chunk
         self.prefill_chunk = min(self.prefill_chunk, int(token_budget))
         # prefix caching: constructor arg wins, then the env kill switch
         self.prefix_cache = (
@@ -735,6 +764,7 @@ class LLMEngine:
                     self.lora_targets, self._smesh)
         self._requests = {}
         self._step_fns = {}
+        self._layouts = {}  # width bucket -> `_step_layout`
         self._phases = {}   # current step's {phase: (t0, t1)} when tracing
         self._span_args = {}  # and what its span says of grid and hand-over
         self._retrace_warned = False
@@ -1259,20 +1289,24 @@ class LLMEngine:
     def _get_step_fn(self, B, W):
         """The unified ragged step program at width bucket ``W`` — one
         jitted executable per (batch, width); kinds no longer key
-        programs. Every row feeds ``count`` chunk tokens plus ``k``
-        drafted candidates (``count + k <= W``); the program runs the
-        forward, gathers the ``K + 1`` scored positions starting at each
-        row's ``last_idx`` (K = the width's draft capacity), and finishes
-        the WHOLE per-token decision on device — sampling, speculative
-        accept/rollback, non-finite containment — returning one packed
-        int32 array ``[B, K + 3]``: emitted-run tokens ``[:, :K + 1]``,
-        accept length ``[:, K + 1]``, row-finite flag ``[:, K + 2]``.
-        The host reads it with a single device→host transfer."""
+        programs. Its tokens lie on one token axis made of the groups of
+        `step_groups`; every row feeds ``count`` chunk tokens plus ``k``
+        drafted candidates (``count + k <=`` its group's width). The
+        program runs the forward over the whole axis, gathers the
+        ``K + 1`` scored positions starting at each row's ``last_idx``
+        (K = the width's draft capacity), and finishes the WHOLE per-token
+        decision on device — sampling, speculative accept/rollback,
+        non-finite containment — returning one packed int32 array
+        ``[B, K + 3]``, a request's row its row of the decode group:
+        emitted-run tokens ``[:, :K + 1]``, accept length ``[:, K + 1]``,
+        row-finite flag ``[:, K + 2]``. The host reads it with a single
+        device→host transfer."""
         if (B, W) in self._step_fns:
             return self._step_fns[(B, W)]
         import jax
         import jax.numpy as jnp
 
+        from .block_pool import PagedGroup
         from .spec import spec_emit_arrays
 
         model = self.model
@@ -1282,60 +1316,52 @@ class LLMEngine:
         K = self._draft_capacity(W)
         quantized = self.pool.quantized
         quant_ops = self.quant_collectives
+        shapes = self.step_groups(W)
 
         from ..models.lora import gather_adapter_rows
 
-        def forward(params, buffers, k_arena, v_arena, lora_tables,
-                    adapter_slots, ids, block_tables, slots, offs, qpos,
-                    q_start, kv_live, q_lens, k_scale=None, v_scale=None,
-                    touched=None, touch_idx=None, lane_state=None,
-                    fresh=None):
-            # runs at TRACE time only — the test's recompile alarm
-            metrics.inc("jit_traces")
-            state = PagedState(k_arena, v_arena, block_tables, slots, offs,
-                               qpos, q_start=q_start, kv_live=kv_live,
-                               q_lens=q_lens,
-                               mesh=None if smesh is None else smesh.mesh,
-                               k_scale=k_scale, v_scale=v_scale,
-                               touched=touched, touch_idx=touch_idx,
-                               quant_collectives=quant_ops,
-                               # per-lane adapter rows gathered INSIDE the
-                               # program (models/lora.py) — None when the
-                               # engine has no adapter slots, keeping the
-                               # trace byte-identical to the pre-LoRA one
-                               lora=gather_adapter_rows(lora_tables,
-                                                        adapter_slots),
-                               lane_state=lane_state, fresh=fresh)
-            # mask the process-global TRAINING mesh for the trace (thread-
-            # local — a concurrent training trace on another thread keeps
-            # its mesh): the serving step's sharding is fully explicit
-            # (in_shardings + PagedState.constrain), but the TP layers'
-            # dp/mp sharding constraints consult
-            # distributed.mesh.get_mesh() — a mesh left installed by
-            # fleet.init/init_mesh would stamp its (differently-deviced)
-            # NamedShardings into this program and the call would reject
-            # the engine's own placement
-            from ..distributed.mesh import suppress_mesh
-
-            with suppress_mesh():
-                (logits, _), _ = functional_call(
-                    model, params, buffers, args=(ids,),
-                    kwargs={"caches": state}, training=False,
-                )
-            return logits, state
-
-        def _decide(logits, state, ids, last_idx, spec_lens, temps, top_ks,
-                    top_ps, key):
+        def _decide(logits, ids, groups, parts, key):
             # the scored window: K + 1 consecutive positions starting at
             # each row's last chunk token — position last_idx + j scores
             # the distribution following fed token last_idx + j, which is
             # exactly what sampling (j = 0) and draft verification
             # (j >= 1) need. Rows without drafts just use slot 0.
-            win = last_idx[:, None] + jnp.arange(K + 1)[None, :]
-            win = jnp.clip(win, 0, W - 1)
-            lg = jnp.take_along_axis(
-                logits, win[..., None], axis=1).astype(jnp.float32)
-            win_ids = jnp.take_along_axis(ids, win, axis=1)
+            if len(groups) == 1:
+                rows = parts[0].__getitem__
+                last_idx, spec_lens = rows("last_idx"), rows("spec_lens")
+                win = last_idx[:, None] + jnp.arange(K + 1)[None, :]
+                win = jnp.clip(win, 0, W - 1)
+                lg = jnp.take_along_axis(
+                    logits, win[..., None], axis=1).astype(jnp.float32)
+                win_ids = jnp.take_along_axis(ids, win, axis=1)
+            else:
+                # the same window on the flat axis: a row's slots begin at
+                # its group's offset + row x the group's width. A request
+                # is decided in ITS row of the decode group whichever group
+                # fed it (`src`: that row, or the row of a later group that
+                # names it in `lanes`), so the sampler draws for
+                # `max_batch` rows, each request's the draw it would have
+                # had in a step of one rectangle, and the result keeps its
+                # shape
+                win, src, n_rows = [], jnp.arange(B), 0
+                for g, a in zip(groups, parts):
+                    w = a["last_idx"][:, None] + jnp.arange(K + 1)[None, :]
+                    win.append(jnp.clip(w, 0, g.width - 1) + g.offset
+                               + g.width * jnp.arange(g.rows)[:, None])
+                    if n_rows:
+                        named = g.lanes[None, :] == jnp.arange(B)[:, None]
+                        src = jnp.where(named.any(axis=1),
+                                        n_rows + jnp.argmax(named, axis=1),
+                                        src)
+                    n_rows += g.rows
+
+                def rows(name):
+                    return jnp.concatenate([a[name] for a in parts])[src]
+
+                last_idx, spec_lens = rows("last_idx"), rows("spec_lens")
+                win = jnp.concatenate(win)[src]
+                lg = jnp.take(logits[0], win, axis=0).astype(jnp.float32)
+                win_ids = jnp.take(ids[0], win, axis=0)
             if smesh is not None:
                 # THE one sanctioned boundary all-gather (analysis
                 # contract IR001): materialize the scored positions'
@@ -1358,7 +1384,8 @@ class LLMEngine:
             # compiled (serving/spec.py is the spec): the emitted run and
             # its length come back ready to publish
             run, n_acc = spec_emit_arrays(
-                lg, win_ids, spec_lens, temps, top_ks, top_ps, key
+                lg, win_ids, spec_lens, rows("temps"), rows("top_ks"),
+                rows("top_ps"), key
             )
             packed = jnp.concatenate(
                 [run, n_acc[:, None], row_ok.astype(jnp.int32)[:, None]],
@@ -1366,7 +1393,7 @@ class LLMEngine:
             )
             return packed
 
-        fields, _ = self._step_layout(W)
+        layouts, _ = self._step_layout(W)
         n_kv = 4 if quantized else 2
         lane_names = tuple(self.pool.lane_state)
         n_arena = n_kv + len(lane_names)
@@ -1376,33 +1403,62 @@ class LLMEngine:
         # lora_tables, packed, key). The arenas are the payload pair, plus
         # the f32 scale sidecars right after it when the arena is int8,
         # plus the pool's lane state after those where a model keeps any;
-        # `packed` is the step's whole host input (`step_input_layout`),
-        # `key` the engine's sampling key, which lives on the device
+        # `packed` is the step's whole host input (`step_input_layout`, a
+        # group after the other), `key` the engine's sampling key, which
+        # lives on the device
         def step(params, buffers, *rest):
             arenas, (lora_tables, packed, key) = rest[:n_arena], rest[n_arena:]
-            a = unpack_step_input(packed, fields)
+            parts = [unpack_step_input(packed, fields) for fields in layouts]
             # the engine's stream: one split a step, the new key handed
             # back as the program's LAST output
             key, sub = jax.random.split(key)
-            last_idx, spec_lens = a["last_idx"], a["spec_lens"]
-            # per-row live width for the ragged kernel: chunk tokens
-            # through last_idx plus the drafted candidates
-            q_lens = last_idx + 1 + spec_lens
-            more = {}
-            if quantized:
-                # the scatter's touched-block lists ride the host input
-                more = dict(k_scale=arenas[2], v_scale=arenas[3],
-                            touched=a["touched"], touch_idx=a["touch_idx"])
-            if lane_names:
-                more.update(lane_state=dict(zip(lane_names, arenas[n_kv:])),
-                            fresh=a["fresh"])
-            logits, state = forward(
-                params, buffers, arenas[0], arenas[1], lora_tables,
-                a["adapter_slots"], a["ids"], a["tables"], a["slots"],
-                a["offs"], a["qpos"], a["q_start"], a["kv_live"], q_lens,
-                **more)
-            out = _decide(logits, state, a["ids"], last_idx, spec_lens,
-                          a["temps"], a["top_ks"], a["top_ps"], sub)
+            # runs at TRACE time only — the test's recompile alarm
+            metrics.inc("jit_traces")
+            groups, offset = [], 0
+            for (n, width), a in zip(shapes, parts):
+                groups.append(PagedGroup(
+                    offset, n, width, a["tables"], a["slots"], a["offs"],
+                    a["qpos"], a["q_start"], a["kv_live"],
+                    # per-row live width for the ragged kernel: chunk
+                    # tokens through last_idx plus the drafted candidates
+                    q_lens=a["last_idx"] + 1 + a["spec_lens"],
+                    # an int8 arena: the scatter's touched-block lists
+                    # ride the host input
+                    touched=a.get("touched"), touch_idx=a.get("touch_idx"),
+                    # per-row adapter rows gathered INSIDE the program
+                    # (models/lora.py) — None when the engine has no
+                    # adapter slots, keeping the trace byte-identical to
+                    # the pre-LoRA one
+                    lora=gather_adapter_rows(lora_tables,
+                                             a["adapter_slots"]),
+                    fresh=a.get("fresh"), lanes=a.get("lanes")))
+                offset += n * width
+            state = PagedState(
+                arenas[0], arenas[1], groups,
+                mesh=None if smesh is None else smesh.mesh,
+                k_scale=arenas[2] if quantized else None,
+                v_scale=arenas[3] if quantized else None,
+                quant_collectives=quant_ops,
+                lane_state=(dict(zip(lane_names, arenas[n_kv:]))
+                            if lane_names else None))
+            ids = state.join([a["ids"] for a in parts])
+            # mask the process-global TRAINING mesh for the trace (thread-
+            # local — a concurrent training trace on another thread keeps
+            # its mesh): the serving step's sharding is fully explicit
+            # (in_shardings + PagedState.constrain), but the TP layers'
+            # dp/mp sharding constraints consult
+            # distributed.mesh.get_mesh() — a mesh left installed by
+            # fleet.init/init_mesh would stamp its (differently-deviced)
+            # NamedShardings into this program and the call would reject
+            # the engine's own placement
+            from ..distributed.mesh import suppress_mesh
+
+            with suppress_mesh():
+                (logits, _), _ = functional_call(
+                    model, params, buffers, args=(ids,),
+                    kwargs={"caches": state}, training=False,
+                )
+            out = _decide(logits, ids, state.groups, parts, sub)
             if stats:
                 # the model's counts behind the rows' results: still ONE
                 # array for the host to read
@@ -1457,12 +1513,45 @@ class LLMEngine:
         it must be THE one formula for both tracing and marshalling."""
         return blocks_per_row(W, self.block_size) + 1
 
+    def step_groups(self, W):
+        """The shape of the width-``W`` program's token axis: its groups
+        ``((rows, width), ...)``, each a rectangle of rows with metadata
+        of its own, side by side on ONE axis of ``sum(rows x width)``
+        slots (`PagedState`). A bucket no wider than the decode group
+        (one token and the draft capacity) is that one rectangle of
+        ``max_batch`` rows: the all-decode steps, and a drafted
+        pure-decode step. A wider bucket is the width of the CHUNK group
+        behind the decode group: the rows that feed more than the decode
+        group holds, `chunk_rows` of them a step (as many whole chunks as
+        ``token_budget`` holds; the scheduler plans no more), so a step
+        that carries a prompt chunk computes the slots of the chunk and
+        one a decoding lane, not ``max_batch`` chunks."""
+        sched = self.scheduler
+        if W <= sched.decode_width:
+            return ((self.max_batch, W),)
+        return ((self.max_batch, sched.decode_width), (sched.chunk_rows, W))
+
     def _step_layout(self, W):
-        """`step_input_layout` of this engine's width-``W`` program."""
-        return step_input_layout(
-            self.max_batch, W, self.max_blocks,
-            self._touched_width(W) if self.pool.quantized else None,
-            bool(self.pool.lane_state))
+        """The width-``W`` program's packed host input: ``([fields of a
+        group, ...], words)``, `step_input_layout` of each of
+        `step_groups` one after the other, offsets from the array's
+        start."""
+        if W in self._layouts:
+            return self._layouts[W]
+        layouts, words = [], 0
+        for i, (n, width) in enumerate(self.step_groups(W)):
+            fields, size = step_input_layout(
+                n, width, self.max_blocks,
+                self._touched_width(width) if self.pool.quantized else None,
+                bool(self.pool.lane_state),
+                # a later group names the decode group's row each of its
+                # rows stands in for
+                lanes=i > 0)
+            layouts.append({name: (words + off, shape, dtype)
+                            for name, (off, shape, dtype) in fields.items()})
+            words += size
+        self._layouts[W] = layouts, words
+        return layouts, words
 
     def expected_program_count(self):
         """THE program-count contract, in one place: the engine compiles
@@ -1926,21 +2015,27 @@ class LLMEngine:
 
     def _row_arrays(self, S):
         """A fresh packed host input for the unified ragged step and its
-        named views (one dict so fill sites cannot drift apart on a
-        future per-row field): ``(packed, {name: view})``. Every section
-        starts zeroed — idle lanes have no live block (``kv_live`` 0: the
-        ragged kernel runs no iteration there), read the all-zeros
+        named views, a dict a group (so fill sites cannot drift apart on a
+        future per-row field): ``(packed, [{name: view}, ...])``. Every
+        section starts zeroed — idle rows have no live block (``kv_live``
+        0: the ragged kernel runs no iteration there), read the all-zeros
         adapter slot 0 and, with an int8 arena, touch only the null block
         (``touched`` slot 0, ``touch_idx`` 0) — but ``top_ps``, which
-        reads 1.0. A buffer a step was launched with is never written
-        again: a `device_put` on the CPU backend may alias it."""
-        fields, words = self._step_layout(S)
+        reads 1.0, and ``lanes``, which names no lane. A buffer a step was
+        launched with is never written again: a `device_put` on the CPU
+        backend may alias it."""
+        layouts, words = self._step_layout(S)
         packed = np.zeros(words, np.int32)
-        a = {name: packed[off:off + math.prod(shape)].view(dtype)
-                   .reshape(shape)
-             for name, (off, shape, dtype) in fields.items()}
-        a["top_ps"][:] = 1.0
-        return packed, a
+        parts = []
+        for fields in layouts:
+            a = {name: packed[off:off + math.prod(shape)].view(dtype)
+                       .reshape(shape)
+                 for name, (off, shape, dtype) in fields.items()}
+            a["top_ps"][:] = 1.0
+            if "lanes" in a:
+                a["lanes"][:] = self.max_batch
+            parts.append(a)
+        return packed, parts
 
     def _fill_row(self, a, i, req, start, w, S):
         """Everything about row `i` that does not depend on WHICH tokens
@@ -1968,6 +2063,31 @@ class LLMEngine:
             lut = {int(b): j + 1 for j, b in enumerate(uniq)}
             a["touch_idx"][i, :w] = [lut.get(int(s), 0) for s in sl]
 
+    def _place_rows(self, rows, groups):
+        """Where each planned row rides in a step of `groups`: ``(at,
+        lanes)``, ``at[i]`` its ``(group, row)`` and ``lanes[i]`` its row
+        of the decode group, which is also its row of the result: its
+        place in the plan or, where the pool holds state a lane, the
+        request's own lane, every step (rows between them idle: no live
+        block, nothing moves). A row that fits the decode group rides
+        there; a wider one takes the chunk group's next row (the scheduler
+        plans no more of them than it has) and names its lane there."""
+        lanes = ([row.req.lane for row in rows] if self.pool.lane_state
+                 else list(range(len(rows))))
+        at, chunk_rows = [], 0
+        for lane, row in zip(lanes, rows):
+            if row.count + len(row.draft) <= groups[0][1]:
+                at.append((0, lane))
+                continue
+            if chunk_rows == groups[1][0]:
+                raise AssertionError(
+                    f"more than {chunk_rows} rows wider than the decode "
+                    f"group in a step whose groups are {groups} — "
+                    "scheduler chunk-row capping broke")
+            at.append((1, chunk_rows))
+            chunk_rows += 1
+        return at, lanes
+
     def _run_rows(self, rows, W, step_id=0):
         """Run one unified ragged step at width bucket `W`: every
         scheduled row feeds its `count` chunk tokens at positions
@@ -1981,15 +2101,15 @@ class LLMEngine:
         reserved blocks return to the pool via `reclaim_spec_blocks`."""
         tr = self.tracer
         t_build = time.monotonic() if tr is not None else 0.0
-        packed, a = self._row_arrays(W)
-        last_idx, spec_lens = a["last_idx"], a["spec_lens"]
-        # which row of the program each planned row takes: the next one, or,
-        # where the pool holds state a lane, the request's own lane, every
-        # step (lanes between them idle: no live block, nothing moves)
-        at = ([row.req.lane for row in rows] if self.pool.lane_state
-              else range(len(rows)))
-        for i, row in zip(at, rows):
+        packed, parts = self._row_arrays(W)
+        groups = self.step_groups(W)
+        lane_state = bool(self.pool.lane_state)
+        at, lanes = self._place_rows(rows, groups)
+        for (g, i), lane, row in zip(at, lanes, rows):
             req, start, count, k = row.req, row.start, row.count, len(row.draft)
+            a = parts[g]
+            if g:
+                a["lanes"][i] = lane
             if start == req.num_tokens - 1:
                 # decode fast path: the single pending token is always the
                 # last one — skip rebuilding prompt+outputs every step
@@ -2000,24 +2120,33 @@ class LLMEngine:
                 # drafts only attach to emitting rows, fed right after
                 # the row's pending (last chunk) token
                 a["ids"][i, count:count + k] = row.draft
-            last_idx[i] = count - 1
-            spec_lens[i] = k
-            if self.pool.lane_state:
+            a["last_idx"][i] = count - 1
+            a["spec_lens"][i] = k
+            if lane_state:
                 # position 0 has no history: the first chunk, also of a
                 # request re-admitted after preemption
                 a["fresh"][i] = start == 0
-            self._fill_row(a, i, req, start, count + k, W)
+            self._fill_row(a, i, req, start, count + k, groups[g][1])
         fn = self._get_step_fn(self.max_batch, W)
         K = self._draft_capacity(W)
         if tr is not None:
-            # what the ragged kernel walks for this step, a head a layer:
-            # lanes past the planned rows carry no request (q_len 0)
-            q_lens = np.where(a["kv_live"] > 0, last_idx + 1 + spec_lens, 0)
-            live, total = ragged_grid_steps(q_lens, a["kv_live"], W,
+            # what the ragged kernel walks for this step, a head a layer,
+            # summed over the groups' launches: rows past the planned ones
+            # carry no request (q_len 0); and what the append's writer
+            # copies back, an arena a layer
+            live = total = blocks = 0
+            for (_, width), a in zip(groups, parts):
+                q_lens = np.where(a["kv_live"] > 0,
+                                  a["last_idx"] + 1 + a["spec_lens"], 0)
+                lv, tot = ragged_grid_steps(q_lens, a["kv_live"], width,
                                             self.block_size)
-            # and what the append's writer copies back, an arena a layer
-            self._span_args.update(grid_live=live, grid_total=total,
-                                   append_blocks=append_blocks(a["slots"]))
+                live, total = live + lv, total + tot
+                blocks += append_blocks(a["slots"])
+            self._span_args.update(
+                grid_live=live, grid_total=total, append_blocks=blocks,
+                # the token slots of the program that ran, beside the
+                # `fed_tokens` that were live in them
+                slots=sum(n * width for n, width in groups))
         t_disp = time.monotonic() if tr is not None else 0.0
         out_dev = self._run_step(fn, packed, step_id=step_id, t_disp=t_disp)
         t_sync = time.monotonic() if tr is not None else 0.0
@@ -2034,10 +2163,10 @@ class LLMEngine:
         run, n_accs, row_ok = (out[:, :K + 1], out[:, K + 1],
                                out[:, K + 2])
         if faults._PLAN is not None:
-            row_ok = self._corrupt_row_ok(zip(at, rows), row_ok)
+            row_ok = self._corrupt_row_ok(zip(lanes, rows), row_ok)
         t_emit = time.monotonic() if tr is not None else 0.0
         outs = []
-        for i, row in zip(at, rows):
+        for i, row in zip(lanes, rows):
             req, k = row.req, len(row.draft)
             if not row_ok[i]:
                 # NaN/Inf logits: abort this row only — its KV and token

@@ -9,7 +9,10 @@ running sequence gets a row, and a row is either
   token, fed at position ``num_cached``), always scheduled, never gated; or
 - a **prefill-chunk row** — the next ``<= prefill_chunk`` tokens of a
   sequence whose prompt (or post-preemption replay) is not yet in the KV
-  arena, admitted FCFS under a per-step ``token_budget`` of prefill tokens.
+  arena, admitted FCFS under a per-step ``token_budget`` of prefill tokens
+  and, of rows wider than a decode row with its drafts, at most
+  ``chunk_rows`` a step: the rows of the step program's chunk group
+  (engine.py `step_groups`). A further chunk waits a step.
 
 Decode therefore never stalls behind prefill: a long prompt streams into
 the arena a chunk at a time WHILE the running batch keeps decoding in the
@@ -258,6 +261,18 @@ class Scheduler:
         # value.
         self.width_buckets = (sorted(int(w) for w in width_buckets)
                               if width_buckets else None)
+        # the shape of a step that carries a chunk (engine.py
+        # `step_groups`): every row that fits the decode group's width (its
+        # one token and the drafts a drafter may hang on it) rides there,
+        # and at most `chunk_rows` wider rows a step ride the chunk group,
+        # as many whole chunks as the budget holds
+        self.decode_width = 1 + (drafter.num_spec_tokens
+                                 if drafter is not None else 0)
+        if self.width_buckets is not None:
+            self.decode_width = min(self.decode_width,
+                                    self.width_buckets[-1])
+        self.chunk_rows = min(self.max_batch, max(
+            1, self.token_budget // self.prefill_chunk))
         # scheduling policy (serving/policy.py SchedulingPolicy) or None.
         # None keeps the FCFS scheduler byte-identical; a policy replaces
         # the admission order, the planning order, and the preemption
@@ -608,6 +623,7 @@ class Scheduler:
                 self._admit(req)
 
         budget = self.token_budget
+        chunk_rows = self.chunk_rows
         rows = []
         # plan in precedence order (arrival order without a policy): the
         # strongest request gets first claim on the budget and on pool
@@ -627,6 +643,8 @@ class Scheduler:
                 count = min(pending, self.prefill_chunk, budget)
                 if count < 1:
                     continue  # budget spent; this chunk waits a step
+                if count > self.decode_width and not chunk_rows:
+                    continue  # the chunk group is full; it waits as well
             start = req.num_cached
             if not self._grow(req, self.pool.blocks_for(start + count)):
                 continue  # deferred — its budget share stays available
@@ -636,6 +654,7 @@ class Scheduler:
                 # budget is charged only for rows that actually scheduled,
                 # so a deferred/preempted chunk's share flows to later rows
                 budget -= count
+                chunk_rows -= count > self.decode_width
             rows.append(ScheduledRow(req, start, count, emit=count == pending))
         if self.drafter is not None and only is None and rows:
             # the unified ragged step program carries drafted candidates
@@ -683,8 +702,11 @@ class Scheduler:
             # request's remaining token allowance
             cap = min(cap, req.remaining_new_tokens() - 1)
             if mixed:
-                # free riders only: never widen a chunk-carrying step
-                cap = min(cap, base_w - row.count)
+                # free riders only: never widen a chunk-carrying step, nor
+                # move a row of the decode group into the chunk group
+                cap = min(cap, (self.decode_width
+                                if row.count <= self.decode_width < base_w
+                                else base_w) - row.count)
             elif top_w is not None:
                 # never exceed the widest compiled program
                 cap = min(cap, top_w - row.count)

@@ -5,7 +5,8 @@ textually the one the row scatter alone gave (PR 32's `_append`, written
 out below), so `ir_baseline.json` stands and nothing counts as a fall-back;
 with the knob every program goes through the Pallas writer, once a layer
 (K and V together) or twice (an int8 arena: each payload after its own
-requantization), and through the scatter nowhere. The six set-ups are PR
+requantization) for each group of rows the program holds, and through the
+scatter nowhere. The six set-ups are PR
 32's witness. On a tpu backend the compiled programs are
 `tests/test_mosaic_aot.py`'s to read.
 """
@@ -51,13 +52,24 @@ def _scatter_only(arenas, layer, news, slots, offs, mesh):
                  for arena, new in zip(arenas, news))
 
 
-def _programs(setup):
+def _engine(setup):
     paddle.seed(0)
     model = GPT(GPTConfig(vocab_size=64, hidden_size=32, num_layers=LAYERS,
                           num_heads=2, max_seq_len=64, dropout=0.0))
-    engine = LLMEngine(model, block_size=4, max_batch=2, max_seq_len=64,
-                       prefill_chunk=8, **SETUPS[setup])
-    return engine.lowered_step_programs()
+    return LLMEngine(model, block_size=4, max_batch=2, max_seq_len=64,
+                     prefill_chunk=8, **SETUPS[setup])
+
+
+def _programs(setup):
+    return _engine(setup).lowered_step_programs()
+
+
+def _groups(engine):
+    """How many groups of rows each of the engine's programs holds (`w1`:
+    the decode group alone; the chunk's width: the chunk group behind it):
+    the append and the attention run once a group."""
+    return {name: len(engine.step_groups(W))
+            for name, (_, W) in engine.step_program_shapes().items()}
 
 
 @pytest.mark.parametrize("setup", sorted(SETUPS))
@@ -89,7 +101,11 @@ def test_append_dispatch(monkeypatch, setup):
         # the scatter nowhere: its fall-back would be counted here
         m.setattr(_backend, "note_xla_fallback",
                   lambda *a: calls.append("xla"))
-        programs = _programs(setup)
+        engine = _engine(setup)
+        programs = engine.lowered_step_programs()
     int8 = "kv_dtype" in SETUPS[setup]
-    assert calls == ([1, 1] if int8 else [2]) * LAYERS * len(programs)
+    groups = _groups(engine)
+    assert sorted(groups) == sorted(programs) and groups["w1"] == 1 \
+        and groups["w8"] == 2
+    assert calls == ([1, 1] if int8 else [2]) * LAYERS * sum(groups.values())
     assert not _backend.XLA_FALLBACKS

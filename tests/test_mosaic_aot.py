@@ -286,31 +286,33 @@ EXPECTED = {
     "flash-grad-d128": FLASH,
     "flash-grad-remat": FLASH,
     # gpt of two layers; LLMEngine's default width buckets: a layer's K and
-    # V go in by one writer call and are read by one ragged call
+    # V go in by one writer call and are read by one ragged call, for each
+    # group of rows the program holds (w1: the decode group; w32: the chunk
+    # group behind it, `LLMEngine.step_groups`)
     "engine-step-w1": {"paged_kv_append": 2, "ragged_paged_attention": 2},
-    "engine-step-w32": {"paged_kv_append": 2, "ragged_paged_attention": 2},
+    "engine-step-w32": {"paged_kv_append": 4, "ragged_paged_attention": 4},
     # heads of 64: no kernel at all (`paged_kernels_off`)
     "engine-step-head64-w1": {},
     # a looped decoder of two layers and two passes: the loop's body is
-    # compiled once, so two pairs and not four
+    # compiled once, so two pairs a group and not four
     "looped-step-w1": {"paged_kv_append": 2, "ragged_paged_attention": 2},
-    "looped-step-w32": {"paged_kv_append": 2, "ragged_paged_attention": 2},
+    "looped-step-w32": {"paged_kv_append": 4, "ragged_paged_attention": 4},
     # grouped-query attention: 32 query heads over 2 K/V heads of 128
     **{f"ragged-gqa-w{w}": {"ragged_paged_attention": 1} for w in (1, 128)},
     **{f"append-gqa-w{w}": {"paged_kv_append": 1} for w in (1, 128)},
 }
 
 
-def test_hybrid_step_programs_hold_one_kernel_pair(aot):
+def test_hybrid_step_programs_hold_one_kernel_pair_a_group(aot):
     """The hybrid block's one attention layer: one writer call and one
-    ragged call a program, under the names the readers match; the other
-    custom calls of the program are the compiler's own (the expert layer's
-    grouped products, the router's top-k)."""
+    ragged call for each group of rows a program holds, under the names the
+    readers match; the other custom calls of the program are the compiler's
+    own (the expert layer's grouped products, the router's top-k)."""
     names = _facts(aot, "KERNEL_NAMES")
-    for w in (1, 32):
+    for w, groups in ((1, 1), (32, 2)):
         got = names[f"hybrid-step-w{w}"]
-        assert got.get("paged_kv_append") == 1, got
-        assert got.get("ragged_paged_attention") == 1, got
+        assert got.get("paged_kv_append") == groups, got
+        assert got.get("ragged_paged_attention") == groups, got
 
 
 @pytest.fixture(scope="module")

@@ -377,19 +377,19 @@ def test_scheduler_fcfs_mixed_rows_and_token_budget():
     ]
     assert r2.state == "running" and r3.state == "waiting"
     r1.num_cached += 6
-    # next step: r1's last 4 prompt tokens (emits) + r2's full 4-token
-    # prompt would exceed budget 6 -> r2 gets the 2 remaining tokens
+    # next step: r1's last 4 prompt tokens (emits). The budget has 2 left,
+    # but a budget of one chunk is a chunk group of ONE row (chunk_rows =
+    # token_budget // prefill_chunk) and r1 has it: r2 waits a step more
+    assert sched.chunk_rows == 1
     rows = sched.schedule()
-    assert [(w.req, w.count, w.emit) for w in rows] == [
-        (r1, 4, True), (r2, 2, False)
-    ]
+    assert [(w.req, w.count, w.emit) for w in rows] == [(r1, 4, True)]
     for w in rows:
         w.req.num_cached += w.count
     r1.output_ids.append(7)  # r1's first token emitted -> decode row next
-    # mixed step: r1 decodes (never budget-gated) while r2 finishes prefill
+    # mixed step: r1 decodes (never gated) while r2 prefills in the one row
     rows = sched.schedule()
     assert [(w.req, w.count, w.emit) for w in rows] == [
-        (r1, 1, True), (r2, 2, True)
+        (r1, 1, True), (r2, 4, True)
     ]
     for w in rows:
         w.req.num_cached += w.count
@@ -401,19 +401,19 @@ def test_scheduler_fcfs_mixed_rows_and_token_budget():
 
 
 def test_scheduler_admission_exactly_at_token_budget():
-    """Chunk packing fills the budget exactly: three rows' chunks sum to
-    token_budget with the tail row truncated, never overshooting."""
+    """Chunk packing never overshoots the budget, and plans no more chunk
+    rows than whole chunks fit it (`chunk_rows`, the step program's chunk
+    group): the third prefill waits a step though 2 tokens of budget are
+    left, where a step of one rectangle gave it those 2."""
     pool = BlockPool(num_blocks=64, num_layers=1, block_size=4, num_heads=1,
                      head_dim=4)
     sched = Scheduler(pool, max_batch=4, token_budget=12, prefill_chunk=5)
     reqs = [Request([1] * n, max_new_tokens=2) for n in (5, 5, 9, 8)]
     for r in reqs:
         sched.add(r)
+    assert sched.chunk_rows == 2
     rows = sched.schedule()
-    assert [(w.req, w.count) for w in rows] == [
-        (reqs[0], 5), (reqs[1], 5), (reqs[2], 2)  # 5+5+2 == budget 12
-    ]
-    assert sum(w.count for w in rows) == 12
+    assert [(w.req, w.count) for w in rows] == [(reqs[0], 5), (reqs[1], 5)]
     for w in rows:
         w.req.num_cached += w.count
         if w.emit:

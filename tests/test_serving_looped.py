@@ -3,9 +3,10 @@ decode through the paged arena, against the plain reference's full forward
 (`benchmark/reference/ouro_loop.py`), on logits and not on tokens.
 
 The logits are the step programs' own: the model's forward is wrapped so
-that every step hands its `[rows, width, vocab]` logits (and the exit gate's
-probabilities) to the host, and the rows the scheduler planned say which
-request and position each belongs to.
+that every step hands its logits (and the exit gate's probabilities) to the
+host in the step's layout, and the rows the scheduler planned, with where
+the engine placed them (`_serving_common.row_origins`), say which request and
+position each belongs to.
 
 Tolerance. Float32 weights and a float32 arena, so the served logits and
 the reference's differ only in the order of float32 sums (the paged path
@@ -30,6 +31,8 @@ from paddle_tpu.serving import LLMEngine
 
 from _looped_common import (VOCAB, build, load_reference, params_of,
                             published_keys)
+from _serving_common import (WAVE, check_wave_plans, rectangle, row_origins,
+                             serve, spy_plans, wave_of)
 
 REPO = Path(__file__).resolve().parents[1]
 TOL = 1e-4
@@ -61,8 +64,9 @@ class Served:
         run_rows = self.engine._run_rows
 
         def planned(rows, W, step_id=0):
-            self._plans.append([(r.req.request_id, r.start, r.count)
-                                for r in rows])
+            self._plans.append([
+                (r.req.request_id, r.start, r.count, at) for r, at in
+                zip(rows, row_origins(self.engine, rows, W))])
             return run_rows(rows, W, step_id)
 
         self.engine._run_rows = planned
@@ -71,11 +75,11 @@ class Served:
         outs = self.engine.step()
         jax.effects_barrier()
         for plan, (lg, gates) in zip(self._plans, self._seen):
-            for row, (rid, start, count) in enumerate(plan):
+            for rid, start, count, (b, s) in plan:
                 for j in range(count):
-                    self.logits.setdefault(rid, {})[start + j] = lg[row, j]
+                    self.logits.setdefault(rid, {})[start + j] = lg[b, s + j]
                     self.gates.setdefault(rid, {})[start + j] = \
-                        gates[:, row, j]
+                        gates[:, b, s + j]
         assert len(self._plans) == len(self._seen)
         self._plans, self._seen = [], []
         return outs
@@ -225,6 +229,73 @@ def test_options_written_for_gpt_are_refused_with_a_sentence(option, kw):
         LLMEngine(build(2), block_size=8, max_batch=2, max_seq_len=64, **kw)
 
 
+# -- the shape of a step that carries a chunk (PR 37) ------------------------
+# as tests/test_serving_chunked.py holds GPT's: the same engine steered back
+# to the one rectangle is the reference, over the wave that puts every kind
+# of row beside every other
+
+GROUPED = dict(block_size=8, max_batch=4, max_seq_len=96, prefill_chunk=8,
+               token_budget=16, prefix_cache=False, seed=11)
+
+
+@pytest.mark.parametrize("knobs", [
+    dict(temperature=0.0), dict(temperature=0.8, top_k=20, top_p=0.95)],
+    ids=["greedy", "seeded"])
+def test_a_grouped_step_is_the_rectangles_step(knobs):
+    """Four passes over 8 slabs of arena, the loop carrying the arenas
+    through both groups' appends: tokens, greedy and sampled, and every
+    slab of the arena as the one rectangle left them (the logits against
+    the reference: the next test)."""
+    wave = wave_of(_prompts([n for _, n, _ in WAVE], seed=4))
+    grouped = LLMEngine(build(4), **GROUPED)
+    parent = rectangle(LLMEngine(build(4), **GROUPED))
+    assert grouped.step_groups(8) == ((4, 1), (2, 8))
+    got, plans = serve(grouped, wave, **knobs)
+    want, parents_plans = serve(parent, wave, **knobs)
+    check_wave_plans(plans)
+    assert plans == parents_plans and got == want
+    for name in ("k", "v"):
+        x, y = (np.asarray(getattr(e.pool, name))[:, :, 1:]
+                for e in (grouped, parent))
+        assert x.shape[0] == 8 and np.abs(x - y).max() <= 1e-5, name
+    assert grouped.metrics.counters["jit_traces"] == \
+        grouped.expected_program_count() == 2
+
+
+def test_a_grouped_steps_logits_are_the_references():
+    served = Served(build(2), **GROUPED)
+    wave = wave_of(_prompts([n for _, n, _ in WAVE], seed=5))
+    plans = spy_plans(served.engine)
+    rids = [served.engine.add_request(p, max_new_tokens=n)
+            for _, p, n in wave[:2]]
+    served.step()
+    served.step()
+    rids += [served.engine.add_request(p, max_new_tokens=n)
+             for _, p, n in wave[2:]]
+    served.run()
+    assert [[c for *_, c, _ in rows] for _, rows in plans[:5]] == [
+        [8, 5], [8, 1], [3, 1, 8], [1, 1, 8, 8], [1, 1, 1, 1]]
+    for rid, (_, prompt, _) in zip(rids, wave):
+        assert _held_to_the_reference(served, rid, prompt) < TOL
+
+
+def test_a_third_chunk_row_waits_and_a_wider_budget_has_a_row_a_lane():
+    prompts = _prompts((12, 11, 10, 9), seed=6)
+    burst = [(0, p, 3) for p in prompts]
+    narrow = LLMEngine(build(2), **GROUPED)
+    assert narrow.scheduler.chunk_rows == 2
+    got, plans = serve(narrow, burst)
+    assert [[(r, c) for r, _, c, _ in rows] for _, rows in plans[:4]] == [
+        [(0, 8), (1, 8)], [(0, 4), (1, 3)],
+        [(0, 1), (1, 1), (2, 8), (3, 8)], [(0, 1), (1, 1), (2, 2), (3, 1)]]
+    wide = LLMEngine(build(2), **{**GROUPED, "token_budget": 4 * 8})
+    assert wide.step_groups(8) == ((4, 1), (4, 8))
+    wants, plans = serve(wide, burst)
+    assert [[c for *_, c, _ in rows] for _, rows in plans[:2]] == [
+        [8, 8, 8, 8], [4, 3, 2, 1]]
+    assert got == wants
+
+
 def test_gpt_step_programs_are_textually_the_parents():
     """PR 32's six set-ups (`tests/test_append_dispatch.py`): every lowered
     GPT step program has the text written down for it (sha256 of
@@ -238,16 +309,45 @@ def test_gpt_step_programs_are_textually_the_parents():
     successor but `main`'s signature and its `return`, and what the
     successor adds is 127-132 lines: the slices, reshapes and two
     `bitcast_convert`s of `unpack_step_input` and the key's split, which
-    PR 34 ran as a program of its own (compared on all thirteen, PR 35)."""
-    from test_append_dispatch import SETUPS, _programs
+    PR 34 ran as a program of its own (compared on all thirteen, PR 35).
+    **PR 37: a program that holds one group of rows is the parent's, and
+    keeps its hash** (`w1` everywhere, `w5` of the drafting engine: the
+    rectangle is the one-group case of the same code and emits no new
+    operation); **a program that holds a chunk group behind the decode
+    group has a text of its own** (`tests/gpt_step_programs_pr37.json`, the
+    six `w8`): its tokens lie on one flat axis, and the append and the
+    attention run once a group."""
+    from test_append_dispatch import SETUPS, _engine
 
-    want = json.loads((REPO / "tests/gpt_step_programs_pr35.json")
-                      .read_text())
-    assert sorted(want) == sorted(SETUPS)
+    assert sorted(_wanted("gpt")) == sorted(SETUPS)
     for setup in sorted(SETUPS):
-        got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()
-               for name, low in _programs(setup).items()}
-        assert got == want[setup], setup
+        _hashes_are_the_recorded(_engine(setup), "gpt", setup)
+
+
+def _wanted(kind):
+    """`{setup: {program: sha256}}`: PR 35's record with the programs PR 37
+    gave a text of their own laid over it."""
+    want = json.loads((REPO / f"tests/{kind}_step_programs_pr35.json")
+                      .read_text())
+    for setup, wide in json.loads(
+            (REPO / f"tests/{kind}_step_programs_pr37.json")
+            .read_text()).items():
+        assert wide.keys() <= want[setup].keys()
+        assert all(want[setup][name] != h for name, h in wide.items())
+        want[setup].update(wide)
+    return want
+
+
+def _hashes_are_the_recorded(engine, kind, setup):
+    got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()
+           for name, low in engine.lowered_step_programs().items()}
+    assert got == _wanted(kind)[setup], setup
+    # what PR 37 recorded anew is the programs of more than one group
+    new = json.loads((REPO / f"tests/{kind}_step_programs_pr37.json")
+                     .read_text())[setup]
+    assert sorted(new) == sorted(
+        name for name, (_, W) in engine.step_program_shapes().items()
+        if len(engine.step_groups(W)) > 1)
 
 
 LOOPED_SETUPS = {"default": {}, "int8": {"kv_dtype": "int8"},
@@ -260,16 +360,14 @@ def test_looped_step_programs_are_textually_the_parents():
     a section that a model with such state adds): the seven lowered step
     programs of three engines over `build(2)` have the text they had at PR
     35 (`tests/looped_step_programs_pr35.json`, hashed there from a copy of
-    that commit with this jax)."""
-    want = json.loads((REPO / "tests/looped_step_programs_pr35.json")
-                      .read_text())
-    assert sorted(want) == sorted(LOOPED_SETUPS)
+    that commit with this jax); since PR 37 the four of one group of rows
+    have, and the three `w16` the text of
+    `tests/looped_step_programs_pr37.json`."""
+    assert sorted(_wanted("looped")) == sorted(LOOPED_SETUPS)
     for setup, kw in sorted(LOOPED_SETUPS.items()):
         engine = LLMEngine(build(2), block_size=8, max_batch=2,
                            max_seq_len=64, prefill_chunk=16, **kw)
-        got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()
-               for name, low in engine.lowered_step_programs().items()}
-        assert got == want[setup], setup
+        _hashes_are_the_recorded(engine, "looped", setup)
 
 
 def test_the_benchmark_has_the_configuration_and_its_cell():

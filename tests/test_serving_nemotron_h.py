@@ -4,9 +4,10 @@ its Mamba layers keep in the pool, against the plain reference's full
 forward (`benchmark/reference/nemotron_h_block.py`), on logits.
 
 The logits are the step programs' own, kept as `tests/test_serving_looped.py`
-keeps them: the model's forward hands every step's `[rows, width, vocab]`
-logits to the host, and the planned rows say which request and position each
-belongs to; a row of the program is the request's LANE here.
+keeps them: the model's forward hands every step's logits to the host, and
+the planned rows, with where the engine placed them, say which request and
+position each belongs to; a row of the decode group is the request's LANE
+here.
 
 Tolerance. Float32 weights, arena and state: served logits and the
 reference's differ in the order of float32 sums (the chunk form, the paged
@@ -25,6 +26,8 @@ from paddle_tpu.serving.engine import step_input_layout
 
 from _nemotron_common import (VOCAB, build, load_reference, params_of,
                               published_keys)
+from _serving_common import (WAVE, check_wave_plans, rectangle, row_origins,
+                             serve, wave_of)
 
 REPO = Path(__file__).resolve().parents[1]
 TOL = 1e-4
@@ -39,7 +42,8 @@ class Served:
 
     def __init__(self, model, **engine_kw):
         self.model = model
-        self.logits, self.plans, self._plans, self._seen = {}, [], [], []
+        self.logits, self.plans = {}, []
+        self._plans, self._origins, self._seen = [], [], []
         plain = model.forward
 
         def forward(ids, caches=None):
@@ -53,8 +57,10 @@ class Served:
         run_rows = self.engine._run_rows
 
         def planned(rows, W, step_id=0):
-            self._plans.append([(r.req.request_id, r.req.lane, r.start,
-                                 r.count) for r in rows])
+            self._plans.append([
+                (r.req.request_id, r.req.lane, r.start, r.count)
+                for r in rows])
+            self._origins.append(row_origins(self.engine, rows, W))
             return run_rows(rows, W, step_id)
 
         self.engine._run_rows = planned
@@ -63,12 +69,12 @@ class Served:
         outs = self.engine.step(only=only)
         jax.effects_barrier()
         assert len(self._plans) == len(self._seen)
-        for plan, lg in zip(self._plans, self._seen):
-            for rid, lane, start, count in plan:
+        for plan, origins, lg in zip(self._plans, self._origins, self._seen):
+            for (rid, _, start, count), (b, s) in zip(plan, origins):
                 for j in range(count):
-                    self.logits.setdefault(rid, {})[start + j] = lg[lane, j]
+                    self.logits.setdefault(rid, {})[start + j] = lg[b, s + j]
         self.plans += self._plans
-        self._plans, self._seen = [], []
+        self._plans, self._origins, self._seen = [], [], []
         return outs
 
     def run(self):
@@ -129,6 +135,82 @@ def test_chunked_prefill_then_decode_gives_the_references_logits():
     assert m.gauges["state_bytes_per_lane"] == 2 * 4 * (
         cfg.mamba_num_heads * cfg.mamba_head_dim * cfg.ssm_state_size
         + (cfg.conv_kernel - 1) * cfg.conv_channels)
+
+
+# -- the shape of a step that carries a chunk (PR 37) ------------------------
+# as tests/test_serving_chunked.py holds GPT's: the same engine steered back
+# to the one rectangle is the reference. Here the chunk group's rows gather
+# the state of the lanes they name and scatter it back, while the decode
+# group uses the lanes' state in place.
+
+GROUPED = dict(block_size=4, max_batch=4, max_seq_len=96, prefill_chunk=8,
+               token_budget=16, seed=11)
+
+
+@pytest.mark.parametrize("knobs", [
+    dict(temperature=0.0), dict(temperature=0.8, top_k=20, top_p=0.95)],
+    ids=["greedy", "seeded"])
+def test_a_grouped_step_is_the_rectangles_step(knobs):
+    """Tokens, greedy and sampled, the arena, AND the state every lane is
+    left with (recurrent state and the convolution's window), as the one
+    rectangle gave them; every lane's state was written."""
+    wave = wave_of(_prompts([n for _, n, _ in WAVE], seed=4))
+    grouped = LLMEngine(build(experts_held=4, experts_from=4), **GROUPED)
+    parent = rectangle(LLMEngine(build(experts_held=4, experts_from=4),
+                                 **GROUPED))
+    assert grouped.step_groups(8) == ((4, 1), (2, 8))
+    assert "lanes" in grouped._step_layout(8)[0][1]
+    got, plans = serve(grouped, wave, **knobs)
+    want, parents_plans = serve(parent, wave, **knobs)
+    check_wave_plans(plans)
+    assert plans == parents_plans and got == want
+    for name in ("k", "v"):
+        x, y = (np.asarray(getattr(e.pool, name))[:, :, 1:]
+                for e in (grouped, parent))
+        assert np.abs(x - y).max() <= 1e-5, name
+    for name, state in grouped.pool.lane_state.items():
+        x, y = np.asarray(state), np.asarray(parent.pool.lane_state[name])
+        assert np.abs(x - y).max() <= 1e-5, name
+        assert all(np.abs(x[:, lane]).max() > 0 for lane in range(4)), name
+    assert grouped.metrics.counters["jit_traces"] == \
+        grouped.expected_program_count() == 2
+
+
+def test_a_grouped_steps_logits_are_the_references():
+    """The wave's five kinds of step against the reference's full forward:
+    a chunk row's state crosses from the chunk group (its first chunks) to
+    the decode group (a last chunk of one token, then decoding)."""
+    served = Served(build(experts_held=4, experts_from=4), **GROUPED)
+    wave = wave_of(_prompts([n for _, n, _ in WAVE], seed=5))
+    engine = served.engine
+    rids = [engine.add_request(p, max_new_tokens=n) for _, p, n in wave[:2]]
+    served.step()
+    served.step()
+    rids += [engine.add_request(p, max_new_tokens=n) for _, p, n in wave[2:]]
+    reqs = [engine.get_request(r) for r in rids]
+    served.run()
+    assert [[c for *_, c in plan] for plan in served.plans[:5]] == [
+        [8, 5], [8, 1], [3, 1, 8], [1, 1, 8, 8], [1, 1, 1, 1]]
+    served.outputs = {r: list(q.output_ids) for r, q in zip(rids, reqs)}
+    for rid, (_, prompt, _) in zip(rids, wave):
+        assert _worst(served, rid, prompt) < TOL
+
+
+def test_a_third_chunk_row_waits_and_a_wider_budget_has_a_row_a_lane():
+    prompts = _prompts((12, 11, 10, 9), seed=6)
+    burst = [(0, p, 3) for p in prompts]
+    narrow = LLMEngine(build(), **GROUPED)
+    assert narrow.scheduler.chunk_rows == 2
+    got, plans = serve(narrow, burst)
+    assert [[(r, c) for r, _, c, _ in rows] for _, rows in plans[:4]] == [
+        [(0, 8), (1, 8)], [(0, 4), (1, 3)],
+        [(0, 1), (1, 1), (2, 8), (3, 8)], [(0, 1), (1, 1), (2, 2), (3, 1)]]
+    wide = LLMEngine(build(), **{**GROUPED, "token_budget": 4 * 8})
+    assert wide.step_groups(8) == ((4, 1), (4, 8))
+    wants, plans = serve(wide, burst)
+    assert [[c for *_, c, _ in rows] for _, rows in plans[:2]] == [
+        [8, 8, 8, 8], [4, 3, 2, 1]]
+    assert got == wants
 
 
 def test_preemption_by_recompute_starts_the_state_from_zeros():
@@ -195,7 +277,13 @@ def test_the_step_input_gains_one_section_and_only_for_lane_state():
     lane, m = step_input_layout(3, 8, 24, None, True)
     assert set(lane) - set(plain) == {"fresh"} and m == n + 3
     engine = LLMEngine(build(), **KW)
-    assert "fresh" in engine._step_layout(1)[0]
+    (decode,), _ = engine._step_layout(1)
+    assert "fresh" in decode and "lanes" not in decode
+    # behind the decode group, whose row i is lane i, the chunk group's
+    # rows say whose state they carry
+    (decode, chunk), _ = engine._step_layout(8)
+    assert "fresh" in decode and "lanes" not in decode
+    assert "fresh" in chunk and chunk["lanes"][1] == (1,)   # one chunk row
     assert [a.shape for a in engine.pool.lane_state.values()] == [
         (2, 3, 8, 16, 16), (2, 3, 3, 192)]
     assert len(engine.step_program_spec()["arena_param_indices"]) == 4

@@ -209,7 +209,9 @@ def test_label_values_truncated():
 
 def test_decomposition_sums_and_preemption_attribution(model):
     # pool sized so the younger of two long requests must be preempted
-    engine = _engine(model, max_batch=2, num_blocks=5, slo=True)
+    # (a chunk row each, so that both prefill in the first step)
+    engine = _engine(model, max_batch=2, num_blocks=5, slo=True,
+                     token_budget=2 * 64)
     rids = [
         engine.add_request(_prompts((24,), seed=3)[0], max_new_tokens=8,
                            tenant="acme", priority="hi", deadline_s=60.0),
@@ -420,7 +422,9 @@ def test_watchdog_trip_stalled_and_bundle(model, tmp_path):
     plan = faults.install(FaultPlan([
         {"point": "step_hang", "at_step": 1, "timeout_s": 60.0},
     ]))
-    engine = _engine(model, trace=True, postmortem_dir=str(tmp_path))
+    # (a chunk row each: both requests are in the step that hangs)
+    engine = _engine(model, trace=True, postmortem_dir=str(tmp_path),
+                     token_budget=2 * 64)
 
     async def main():
         fe = await AsyncLLMEngine(

@@ -123,17 +123,23 @@ def test_every_step_span_says_grid_and_handover(model, kv_dtype):
     for span, phases in steps:
         a = span["args"]
         assert "host_syncs" not in a
-        nq = a["width"] // _q_tile(a["width"])
+        # the launches of the step's groups, summed: every query tile of
+        # every row of every group (`step_groups`)
+        groups = engine.step_groups(a["width"])
+        nq = sum(n * (w // _q_tile(w)) for n, w in groups)
+        assert a["slots"] == sum(n * w for n, w in groups) >= a["fed_tokens"]
         # every planned row fetches at least its own first pages, and
-        # every query tile of every lane either iterates or is skipped
+        # every query tile of every row either iterates or is skipped
         assert a["rows"] <= a["grid_live"] <= a["grid_total"]
-        assert a["grid_total"] >= engine.max_batch * nq
-        assert a["grid_total"] - a["grid_live"] <= engine.max_batch * nq
+        assert a["grid_total"] >= nq
+        assert a["grid_total"] - a["grid_live"] <= nq
         # one packed buffer a step, whatever the arena: its sections are
-        # the step's thirteen host arrays (fifteen with an int8 arena)
-        fields, words = engine._step_layout(a["width"])
+        # a group's thirteen host arrays (fifteen with an int8 arena; a
+        # group behind the decode group has `lanes` besides)
+        layouts, words = engine._step_layout(a["width"])
         assert a["h2d_arrays"] == 1
-        assert len(fields) == (15 if kv_dtype else 13)
+        assert [len(fields) for fields in layouts] == [
+            (15 if kv_dtype else 13) + (g > 0) for g in range(len(groups))]
         assert a["h2d_bytes"] == 4 * words
         # ts/dur are rounded to 3 decimals of a microsecond
         assert 0.0 <= a["h2d_ms"] <= phases["dispatch"]["dur"] / 1e3 + 1e-5
@@ -149,7 +155,8 @@ def test_grid_live_follows_the_rows_contexts(model, monkeypatch):
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     monkeypatch.setattr(pa, "_KV_FETCH_TOKENS", 8)
-    engine = _engine(model, trace=1.0, max_batch=2)
+    # (a chunk row each: both prompts go in with the first step)
+    engine = _engine(model, trace=1.0, max_batch=2, token_budget=2 * 64)
     engine.generate(_prompts((7, 15), seed=3), max_new_tokens=10)
     decode = [s["args"] for s, _ in _steps(engine)
               if s["args"]["kind"] == "decode" and s["args"]["rows"] == 2]
@@ -167,7 +174,8 @@ def test_append_blocks_is_the_blocks_a_step_writes(model):
     of 8: prompts of 7 and 15 tokens from position 0 fill one and two; a
     decode step writes one token a row, one block each; rows that run out
     leave lanes that write none."""
-    engine = _engine(model, trace=1.0, max_batch=2, prefill_chunk=16)
+    engine = _engine(model, trace=1.0, max_batch=2, prefill_chunk=16,
+                     token_budget=2 * 16)
     for prompt, n in zip(_prompts((7, 15), seed=3), (10, 6)):
         engine.add_request(prompt, max_new_tokens=n)
     while engine.has_unfinished():

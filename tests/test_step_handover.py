@@ -40,8 +40,10 @@ def model():
 
 
 def _engine(model, **kw):
+    # a budget of two chunks: two chunk rows a step (the default is one)
     kw = dict(dict(block_size=8, max_batch=3, max_seq_len=64,
-                   prefill_chunk=CHUNK, seed=SEED), **kw)
+                   prefill_chunk=CHUNK, token_budget=2 * CHUNK, seed=SEED),
+              **kw)
     return LLMEngine(model, **kw)
 
 
@@ -66,11 +68,14 @@ def _same_key(a, b):
 # -- (a) the layout ----------------------------------------------------------
 
 
-def _pr34_arrays(engine, rows, S):
+def _pr34_arrays(engine, placed, B, S):
     """The separate host arrays PR 34's `_row_arrays` made and its
-    `_run_rows` filled (written out from that commit), for the same plan,
-    through the engine's own `_fill_row`."""
-    B = engine.max_batch
+    `_run_rows` filled (written out from that commit) for a rectangle of
+    `B` rows of `S` slots, through the engine's own `_fill_row`. `placed`:
+    `(row of the rectangle, planned row)` pairs; PR 34's step was the one
+    rectangle `[max_batch, S]` with every planned row in its place in the
+    plan, and a step is one such rectangle a group now
+    (`LLMEngine.step_groups`)."""
     a = {
         "ids": np.zeros((B, S), np.int32),
         "qpos": np.zeros((B, S), np.int32),
@@ -89,7 +94,7 @@ def _pr34_arrays(engine, rows, S):
     if engine.pool.quantized:
         a["touched"] = np.zeros((B, engine._touched_width(S)), np.int32)
         a["touch_idx"] = np.zeros((B, S), np.int32)
-    for i, row in enumerate(rows):
+    for i, row in placed:
         req, start, count, k = row.req, row.start, row.count, len(row.draft)
         if start == req.num_tokens - 1:
             a["ids"][i, 0] = req.last_token
@@ -101,6 +106,27 @@ def _pr34_arrays(engine, rows, S):
         a["spec_lens"][i] = k
         engine._fill_row(a, i, req, start, count + k, S)
     return a
+
+
+def _group_arrays(engine, rows, W):
+    """`[(PR 34's arrays, rows used), ...]`, a group of the width-`W` step
+    each: a planned row that feeds one token (and its drafts) in its place
+    in the plan in the decode group, a wider one in the chunk group's next
+    row, which also names that place (`lanes`; an idle row names none:
+    `max_batch`)."""
+    groups = engine.step_groups(W)
+    wide = [i for i, r in enumerate(rows)
+            if r.count + len(r.draft) > groups[0][1]]
+    out = []
+    for g, (B, S) in enumerate(groups):
+        placed = ([(j, rows[i]) for j, i in enumerate(wide)] if g else
+                  [(i, r) for i, r in enumerate(rows) if i not in wide])
+        a = _pr34_arrays(engine, placed, B, S)
+        if g:
+            a["lanes"] = np.full(B, engine.max_batch, np.int32)
+            a["lanes"][:len(wide)] = wide
+        out.append((a, {i for i, _ in placed}))
+    return out
 
 
 def _bits(x):
@@ -127,7 +153,7 @@ def test_the_program_unpacks_what_pr34s_separate_arrays_held(
 
     def spy_rows(rows, W, step_id=0):
         # before the step runs: emitting moves the requests on
-        steps.append([W, len(rows), _pr34_arrays(engine, rows, W)])
+        steps.append([W, len(rows), _group_arrays(engine, rows, W)])
         return run_rows(rows, W, step_id)
 
     def spy_step(fn, packed, **kw):
@@ -149,36 +175,46 @@ def test_the_program_unpacks_what_pr34s_separate_arrays_held(
 
     widths = {W for W, *_ in steps}
     assert {1, CHUNK} <= widths and (not extras or len(widths) == 3)
-    fewer = False
-    for n, (W, n_rows, want, packed) in enumerate(steps):
-        fields, words = engine._step_layout(W)
+    fewer = chunk_beside_decode = False
+    for n, (W, n_rows, groups, packed) in enumerate(steps):
+        layouts, words = engine._step_layout(W)
         assert packed.dtype == np.int32 and packed.shape == (words,)
-        assert list(fields) == [
-            "ids", "tables", "slots", "offs", "qpos", "q_start", "kv_live",
-            *(["touched", "touch_idx"] if kv_dtype else []),
-            "adapter_slots", "last_idx", "spec_lens", "temps", "top_ks",
-            "top_ps"]
-        got = jax.jit(functools.partial(unpack_step_input, fields=fields))(
-            packed)
-        assert sorted(got) == sorted(want)
-        for name in want:
-            assert got[name].dtype == want[name].dtype, name
-            assert got[name].shape == want[name].shape, name
-            assert np.array_equal(_bits(got[name]), _bits(want[name])), (
-                n, W, name)
-        # lanes past the plan: zeros everywhere, and top_ps 1.0
-        for name in want:
-            idle = np.asarray(got[name])[n_rows:]
-            assert (idle == (1.0 if name == "top_ps" else 0)).all(), name
+        # the chunk's width alone puts a chunk group behind the decode group
+        assert len(layouts) == len(groups) == (2 if W == CHUNK else 1)
+        for g, (fields, (want, used)) in enumerate(zip(layouts, groups)):
+            assert list(fields) == [
+                "ids", "tables", "slots", "offs", "qpos", "q_start",
+                "kv_live", *(["touched", "touch_idx"] if kv_dtype else []),
+                *(["lanes"] if g else []),
+                "adapter_slots", "last_idx", "spec_lens", "temps", "top_ks",
+                "top_ps"]
+            got = jax.jit(functools.partial(unpack_step_input,
+                                            fields=fields))(packed)
+            assert sorted(got) == sorted(want)
+            for name in want:
+                assert got[name].dtype == want[name].dtype, name
+                assert got[name].shape == want[name].shape, name
+                assert np.array_equal(_bits(got[name]), _bits(want[name])), (
+                    n, W, g, name)
+            # rows without a planned row: zeros everywhere, top_ps 1.0, and
+            # no lane named
+            idle = [i for i in range(len(want["last_idx"])) if i not in used]
+            for name in want:
+                fill = {"top_ps": 1.0, "lanes": engine.max_batch}.get(name, 0)
+                assert (np.asarray(got[name])[idle] == fill).all(), name
+        chunk_beside_decode |= len(groups) == 2 and all(
+            used for _, used in groups)
         fewer |= n > 0 and n_rows < steps[n - 1][1]
+    assert chunk_beside_decode
     assert fewer
     # the sampling knobs reached the program as the floats they were
-    first = steps[0][2]
+    first = steps[0][2][-1][0]     # the prompts' first chunks
     assert first["temps"][0] == np.float32(0.7)
     assert first["top_ps"][1] == np.float32(0.35)
     if extras:
         assert first["adapter_slots"][0] == 1
-        assert any(w["spec_lens"].any() for _, _, w, _ in steps)
+        assert any(w["spec_lens"].any() for _, _, groups, _ in steps
+                   for w, _ in groups)
 
 
 def test_the_layout_is_a_function_of_the_programs_shape():
