@@ -184,6 +184,7 @@ class Model:
         self._in_fit = False          # fit emits the train_step span itself
         self._trace_phases = {}       # last step's {phase: (t0, t1)}
         self._trace_sid = None        # last step's trace id, unclaimed
+        self._trace_args = {}         # and what its launch stamped
         self._jit_traces = 0          # bumped at TRACE time in step bodies
         self._monitor_grad_norm = False
         self._last_grad_norm = None
@@ -399,9 +400,11 @@ class Model:
         if tr is not None:
             # the dispatch runs under the xplane join annotation so a
             # jax.profiler capture of this fit joins back to the host
-            # train_step spans by step id (xplane.join_engine_steps)
+            # train_step spans by step id (xplane.join_engine_steps), and
+            # stamps the span's `launch_ns` (`Tracer.launch`)
             sid = tr.next_step_id()
-            ann = jax.profiler.TraceAnnotation(tr.step_annotation(sid))
+            self._trace_args = {}
+            ann = tr.launch(sid, self._trace_args)
         else:
             sid, ann = None, contextlib.nullcontext()
         t_disp0 = time.monotonic() if tr is not None else 0.0
@@ -437,6 +440,7 @@ class Model:
                 tr.record_train_step(sid, self._trace_phases, {
                     "batch_size": int(ins[0].shape[0]) if ins else 0,
                     "loss": loss_val[0],
+                    **self._trace_args,
                 })
                 self._trace_sid = None
         if metrics:
@@ -651,6 +655,7 @@ class Model:
                         "batch": step,
                         "batch_size": batch_size,
                         "loss": logs.get("loss"),
+                        **self._trace_args,
                     })
                     self._trace_sid = None
                 if mode == "train" and self.stop_training:

@@ -23,6 +23,12 @@ compiled train steps) records the same kind of timeline:
 (`STEP_ANNOTATION_PREFIX`) carrying the SAME id as the host span, so
 `profiler.xplane.engine_step_spans` / `join_engine_steps` line device
 captures up against host ``step[kind]`` AND ``train_step`` spans alike.
+`Tracer.launch` is the one place that enters it (the engine's step,
+`train_dispatch_span` and `hapi.Model.train_batch` all launch through it): it
+stamps ``time.monotonic_ns()`` immediately before (the span's ``launch_ns``), so
+every step is a pair (this tracer's clock, the capture's host clock) and
+the join fits ONE offset over all of them; the export carries the epoch
+(``otherData.epoch_ns``), so a dumped trace is enough to redo the fit.
 
 **Off by default, free when off**: training code asks `train_tracer()`
 for the process-wide tracer and gets None unless ``PADDLE_TPU_TRACE`` is
@@ -138,8 +144,25 @@ class Tracer:
     def step_annotation(self, step_id):
         """Name for the `jax.profiler.TraceAnnotation` wrapping this
         step's device dispatch — the join key between this host trace and
-        an xplane device capture (profiler.xplane.engine_step_spans)."""
+        an xplane device capture (profiler.xplane.engine_step_spans), and
+        one half of the step's clock pair: the annotation's start on the
+        capture's host clock against the ``launch_ns`` that `launch`
+        stamps on this tracer's clock just before entering it."""
         return f"{STEP_ANNOTATION_PREFIX}{step_id}"
+
+    @contextlib.contextmanager
+    def launch(self, step_id, args):
+        """Run a step's launch call under its annotation and write into
+        `args` (the step span's) ``launch_ns``: ``time.monotonic_ns()``
+        taken immediately before the annotation is entered.
+        `profiler.xplane.join_engine_steps` fits this tracer's clock to a
+        capture's from the pairs (``launch_ns``, annotation's start)."""
+        import jax
+
+        ann = jax.profiler.TraceAnnotation(self.step_annotation(step_id))
+        args["launch_ns"] = time.monotonic_ns()
+        with ann:
+            yield
 
     def phased_span(self, name, pid, tid, step_id, phases, phase_order,
                     args=None):
@@ -177,6 +200,9 @@ class Tracer:
                 "sample": self.sample,
                 "capacity": self.capacity,
                 "dropped_events": self.dropped,
+                # `ts` counts from here on time.monotonic(): with it a
+                # span and its `launch_ns` are on one clock
+                "epoch_ns": round(self.epoch * 1e9),
             },
         }
 
@@ -239,13 +265,13 @@ def train_dispatch_span(tracer, args=None):
     the body under the xplane join annotation, and records a ``train_step``
     span whose only phase is ``dispatch``. For producers (ShardedTrainStep,
     the pipelined GPT step) that hand back device arrays and never see the
-    caller's host sync. Yields the step id."""
-    import jax
-
+    caller's host sync; the span carries ``launch_ns`` as a serving
+    step's does (`Tracer.launch`). Yields the step id."""
     sid = tracer.next_step_id()
+    args = dict(args or ())
     t0 = time.monotonic()
     try:
-        with jax.profiler.TraceAnnotation(tracer.step_annotation(sid)):
+        with tracer.launch(sid, args):
             yield sid
     finally:
         tracer.record_train_step(sid, {"dispatch": (t0, time.monotonic())},

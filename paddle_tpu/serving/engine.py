@@ -117,6 +117,7 @@ pointer test per hook site.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import logging
@@ -1735,20 +1736,6 @@ class LLMEngine:
             "no_alias": ("swap_out",),
         }
 
-    def _annotation(self, step_id):
-        """While tracing, the device dispatch runs under a jax.profiler
-        TraceAnnotation named after the step id — the join key that lets
-        profiler.xplane.engine_step_spans line device captures up against
-        the host step timeline. A no-op context when tracing is off."""
-        if self.tracer is None:
-            import contextlib
-
-            return contextlib.nullcontext()
-        import jax
-
-        return jax.profiler.TraceAnnotation(
-            self.tracer.step_annotation(step_id))
-
     def _run_step(self, fn, packed, step_id=0, t_disp=0.0):
         """Hand the step's packed host input to the device and dispatch
         the unified step program; returns the DEVICE result array (the
@@ -1761,16 +1748,22 @@ class LLMEngine:
         host arrays handed over (one, on every step of every engine),
         ``h2d_bytes`` its size and ``h2d_ms`` the time from ``t_disp``
         (the start of the ``dispatch`` phase) to just before the program
-        is launched: that one transfer, so ``dispatch - h2d_ms`` is the
-        launch."""
+        is launched: that one transfer. The launch then runs under the
+        step's annotation (`Tracer.launch`: the join key to a device
+        capture and, with the ``launch_ns`` stamped before it, the step's
+        clock pair)."""
         import jax
 
         dev = jax.device_put(packed, self._rep)
-        if self.tracer is not None:
+        tr = self.tracer
+        if tr is None:
+            launch = contextlib.nullcontext()
+        else:
             self._span_args.update(
                 h2d_arrays=1, h2d_bytes=packed.nbytes,
                 h2d_ms=(time.monotonic() - t_disp) * 1e3)
-        with self._annotation(step_id):
+            launch = tr.launch(step_id, self._span_args)
+        with launch:
             out, *arenas, self._key = fn(
                 self._params, self._buffers, *self._arenas(),
                 self._lora_tables, dev, self._key)

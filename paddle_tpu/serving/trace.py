@@ -24,7 +24,14 @@ exactly those two views as Chrome/Perfetto trace events:
   append's writer copies back (``append_blocks``) and the hand-over to
   the device inside ``dispatch``: ``h2d_arrays`` (one: the step's packed
   host input), ``h2d_bytes`` (its size) and ``h2d_ms`` (that one
-  transfer: ``dispatch``'s start to just before the launch). A model
+  transfer: ``dispatch``'s start to just before the launch). Then
+  ``launch_ns`` (``time.monotonic_ns()`` taken immediately before the
+  step's annotation is entered: the monotonic half of the step's clock
+  pair, which `join_engine_steps` fits) and ``width`` (the width bucket of
+  the program that ran, beside ``slots``: the join holds steps of one
+  width to one program). A traced step runs the one ``np.asarray`` an
+  untraced one does; what ``sync`` is made of is read from a device
+  capture (device end -> the read's return), not from a second path. A model
   with expert layers and state a lane (models/nemotron_h.py) adds the
   counts its step program makes, which ride the step's one packed
   result: ``moe_pairs`` (picks of live tokens), ``moe_pairs_local``
@@ -49,8 +56,15 @@ Export: `chrome_trace()` returns the standard trace-event JSON object
 https://ui.perfetto.dev. Device-side correlation: while tracing, every
 device dispatch is wrapped in a ``jax.profiler.TraceAnnotation`` named
 ``paddle_tpu.step <id>`` carrying the SAME step id as the host ``step``
-span, so `profiler.xplane.engine_step_spans` / `join_engine_steps` can
-join host phases to device ops captured with `jax.profiler.trace`.
+span (`Tracer.launch`). The annotation spans only the launch CALL; with
+it `profiler.xplane.join_engine_steps` finds, in a `jax.profiler.trace`
+capture, the step program's RUN on the device (the event of the device
+plane's ``XLA Modules`` line that the step launched) and lays it on this
+tracer's clock, fitted from every step's (``launch_ns``, annotation's
+start): launch -> issue -> device start -> device end -> done -> the read
+returns, so the device's idle time between two runs splits into waiting
+to be heard (``readback``), for the host and to start (``launch``):
+`profiler.xplane.split_idle`, `print_step_timeline`.
 """
 from __future__ import annotations
 

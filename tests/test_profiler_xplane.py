@@ -309,12 +309,309 @@ def test_engine_step_spans_and_join():
             {"name": "dispatch", "ph": "X", "pid": 1, "tid": 0,
              "ts": 150.0, "dur": 100.0, "args": {"step": 0}},
         ]}
-        rows = xplane.join_engine_steps(chrome, path)
+        join = xplane.join_engine_steps(chrome, path)
+    rows = join.steps
     assert [r["step"] for r in rows] == [0, 7]
     assert rows[0]["kind"] == "decode"
-    assert rows[0]["capture_dur_us"] == pytest.approx(2000.0)
+    # the annotation is the launch CALL on the host, and is named so
+    assert rows[0]["launch_call_us"] == pytest.approx(2000.0)
     assert rows[0]["capture_plane"] == "/host:CPU"
-    assert rows[1]["capture_dur_us"] is None  # step 7 not captured
+    assert rows[1]["launch_call_us"] is None  # step 7 not captured
+    # a host-only capture places no run, and says why
+    assert rows[0]["device_us"] is None and rows[0]["device_start_us"] is None
+    assert "no device plane" in join.clocks[0]["why"]
+
+
+# -- the step's run on the device, on the tracer's clock (PR 38) -----------
+
+def _steps(n=8, period=5000.0, call=400.0, to_start=700.0, run=3000.0):
+    return [{"id": i, "launch": 1000.0 + period * i, "call": call,
+             "dev": (1000.0 + period * i + to_start,
+                     1000.0 + period * i + to_start + run)}
+            for i in range(n)]
+
+
+def _join(tmp_path, steps, records=None, **capture):
+    import _xplane_synth as synth
+    from paddle_tpu.profiler import xplane
+
+    path = synth.write(synth.step_capture(steps, **capture),
+                       tmp_path / "cap.xplane.pb")
+    if records is None:
+        records = synth.step_records(steps)
+    return xplane.join_engine_steps(records, path)
+
+
+def test_join_finds_each_steps_run_on_the_device(tmp_path):
+    """Every step gets the event of the device plane's `XLA Modules` line
+    that it launched, by `run_id` through the host's enqueue event."""
+    steps = _steps()
+    join = _join(tmp_path, steps)
+    clock = join.clocks[0]
+    assert clock["why"] is None
+    assert clock["steps"] == clock["pairs"] == len(steps)
+    for st, r in zip(steps, join.steps):
+        assert r["step"] == st["id"] and r["program"] == "jit_step(123)"
+        assert r["run_id"] == 100 + st["id"]
+        assert r["device_us"] == pytest.approx(3000.0)
+        assert r["launch_call_us"] == pytest.approx(400.0)
+        # one clock, the tracer's (offset 0 here): the whole chain in order
+        assert r["launch_us"] == pytest.approx(st["launch"])
+        assert r["enqueue_us"] == pytest.approx(st["dev"][0] - 15.0)
+        assert r["device_start_us"] == pytest.approx(st["dev"][0])
+        assert r["device_end_us"] == pytest.approx(st["dev"][1])
+        assert r["issue_us"] == pytest.approx(st["dev"][0] - 20.0)
+        assert r["done_us"] == pytest.approx(st["dev"][1] + 30.0)
+        assert r["read_us"] == pytest.approx(st["dev"][1] + 60.0)
+    assert clock["violations"] == 0 and clock["shift_us"] == 0.0
+    # the steps' mean period, by the device's own clock
+    assert clock["period_us"] == pytest.approx(5000.0)
+
+
+def test_join_without_run_ids_matches_nothing_and_says_why(tmp_path):
+    """There is no matching by order: a capture whose host events carry no
+    `run_id` gives the annotations' own spans and the reason."""
+    join = _join(tmp_path, _steps(), run_ids=False)
+    assert "carries a run_id" in join.clocks[0]["why"]
+    assert all(r["device_us"] is None for r in join.steps)
+    assert join.steps[0]["launch_call_us"] == pytest.approx(400.0)
+
+
+def test_join_keys_on_a_runs_enqueue_not_on_its_completion(tmp_path):
+    """What an overlapped loop produces: the completion event of step N,
+    which carries N's `run_id`, lands after annotation N+1 began and before
+    N+1's own enqueue. It names nobody: every step keeps its own run."""
+    steps = _steps()
+    for a, b in zip(steps, steps[1:]):
+        a["complete"] = b["launch"] + 50.0    # N+1 enqueues 685 us in
+    join = _join(tmp_path, steps)
+    assert join.clocks[0]["why"] is None
+    assert [r["run_id"] for r in join.steps] == \
+        [100 + st["id"] for st in steps]
+    assert len({r["run_id"] for r in join.steps}) == len(steps)
+
+
+def test_join_holds_steps_of_one_width_to_one_program(tmp_path):
+    """Another program's enqueue between a step's annotation and its own
+    (a swap, a prefix copy) would hand the step that run: the span's
+    `width` names the program, so the join sees it and places nothing."""
+    steps = _steps()
+    steps[4]["program"] = "jit_swap_out(9)"
+    join = _join(tmp_path, steps)
+    why = join.clocks[0]["why"]
+    assert "step 4 of width 1" in why and "jit_swap_out(9)" in why
+    assert all(r["device_us"] is None for r in join.steps)
+    # a wider step runs a program of its own, and that is no fault
+    steps[4]["width"] = 128
+    join = _join(tmp_path, steps)
+    assert join.clocks[0]["why"] is None
+    assert join.steps[4]["program"] == "jit_swap_out(9)"
+
+
+def test_join_matches_by_run_id_not_by_position(tmp_path):
+    """A run of another program between two steps (a copy-on-write, a
+    swap) and steps cut off at the capture's two ends do not move the
+    match when the host's enqueue events carry the run's id."""
+    import _xplane_synth as synth
+    from paddle_tpu.profiler import xplane
+
+    steps = _steps(6)
+    xs = synth.step_capture(steps)
+    dev = next(p for p in xs.planes if p.name == "/device:TPU:0")
+    modules = next(ln for ln in dev.lines if ln.name == "XLA Modules")
+    stray = modules.events.add()        # someone else's program, mid-way
+    stray.CopyFrom(modules.events[2])
+    stray.offset_ps += 3_500_000_000
+    del stray.stats[:]
+    del modules.events[0]               # the first step's run: not captured
+    del modules.events[4]               # nor the last one's (index moved)
+    path = synth.write(xs, tmp_path / "cap.xplane.pb")
+    join = xplane.join_engine_steps(synth.step_records(steps), path)
+    clock = join.clocks[0]
+    assert clock["why"] is None
+    assert [r["run_id"] for r in join.steps] == \
+        [None, 101, 102, 103, 104, None]
+    assert clock["steps"] == 4
+
+
+@pytest.mark.parametrize("missing,says", [
+    ((3,), "step 3 lies between joined steps"),
+    ((0, 1, 2, 3, 4, 5, 6), "no step's run_id is on the device's line"),
+])
+def test_join_with_a_missing_run_returns_nothing_and_says_why(
+        tmp_path, missing, says):
+    import _xplane_synth as synth
+    from paddle_tpu.profiler import xplane
+
+    steps = _steps()
+    xs = synth.step_capture(steps)
+    dev = next(p for p in xs.planes if p.name == "/device:TPU:0")
+    modules = next(ln for ln in dev.lines if ln.name == "XLA Modules")
+    for i in sorted(missing, reverse=True):     # not on the device's line
+        del modules.events[i]
+    if len(missing) > 1:                        # the one left: a stranger's
+        del modules.events[0].stats[:]
+    path = synth.write(xs, tmp_path / "cap.xplane.pb")
+    join = xplane.join_engine_steps(synth.step_records(steps), path)
+    assert says in join.clocks[0]["why"]
+    assert all(r["device_us"] is None and r["device_start_us"] is None
+               for r in join.steps)
+    # the annotation's own span is still there
+    assert join.steps[0]["launch_call_us"] == pytest.approx(400.0)
+
+
+def test_join_fits_one_clock_from_every_steps_pair(tmp_path):
+    """The offset is the median of (annotation's start - `launch_ns`), the
+    fit's error their quartile distance; every stamp comes back on the
+    tracer's clock."""
+    import statistics
+
+    import _xplane_synth as synth
+
+    steps = _steps()
+    jitter = (0.0, 2.0, -2.0, 4.0, -4.0, 1.0, -1.0, 30.0)   # one outlier
+    records = synth.step_records(steps, offset_us=123456.0, jitter_us=jitter)
+    join = _join(tmp_path, steps, records=records)
+    clock = join.clocks[0]
+    # launch_ns = annotation - offset + jitter
+    diffs = [123456.0 - j for j in jitter]
+    assert clock["offset_ns"] == pytest.approx(
+        statistics.median(diffs) * 1e3)
+    q1, _, q3 = statistics.quantiles(diffs, n=4)
+    assert clock["fit_us"] == pytest.approx(q3 - q1)
+    assert clock["error_us"] == clock["fit_us"]          # nothing shifted
+    mid = statistics.median(diffs)
+    for st, r in zip(steps, join.steps):
+        assert r["device_start_us"] == pytest.approx(st["dev"][0] - mid)
+        assert r["read_us"] == pytest.approx(st["dev"][1] + 60.0 - 123456.0)
+
+
+def test_join_counts_a_planted_causality_violation(tmp_path):
+    """One step whose run ends after its read returned: counted, with its
+    margin; a shift that mends it is found between the other steps'
+    bounds."""
+    steps = _steps()
+    steps[5]["done"] = steps[5]["dev"][1] + 500.0
+    steps[5]["read"] = steps[5]["dev"][1] - 8.0      # heard before the end
+    join = _join(tmp_path, steps)
+    clock = join.clocks[0]
+    assert clock["violations"] == 1
+    assert clock["worst_us"] == pytest.approx(8.0)
+    # every step is causal for shifts in [-15 (the enqueue, 15 us before
+    # the start), -8]: the middle is taken, the width added
+    assert clock["causal_width_us"] == pytest.approx(7.0)
+    assert clock["shift_us"] == pytest.approx(-11.5)
+    assert clock["error_us"] == pytest.approx(clock["fit_us"] + 7.0)
+    # the same doubt in the shares' unit: points of the 5 ms period
+    assert clock["error_points"] == pytest.approx(
+        100 * clock["error_us"] / 5000.0)
+
+
+def test_join_shifts_a_skewed_device_plane_into_the_causal_interval(tmp_path):
+    """What a TPU v5e capture shows: every run stamped 1.15 ms early
+    against the host plane. Every step violates; the plane is shifted to
+    the middle of the interval that makes all of them causal."""
+    steps = _steps()
+    join = _join(tmp_path, steps, skew_us=-1150.0)
+    clock = join.clocks[0]
+    assert clock["violations"] == len(steps)
+    assert clock["worst_us"] == pytest.approx(1150.0 - 15.0)
+    # enqueue 15 us before the true start, Done 30 us after the true end
+    assert clock["causal_width_us"] == pytest.approx(45.0)
+    assert clock["shift_us"] == pytest.approx(1150.0 + (30.0 - 15.0) / 2)
+    for st, r in zip(steps, join.steps):
+        assert r["device_start_us"] == pytest.approx(st["dev"][0] + 7.5)
+        assert r["enqueue_us"] <= r["device_start_us"]
+        assert r["device_end_us"] <= r["done_us"] <= r["read_us"]
+
+
+def test_join_places_nothing_where_one_side_only_is_bounded(tmp_path):
+    """A skewed device plane with nothing that follows a run's end (no
+    Done, no completion event, no `sync` to end): there is no interval to
+    take the middle of, so nothing is placed and no share is read."""
+    import _xplane_synth as synth
+    from paddle_tpu.profiler import xplane
+
+    steps = _steps()
+    for st in steps:
+        st["done"] = None
+    records = synth.step_records(steps)
+    for r in records:
+        del r["phases"]["sync"]
+    join = _join(tmp_path, steps, records=records, skew_us=-1150.0)
+    clock = join.clocks[0]
+    assert "one side only" in clock["why"] and clock["error_us"] is None
+    assert clock["violations"] == len(steps)
+    assert all(r["device_us"] == pytest.approx(3000.0) for r in join.steps)
+    assert all(r["device_start_us"] is None for r in join.steps)
+    assert xplane.split_idle(
+        [r for r in join.steps if r["device_start_us"] is not None]) == []
+
+
+def test_join_refuses_violations_no_one_shift_mends(tmp_path):
+    steps = _steps()
+    steps[2]["read"] = steps[2]["dev"][1] - 100.0    # needs a shift < -100
+    steps[2]["done"] = steps[2]["dev"][1] + 500.0
+    join = _join(tmp_path, steps)                    # enqueue: shift >= -15
+    clock = join.clocks[0]
+    assert "no one shift" in clock["why"] and clock["causal_width_us"] < 0
+    # matched, not placed
+    assert all(r["device_us"] == pytest.approx(3000.0) for r in join.steps)
+    assert all(r["device_start_us"] is None for r in join.steps)
+
+
+def test_split_idle_cuts_a_period_at_the_read_and_the_next_launch(tmp_path):
+    from paddle_tpu.profiler import xplane
+
+    join = _join(tmp_path, _steps())
+    parts = xplane.split_idle(join.steps)
+    assert len(parts) == len(join.steps) - 1
+    for p in parts:
+        # period 5,000: run 3,000; heard 60 after the end; the next launch
+        # 700 before the next start
+        assert p["run"] == pytest.approx(3000.0)
+        assert p["readback"] == pytest.approx(60.0)
+        assert p["launch"] == pytest.approx(700.0)
+        assert p["host"] == pytest.approx(5000.0 - 3000.0 - 60.0 - 700.0)
+    # a loop that launches the next step before it has read this one: the
+    # parts stay inside the gap and still sum to it
+    a, b = dict(join.steps[0]), dict(join.steps[1])
+    b["launch_us"] = a["device_end_us"] - 1000.0
+    (p,) = xplane.split_idle([a, b])
+    gap = b["device_start_us"] - a["device_end_us"]
+    assert p["readback"] == pytest.approx(60.0) and p["host"] == 0.0
+    assert p["readback"] + p["host"] + p["launch"] == pytest.approx(gap)
+
+
+def test_step_timeline_cli_prints_the_three_parts(tmp_path, capsys):
+    """`python -m paddle_tpu.profiler.xplane <capture> --steps <dump>`:
+    what an operator runs on a capture of a live server and its
+    /debug/trace."""
+    import json
+
+    import _xplane_synth as synth
+    from paddle_tpu.profiler import xplane
+
+    steps = _steps()
+    path = synth.write(synth.step_capture(steps), tmp_path / "cap.xplane.pb")
+    events = []
+    for r in synth.step_records(steps):
+        events.append({"name": "step[decode]", "ph": "X", "pid": 1,
+                       "tid": 0, "ts": r["start"] * 1e6,
+                       "dur": (r["end"] - r["start"]) * 1e6,
+                       "args": {**r["args"], "kind": "decode"}})
+        for name, (s, e) in r["phases"].items():
+            events.append({"name": name, "ph": "X", "pid": 1, "tid": 0,
+                           "ts": s * 1e6, "dur": (e - s) * 1e6,
+                           "args": {"step": r["id"]}})
+    dump = tmp_path / "trace.json"
+    dump.write_text(json.dumps({"traceEvents": events,
+                                "otherData": {"epoch_ns": 0}}))
+    assert xplane.main([path, "--steps", str(dump)]) == 0
+    out = capsys.readouterr().out
+    assert "8 steps matched, 8 clock pairs" in out
+    for part in ("run", "readback", "host", "launch", "readback + launch"):
+        assert f"  {part}" in out
 
 
 def test_join_on_real_traced_serve():
@@ -346,12 +643,13 @@ def test_join_on_real_traced_serve():
                             max_new_tokens=4)
         spans = xplane.engine_step_spans(td)
         assert spans, "no step annotations reached the capture"
-        rows = xplane.join_engine_steps(engine.tracer.chrome_trace(), td)
-    joined = [r for r in rows if r["capture_dur_us"] is not None]
+        rows = xplane.join_engine_steps(engine.tracer.chrome_trace(),
+                                        td).steps
+    joined = [r for r in rows if r["launch_call_us"] is not None]
     assert joined, "no host step span joined to the capture"
     for r in joined:
         assert r["step"] in spans
-        assert r["capture_dur_us"] > 0
+        assert r["launch_call_us"] > 0
         # the annotation wraps only the dispatch, so it can never exceed
         # the full host step span by more than measurement jitter
         assert r["host_dur_us"] > 0

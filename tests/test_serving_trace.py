@@ -217,6 +217,90 @@ def test_disabled_tracing_is_byte_identical_and_absent(model, monkeypatch):
     assert len(on.tracer.events) > 0
 
 
+class _ResultSpy:
+    """Stands in for the step's device result: counts the host's reads of
+    it (`np.asarray` goes through `__array__`) and its waits."""
+
+    def __init__(self, array, calls):
+        self._array, self._calls = array, calls
+
+    def copy_to_host_async(self):
+        self._calls["copy_to_host_async"] += 1
+        return self._array.copy_to_host_async()
+
+    def block_until_ready(self):
+        self._calls["block_until_ready"] += 1
+        return self._array.block_until_ready()
+
+    def __array__(self, dtype=None, copy=None):
+        self._calls["asarray"] += 1
+        return np.asarray(self._array, dtype=dtype)
+
+
+def _spy_on_result(engine):
+    calls = {"steps": 0, "asarray": 0, "block_until_ready": 0,
+             "copy_to_host_async": 0}
+    run_step = engine._run_step
+
+    def spied(*args, **kwargs):
+        calls["steps"] += 1
+        return _ResultSpy(run_step(*args, **kwargs), calls)
+
+    engine._run_step = spied
+    return calls
+
+
+@pytest.mark.parametrize("trace", [None, 1.0], ids=["untraced", "traced"])
+def test_step_reads_its_result_once_and_never_waits(
+        model, monkeypatch, trace):
+    """A step's one host sync is one `np.asarray` of the packed result and
+    nothing calls `block_until_ready`, with tracing off AND on: a traced
+    step times the `sync` production runs, not a second path; what `sync`
+    is made of is read from a device capture."""
+    monkeypatch.delenv("PADDLE_TPU_TRACE", raising=False)
+    prompts = _prompts((13, 6, 20), seed=3)
+    want = _engine(model, prefill_chunk=8).generate(prompts, max_new_tokens=6)
+    engine = _engine(model, prefill_chunk=8, trace=trace)
+    calls = _spy_on_result(engine)
+    assert engine.generate(prompts, max_new_tokens=6) == want
+    assert calls["steps"] > 0
+    assert calls["asarray"] == calls["steps"]
+    assert calls["block_until_ready"] == calls["copy_to_host_async"] == 0
+
+
+def test_step_span_says_when_it_launched_and_which_program_ran(model):
+    """The args the device-timeline join reads (`profiler/xplane.py
+    join_engine_steps`): `launch_ns`, the monotonic half of the step's
+    clock pair, stamped inside `dispatch` after the transfer and just
+    before the annotation; `width` beside `slots`, which names the program
+    that ran. Every arg of the hand-over has a reader: none times `sync`'s
+    parts, which a traced step does not split."""
+    engine = _engine(model, prefill_chunk=8, trace=1.0)
+    engine.generate(_prompts((13, 6), seed=4), max_new_tokens=5)
+    trace = engine.tracer.chrome_trace()
+    epoch_ns = trace["otherData"]["epoch_ns"]
+    steps = [e for e in trace["traceEvents"]
+             if e["ph"] == "X" and e["name"].startswith("step[")]
+    assert {e["args"]["kind"] for e in steps} == {"mixed", "decode"}
+    phases = {(e["args"]["step"], e["name"]): e
+              for e in trace["traceEvents"]
+              if e["ph"] == "X" and e["pid"] == PID_ENGINE
+              and e["tid"] == TID_STEPS and not e["name"].startswith("step[")}
+    slack = 1.0     # us: the spans' stamps are rounded to the nanosecond
+    for e in steps:
+        a, sid = e["args"], e["args"]["step"]
+        for key in ("launch_ns", "h2d_ms", "width", "slots"):
+            assert key in a, (key, a)
+        assert not {"launch_ms", "wait_ms", "d2h_ms", "d2h_bytes"} & set(a)
+        dispatch = phases[sid, "dispatch"]
+        at_us = (a["launch_ns"] - epoch_ns) / 1e3
+        assert dispatch["ts"] + a["h2d_ms"] * 1e3 - slack <= at_us
+        assert at_us <= dispatch["ts"] + dispatch["dur"] + slack
+        assert a["width"] in engine.width_buckets
+        assert a["slots"] == sum(n * w for n, w in
+                                 engine.step_groups(a["width"]))
+
+
 def test_trace_env_knob(model, monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_TRACE", "1")
     assert _engine(model).tracer is not None

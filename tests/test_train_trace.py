@@ -102,9 +102,18 @@ def test_train_trace_schema_canary():
     steps = [e for e in trace["traceEvents"] if e["name"] == "train_step"]
     assert len(steps) == 4                       # 32 samples / batch 8
     for ev in steps:
-        for key in ("step", "batch", "batch_size", "loss"):
+        for key in ("step", "batch", "batch_size", "loss", "launch_ns"):
             assert key in ev["args"], ev["args"]
         assert ev["args"]["batch_size"] == 8
+    # `Model.fit` launches through `Tracer.launch` as every stack does: the
+    # clock pair's monotonic half lies inside the step's `dispatch` phase
+    epoch_ns = trace["otherData"]["epoch_ns"]
+    dispatch = {e["args"]["step"]: e for e in trace["traceEvents"]
+                if e.get("ph") == "X" and e["name"] == "dispatch"}
+    for ev in steps:
+        d = dispatch[ev["args"]["step"]]
+        at_us = (ev["args"]["launch_ns"] - epoch_ns) / 1e3
+        assert d["ts"] - 1.0 <= at_us <= d["ts"] + d["dur"] + 1.0
     # step ids are consecutive and spans carry monotonically ordered steps
     assert [e["args"]["batch"] for e in steps] == [0, 1, 2, 3]
 
@@ -181,6 +190,7 @@ def test_standalone_train_batch_records_span():
     # standalone: no loader, no callback list — the three core phases only
     assert {"shard", "dispatch", "sync"} <= names
     assert "data" not in names
+    assert spans[0]["args"]["launch_ns"] > 0
 
 
 def test_train_dispatch_span_unit():
@@ -194,6 +204,51 @@ def test_train_dispatch_span_unit():
     assert span["args"]["source"] == "unit"
     child = next(e for e in evs if e["name"] == "dispatch")
     assert child["args"]["step"] == sid
+    # the clock pair's monotonic half, stamped inside `dispatch` just
+    # before the annotation, as a serving step's is (`Tracer.launch`)
+    epoch_ns = tr.chrome_trace()["otherData"]["epoch_ns"]
+    at_us = (span["args"]["launch_ns"] - epoch_ns) / 1e3
+    assert child["ts"] <= at_us <= child["ts"] + child["dur"] + 1.0
+
+
+def test_dispatch_spans_join_to_their_runs_on_one_clock(tmp_path):
+    """One join serves both stacks: `train_step` spans (kind None, no
+    `sync` phase) find their program's run on a capture's device plane by
+    the same annotation and `launch_ns`. With no read to bound the run's
+    end, the runtime's Done does."""
+    import _xplane_synth as synth
+
+    from paddle_tpu.profiler import xplane
+
+    tr = TrainTracer(capacity=256)
+    for _ in range(5):
+        with tracing.train_dispatch_span(tr):
+            pass
+    records = xplane.step_span_records(tr.chrome_trace())
+    assert [r["kind"] for r in records] == [None] * 5
+    assert all(set(r["phases"]) == {"dispatch"} for r in records)
+    for i, r in enumerate(records):     # empty bodies: space them 5 ms apart
+        r["args"]["launch_ns"] += i * 5_000_000
+    # a capture whose host clock reads 7 s more than this tracer's, each
+    # run 300 us after its launch, 2 ms long
+    steps = [{"id": r["id"], "call": 100.0,
+              "launch": r["args"]["launch_ns"] / 1e3 + 7e6,
+              "dev": (r["args"]["launch_ns"] / 1e3 + 7e6 + 300.0,
+                      r["args"]["launch_ns"] / 1e3 + 7e6 + 2300.0)}
+             for r in records]
+    path = synth.write(synth.step_capture(steps, skew_us=-900.0),
+                       tmp_path / "cap.xplane.pb")
+    join = xplane.join_engine_steps(records, path)
+    clock = join.clocks[0]
+    assert clock["why"] is None
+    assert clock["offset_ns"] == pytest.approx(7e9, abs=1e3)
+    assert clock["violations"] == 5
+    assert clock["shift_us"] == pytest.approx(900.0 + 7.5, abs=0.01)
+    for r, rec in zip(join.steps, records):
+        assert r["kind"] is None and r["read_us"] is None
+        assert r["device_us"] == pytest.approx(2000.0)
+        assert r["device_start_us"] - r["launch_us"] == \
+            pytest.approx(307.5, abs=0.01)
 
 
 def test_instrumented_step_delegates_and_traces():
@@ -236,13 +291,13 @@ def test_training_capture_joins_by_step_id(tmp_path):
         model.fit(ds, epochs=1, batch_size=8, verbose=0, shuffle=False)
     spans = xplane.engine_step_spans(str(tmp_path))
     assert spans, "no step annotations reached the capture"
-    rows = xplane.join_engine_steps(tr.chrome_trace(), str(tmp_path))
+    rows = xplane.join_engine_steps(tr.chrome_trace(), str(tmp_path)).steps
     assert rows and all(r["kind"] is None for r in rows)  # training spans
-    joined = [r for r in rows if r["capture_dur_us"] is not None]
+    joined = [r for r in rows if r["launch_call_us"] is not None]
     assert joined, "no train_step span joined to the capture"
     for r in joined:
         assert r["step"] in spans
-        assert r["capture_dur_us"] > 0 and r["host_dur_us"] > 0
+        assert r["launch_call_us"] > 0 and r["host_dur_us"] > 0
 
 
 # -- TrainMonitor ------------------------------------------------------------
