@@ -7,8 +7,9 @@ full width of models the repo ships, with seeded random weights:
 
 - kernels: the ragged paged-attention kernel and the append's block writer
   (bf16 and int8 arenas, every width the serve phase compiles) and the
-  three flash kernels (forward, dK/dV, dQ), compiled (not interpreted),
-  against their XLA references, the writer bit for bit;
+  three flash kernels (forward, dK/dV, dQ), and the routed experts'
+  grouped matmul at the hybrid configuration's shapes, compiled (not
+  interpreted), against their XLA references, the writer bit for bit;
 - serve:   `gpt_1p3b` in bf16 behind `ServingServer` on 127.0.0.1:0, nine
   requests over `/v1/completions`, one of them streamed (SSE);
 - train:   `gpt_small` in bf16, AdamW, five `make_sharded_train_step` steps;
@@ -360,7 +361,53 @@ def check_flash_kernels(key, *, batch, seq, heads, head_dim):
     return worst
 
 
-def kernel_phase(*, ragged_shapes, widths, flash_shapes, seed=0):
+def check_grouped_matmul(key, *, rows, inner, outer, groups, hit):
+    """The routed experts' grouped matmul through its dispatch
+    (ops/pallas/grouped_matmul.py) against `jax.lax.ragged_dot` in float32
+    at `highest` over the same bfloat16 values: `hit` of `groups` groups
+    hold a quarter of the `rows` rows between them, unevenly, and the rows
+    behind them hold NaN, which nothing may read. Both multiply exact
+    products of bfloat16 and sum in float32, so they differ in the order
+    of `inner` sums: 1e-3 of the result's scale holds with room, and a
+    wrong group, tile or mask moves values by the scale itself. Returns the
+    worst error over that scale."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    kx, kw, kh, ks = jax.random.split(key, 4)
+    x = jax.random.normal(kx, (rows, inner), jnp.bfloat16)
+    w = (0.05 * jax.random.normal(kw, (groups, inner, outer))).astype(
+        jnp.bfloat16)
+    live = np.sort(np.asarray(jax.random.permutation(kh, groups))[:hit])
+    share = np.asarray(jax.random.dirichlet(ks, jnp.full((hit,), 0.5)))
+    sizes = np.zeros(groups, np.int32)
+    sizes[live] = 1 + np.floor(share * max(rows // 4 - hit, 0)).astype(
+        np.int32)
+    total = int(sizes.sum())
+    require(0 < total < rows, f"grouped matmul: {total} of {rows} rows")
+    x = x.at[total:].set(jnp.nan)
+    got = np.asarray(jax.jit(grouped_matmul)(x, w, jnp.asarray(sizes)))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(lambda x, w, s: jax.lax.ragged_dot(
+            x, w, s, preferred_element_type=jnp.float32))(
+                x[:total].astype(jnp.float32), w.astype(jnp.float32),
+                jnp.asarray(sizes)))
+    what = f"grouped matmul {rows} x {inner} x {outer}, {hit} of {groups}"
+    require(got.shape == (rows, outer) and got.dtype == np.float32,
+            f"{what}: {got.dtype}{got.shape}")
+    require(not got[total:].any(),
+            f"{what}: the rows behind the groups are not zeros")
+    scale = float(np.abs(want).max())
+    err = float(np.abs(got[:total] - want).max()) / scale
+    require(err < 1e-3, f"{what}: off by {err:.3g} of the result's scale")
+    return err
+
+
+def kernel_phase(*, ragged_shapes, widths, flash_shapes, grouped_shapes=(),
+                 seed=0):
     """Every kernel variant the later phases compile, at their shapes."""
     import jax
 
@@ -399,8 +446,13 @@ def kernel_phase(*, ragged_shapes, widths, flash_shapes, seed=0):
         worst = check_flash_kernels(sub, **shape)
         log(f"flash kernels {shape}: worst |err| "
             + ", ".join(f"{k} {v:.3g}" for k, v in worst.items()))
+    for shape in grouped_shapes:
+        key, sub = jax.random.split(key)
+        err = check_grouped_matmul(sub, **shape)
+        log(f"grouped matmul {shape}: worst |err| {err:.3g} of the scale")
     require(not _backend.XLA_FALLBACKS,
-            f"attention took the XLA path: {dict(_backend.XLA_FALLBACKS)}")
+            f"a kernel's dispatch took the XLA path: "
+            f"{dict(_backend.XLA_FALLBACKS)}")
 
 
 # ---------------------------------------------------------------------------
@@ -719,9 +771,15 @@ def run(report, clock):
     if four:
         ragged.append(dict(heads=4, **serve_shape))
         flash.append(dict(batch=1, seq=2048, heads=16, head_dim=128))
+    # the hybrid configuration's routed experts (nemotron3s-ep4-serve): a
+    # decode step's 704 pair rows over two thirds of 128 held experts, a
+    # chunk-carrying step's 3,520 over all of them, latent 1,024 <-> 2,688
+    grouped = [dict(rows=r, inner=k, outer=n, groups=128, hit=h)
+               for r, h in ((704, 85), (3520, 128))
+               for k, n in ((1024, 2688), (2688, 1024))]
     with phase("kernels", clock, report):
         kernel_phase(ragged_shapes=ragged, widths=(1, 5, 128),
-                     flash_shapes=flash)
+                     flash_shapes=flash, grouped_shapes=grouped)
 
     # nine requests: eight at once, mixed lengths, one longer than a
     # prefill chunk (128); then the first again. No depth cut: 24 layers.
@@ -746,7 +804,8 @@ def run(report, clock):
             train_phase(_bf16(gpt_1p3b()), degrees={"dp": 4}, batch=4,
                         seq=2048, zero_stage=2, remat=True)
     require(not _backend.XLA_FALLBACKS,
-            f"attention took the XLA path: {dict(_backend.XLA_FALLBACKS)}")
+            f"a kernel's dispatch took the XLA path: "
+            f"{dict(_backend.XLA_FALLBACKS)}")
 
 
 def main():

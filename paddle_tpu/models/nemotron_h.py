@@ -39,8 +39,10 @@ on to the next layer. `W_down`, `W_up`, the router and the shared expert are
 computed whole, as every chip of the group would. No code stands in for the
 other chips. The expert layer is dropless: every held pick of a live token
 is computed whatever the imbalance (tokens sorted by held expert, a grouped
-product `jax.lax.ragged_dot` over the stacked expert weights); slots that
-carry no token (past a row's live tokens, idle lanes) are not dispatched.
+product over the stacked expert weights: `ops/pallas/grouped_matmul.py`, the
+kernel `grouped_matmul` where the kernels are on and the expert's widths are
+multiples of 128, `jax.lax.ragged_dot` elsewhere); slots that carry no token
+(past a row's live tokens, idle lanes) are not dispatched.
 
 SERVING (`caches` a paged state, serving/block_pool.py). Two kinds of state:
 the attention layers' keys and values in the paged arena (`kv_slabs` = the
@@ -85,6 +87,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn import initializer as I
+from ..ops.pallas.grouped_matmul import grouped_matmul
 from .looped import _Gain, _Matrix, causal_attention, rms_norm
 
 # the residual stream's type: float32 under bf16 weights, as models/looped.py
@@ -363,11 +366,8 @@ class LatentMoE(nn.Layer):
             bounds = jnp.searchsorted(sorted_key, jnp.arange(held + 1))
             sizes = (bounds[1:] - bounds[:-1]).astype(jnp.int32)
             x = u[order // k]                             # [T k, latent]
-            hidden = jax.lax.ragged_dot(
-                x, w1, sizes, preferred_element_type=jnp.float32)
-            y = jax.lax.ragged_dot(
-                _relu2(hidden).astype(w2.dtype), w2, sizes,
-                preferred_element_type=jnp.float32)
+            hidden = grouped_matmul(x, w1, sizes)
+            y = grouped_matmul(_relu2(hidden).astype(w2.dtype), w2, sizes)
             # back to (token, pick) order; rows outside the groups hold
             # nothing that is read
             back = jnp.zeros_like(order).at[order].set(

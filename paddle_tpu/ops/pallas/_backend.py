@@ -1,5 +1,5 @@
-"""Shared backend gate for the Pallas kernels (flash, ragged paged attn and
-the paged arena's append).
+"""Shared backend gate for the Pallas kernels (flash, ragged paged attn, the
+paged arena's append and the routed experts' grouped matmul).
 
 One policy, two env knobs, checked in this order:
 

@@ -34,6 +34,32 @@ def test_kernel_phase_interpreted():
     )
 
 
+GROUPED = [dict(rows=48, inner=128, outer=256, groups=8, hit=5),
+           dict(rows=48, inner=256, outer=128, groups=8, hit=8)]
+
+
+def test_kernel_phase_grouped_matmul_interpreted():
+    chip_smoke.kernel_phase(ragged_shapes=[], widths=(), flash_shapes=[],
+                            grouped_shapes=GROUPED)
+
+
+def test_kernel_phase_catches_a_wrong_grouped_matmul(monkeypatch):
+    """A walk that lays a group's rows one row off fails the phase (at a
+    shape of its own: a traced program is not traced again)."""
+    from paddle_tpu.ops.pallas import grouped_matmul as module
+
+    real = module._visits
+
+    def shifted(sizes, rows, tm):
+        off, *rest = real(sizes, rows, tm)
+        return (off + 1, *rest)
+
+    monkeypatch.setattr(module, "_visits", shifted)
+    with pytest.raises(chip_smoke.SmokeFailure, match="grouped matmul"):
+        chip_smoke.kernel_phase(ragged_shapes=[], widths=(), flash_shapes=[],
+                                grouped_shapes=[dict(GROUPED[0], rows=64)])
+
+
 def test_kernel_phase_catches_a_wrong_kernel(monkeypatch):
     """The comparison has teeth: a kernel that ignores the int8 scales
     (what a dropped sidecar would do) fails the phase."""

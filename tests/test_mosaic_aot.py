@@ -216,11 +216,30 @@ for W in engine.width_buckets:
     compile_for_v5e(engine._get_step_fn(B, W), *step_args(engine, W),
                     case=f"looped-step-w{W}")
 
+# the routed experts' grouped matmul alone, at the hybrid cell's four shapes
+# (nemotron3s-ep4-serve: a decode step's 704 pair rows and a chunk-carrying
+# step's 3,520 through `[1024, 2688]` and back, 128 held experts), with the
+# tiles the kernel's file chooses: a whole expert matrix a block
+from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+for rows in (704, 3520):
+    for K, N in ((1024, 2688), (2688, 1024)):
+        case = f"grouped-matmul-m{rows}-{K}x{N}"
+        assert compile_for_v5e(
+            gm.grouped_matmul_kernel, sds((rows, K), "bfloat16"),
+            sds((128, K, N), "bfloat16"), sds((128,), "int32"),
+            case=case) == 1, case
+
 # the hybrid block (models/nemotron_h.py): one Mamba, one attention and one
 # expert layer; the arena has the model's 2 K/V heads under 4 query heads,
-# the pool's lane state rides beside it, and the grouped product of the
-# expert layer (`jax.lax.ragged_dot`) goes through the TPU compiler
+# the pool's lane state rides beside it, and the expert layer's two grouped
+# products (latent 128, expert 256: widths the kernel takes) are
+# `grouped_matmul` kernels (the dispatch asks the default backend, the CPU
+# here, so its gate is opened by hand as the attention's is above)
 from paddle_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+
+gm.use_pallas = lambda: True
+gm.interpret_mode = lambda: False
 
 engine = LLMEngine(
     NemotronH(NemotronHConfig(
@@ -300,19 +319,26 @@ EXPECTED = {
     # grouped-query attention: 32 query heads over 2 K/V heads of 128
     **{f"ragged-gqa-w{w}": {"ragged_paged_attention": 1} for w in (1, 128)},
     **{f"append-gqa-w{w}": {"paged_kv_append": 1} for w in (1, 128)},
+    # the routed experts' grouped matmul at the hybrid cell's shapes
+    **{f"grouped-matmul-m{m}-{k}x{n}": {"grouped_matmul": 1}
+       for m in (704, 3520) for k, n in ((1024, 2688), (2688, 1024))},
 }
 
 
 def test_hybrid_step_programs_hold_one_kernel_pair_a_group(aot):
     """The hybrid block's one attention layer: one writer call and one
-    ragged call for each group of rows a program holds, under the names the
-    readers match; the other custom calls of the program are the compiler's
-    own (the expert layer's grouped products, the router's top-k)."""
+    ragged call for each group of rows a program holds, and its one expert
+    layer's two grouped products as `grouped_matmul` kernels over the
+    program's whole token axis, under the names the readers match; the
+    other custom calls of the program are the compiler's own (the router's
+    top-k), and none of them a `ragged-dot`."""
     names = _facts(aot, "KERNEL_NAMES")
     for w, groups in ((1, 1), (32, 2)):
         got = names[f"hybrid-step-w{w}"]
         assert got.get("paged_kv_append") == groups, got
         assert got.get("ragged_paged_attention") == groups, got
+        assert got.get("grouped_matmul") == 2, got
+        assert not any("ragged-dot" in name for name in got), got
 
 
 @pytest.fixture(scope="module")

@@ -196,6 +196,37 @@ def test_a_grouped_steps_logits_are_the_references():
         assert _worst(served, rid, prompt) < TOL
 
 
+def test_the_grouped_matmul_kernel_inside_the_step_serves_the_xla_paths_tokens(
+        monkeypatch):
+    """Expert widths the kernel takes (latent 128, expert 256) and the
+    interpreter forced: both step programs hold the routed experts' two
+    products as `grouped_matmul` kernels (`ops/pallas/grouped_matmul.py`),
+    which zero the rows behind the groups where `jax.lax.ragged_dot` leaves
+    what it leaves. The wave's tokens, greedy and sampled, are those of the
+    same engine on the XLA path."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    wave = wave_of(_prompts([n for _, n, _ in WAVE], seed=7))
+    sizes = dict(experts_held=4, experts_from=4, moe_latent_size=128,
+                 moe_intermediate_size=256)
+    calls, kernel = [], gm.grouped_matmul_kernel
+    monkeypatch.setattr(
+        gm, "grouped_matmul_kernel",
+        lambda x, w, s, **kw: calls.append(w.shape) or kernel(x, w, s, **kw))
+    want = [serve(LLMEngine(build(**sizes), **GROUPED), wave, **knobs)[0]
+            for knobs in (dict(temperature=0.0),
+                          dict(temperature=0.8, top_k=20, top_p=0.95))]
+    assert not calls                      # off the chip: the XLA path
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS_INTERPRET", "1")
+    got = [serve(LLMEngine(build(**sizes), **GROUPED), wave, **knobs)[0]
+           for knobs in (dict(temperature=0.0),
+                         dict(temperature=0.8, top_k=20, top_p=0.95))]
+    assert got == want
+    # two expert layers, two products each, traced once a program (widths 1
+    # and 8), for each of the two engines
+    assert calls == [(4, 128, 256), (4, 256, 128)] * 8
+
+
 def test_a_third_chunk_row_waits_and_a_wider_budget_has_a_row_a_lane():
     prompts = _prompts((12, 11, 10, 9), seed=6)
     burst = [(0, p, 3) for p in prompts]
@@ -344,7 +375,9 @@ def test_the_benchmark_has_the_configuration_and_its_cell():
     assert sorted(mine) == sorted([
         "step.hybrid_mfu", "hybrid.decode_stream_share",
         "moe.local_pair_share", "moe.experts_hit_share",
-        "moe.load_max_over_mean", "pool.state_hbm_share"])
+        "moe.load_max_over_mean", "pool.state_hbm_share",
+        # PR 39: the routed experts' grouped matmul, by its own name
+        "kernel.grouped_time_share", "kernel.grouped_decode_roofline"])
     for named in (entry["file"], config["reference"],
                   "benchmark/traffic/turns.json",
                   "benchmark/reduce/hybrid.py",
