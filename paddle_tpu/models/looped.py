@@ -140,6 +140,24 @@ def causal_attention(q, k, v):
                       preferred_element_type=jnp.float32)
 
 
+def project(x, layer):
+    """`layer(x)` on arrays: a `_Matrix` product (operands in the weight's
+    type, float32 result)."""
+    return layer(Tensor._from_op(x))._array
+
+
+def _apply(layer, x, name):
+    return project(x, layer)
+
+
+def gated_ffn(x, gate, up, down, column=_apply, row=_apply):
+    """The gated SiLU feed-forward `down(silu(gate(x)) * up(x))` on arrays;
+    `column` and `row` are how a projection is applied (a paged cache's
+    seams, where there is one)."""
+    f = jax.nn.silu(column(gate, x, "ffn_gate")) * column(up, x, "ffn_up")
+    return row(down, f, "ffn_down")
+
+
 class LoopedLayer(nn.Layer):
     def __init__(self, cfg):
         super().__init__()
@@ -194,9 +212,7 @@ class LoopedLayer(nn.Layer):
         o = row(self.o, mixed.reshape(b, s, -1), "attn_o")
         h = h + rms_norm(o, self.norm2, eps).astype(h.dtype)
         m = rms_norm(h, self.norm3, eps)
-        f = jax.nn.silu(column(self.gate, m, "ffn_gate")) \
-            * column(self.up, m, "ffn_up")
-        f = row(self.down, f, "ffn_down")
+        f = gated_ffn(m, self.gate, self.up, self.down, column, row)
         return h + rms_norm(f, self.norm4, eps).astype(h.dtype)
 
 

@@ -30,7 +30,8 @@ embedding (the state-space layers carry position).
   matrix). Routed part `(sum_e w_e f_e(u)) W_up` (latent -> hidden). Shared
   expert on the full width: `relu(a V1)^2 V2`. Output routed + shared.
 
-THE CHIP'S SHARE (expert parallelism without its exchange). The layer holds
+THE CHIP'S SHARE (expert parallelism without its exchange; router and
+dispatch are models/experts.py, shared with models/dots3.py). The layer holds
 experts `[experts_from, experts_from + experts_held)` of `router_experts`.
 The router keeps its whole width, its picks and the normalisation over all
 of them; the sum over e runs over the picks that are held here; what the
@@ -88,7 +89,10 @@ from .. import nn
 from ..core.tensor import Tensor
 from ..nn import initializer as I
 from ..ops.pallas.grouped_matmul import grouped_matmul
+from .experts import (held_expert_sum, pair_counts, sigmoid_route,
+                      sum_step_stats)
 from .looped import _Gain, _Matrix, causal_attention, rms_norm
+from .looped import project as _project
 
 # the residual stream's type: float32 under bf16 weights, as models/looped.py
 # keeps it (the published model states `residual_in_fp32: false`). What it
@@ -99,8 +103,8 @@ RESIDUAL_DTYPE = jnp.float32
 # the recurrent state's type; a test turns it to bfloat16 to show that its
 # comparison would catch that
 SSM_STATE_DTYPE = jnp.float32
-# the router's type (input, weight, product, sigmoid); same kind of test
-ROUTER_DTYPE = jnp.float32
+# (the router's type, float32 too, is models/experts.py ROUTER_DTYPE, which
+# this block shares with models/dots3.py; same kind of test)
 
 # what a step of a model with expert layers and lane state reports, in the
 # order of the vector `forward` hands to `caches.step_stats` (the engine
@@ -159,12 +163,6 @@ class NemotronHConfig:
     @property
     def conv_channels(self):
         return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
-
-
-def _project(x, layer):
-    """`layer(x)` on arrays: a `_Matrix` product (operands in the weight's
-    type, float32 result)."""
-    return layer(Tensor._from_op(x))._array
 
 
 def ssm_chunk(x, B, C, dt, A, S_in):
@@ -331,58 +329,34 @@ class LatentMoE(nn.Layer):
         """`(picks [T, k], weights [T, k])` for the normed stream `a`
         `[T, hidden]`, over the router's whole width."""
         cfg = self.cfg
-        logits = jnp.matmul(
-            a.astype(ROUTER_DTYPE),
-            self.router.weight._array.astype(ROUTER_DTYPE),
-            precision=jax.lax.Precision.HIGHEST)
-        s = jax.nn.sigmoid(logits).astype(jnp.float32)
-        _, picks = jax.lax.top_k(
-            s + self.router.bias._array.astype(jnp.float32),
-            cfg.experts_per_tok)
-        chosen = jnp.take_along_axis(s, picks, axis=-1)
-        if cfg.norm_topk_prob:
-            chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
-        return picks, cfg.routed_scaling * chosen
+        return sigmoid_route(
+            a, self.router.weight._array, self.router.bias._array,
+            cfg.experts_per_tok, cfg.routed_scaling, cfg.norm_topk_prob)
 
     def forward(self, a, live):
         """`a` `[T, hidden]` float32, `live` `[T]` which slots hold a token.
         Returns `(out [T, hidden] float32, stats)`; `stats` are int32
         scalars `(pairs, pairs_local, experts_hit, max_load)`."""
         cfg = self.cfg
-        T, k, held = a.shape[0], cfg.experts_per_tok, cfg.experts_held
         with jax.named_scope("moe_route"):
             picks, weights = self.route(a)
         with jax.named_scope("moe_experts"):
             w1, w2 = self.experts.w1._array, self.experts.w2._array
+
+            def expert(x, sizes):
+                hidden = grouped_matmul(x, w1, sizes)
+                return grouped_matmul(_relu2(hidden).astype(w2.dtype), w2,
+                                      sizes)
+
             u = _project(a, self.latent_down).astype(w1.dtype)
-            local = ((picks >= cfg.experts_from)
-                     & (picks < cfg.experts_from + held) & live[:, None])
-            # pairs sorted by held expert; every other pair (an absent
-            # expert's, a dead slot's) behind them under the key `held`,
-            # outside every group: not dispatched
-            key = jnp.where(local, picks - cfg.experts_from, held).reshape(-1)
-            order = jnp.argsort(key, stable=True)
-            sorted_key = key[order]
-            bounds = jnp.searchsorted(sorted_key, jnp.arange(held + 1))
-            sizes = (bounds[1:] - bounds[:-1]).astype(jnp.int32)
-            x = u[order // k]                             # [T k, latent]
-            hidden = grouped_matmul(x, w1, sizes)
-            y = grouped_matmul(_relu2(hidden).astype(w2.dtype), w2, sizes)
-            # back to (token, pick) order; rows outside the groups hold
-            # nothing that is read
-            back = jnp.zeros_like(order).at[order].set(
-                jnp.arange(T * k, dtype=order.dtype))
-            y = y[back].reshape(T, k, -1)
-            routed = jnp.sum(
-                jnp.where(local[..., None], y * weights[..., None], 0.0),
-                axis=1)
+            routed, local, sizes = held_expert_sum(
+                u, picks, weights, live, cfg.experts_from, cfg.experts_held,
+                expert)
             routed = _project(routed, self.latent_up)
         with jax.named_scope("moe_shared"):
             shared = _project(_relu2(_project(a, self.shared.v1)),
                               self.shared.v2)
-        stats = (jnp.sum(live).astype(jnp.int32) * k,
-                 jnp.sum(local).astype(jnp.int32),
-                 jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes))
+        stats = pair_counts(live, local, sizes)
         return routed + shared, stats
 
 
@@ -534,10 +508,8 @@ class NemotronH(nn.Layer):
         if caches is None:
             return Tensor._from_op(logits)
         # STEP_STATS: sums over the expert layers, but the largest load
-        per_layer = jnp.array(moe_stats, jnp.int32).reshape(-1, 4)
         caches.step_stats = jnp.concatenate([
-            jnp.sum(per_layer[:, :3], axis=0),
-            jnp.max(per_layer[:, 3:], axis=0, initial=0),
+            *sum_step_stats(moe_stats),
             sum(jnp.sum(jnp.any(g.live_slots(), axis=1), keepdims=True)
                 for g in caches.groups).astype(jnp.int32)])
         return Tensor._from_op(logits), caches

@@ -17,6 +17,15 @@ everywhere else. Because every device op has a static shape, the whole
 mixed prefill+decode serve compiles to two programs — no shape ever depends
 on how many requests are in flight or how long they are.
 
+A model whose cache rows are not a head's K and V declares its CACHE KINDS
+(`CacheKind`; models/dots3.py: a latent row a token shared by the heads, an
+index key, a window layer's latent row) and the pool owns an arena a kind in
+place of the pair: headless arenas ``[slabs, num_blocks, block_size, width]``
+under the same block ids and tables (`rows_append`, `rows_gather`,
+`blocks_gather`: plain XLA scatters and gathers of rows), and for a kind a
+window bounds a ring a lane among the lane state. The two Pallas kernels walk
+the K/V pair only.
+
 Block 0 is the NULL block: the allocator never hands it out, and every
 padded/inactive scatter is routed there, so out-of-range writes can never
 corrupt a live sequence. Reads through padding gather garbage from block 0,
@@ -43,6 +52,7 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
+from typing import NamedTuple
 
 import numpy as np
 
@@ -57,6 +67,68 @@ def blocks_for(num_tokens, block_size):
     exists) both delegate here so admission and construction bounds can
     never drift apart."""
     return max(1, -(-int(num_tokens) // int(block_size)))
+
+
+class CacheKind(NamedTuple):
+    """One kind of cache a model keeps a token: what `BlockPool` owns an
+    arena of. `slabs` layers of it (the arena's leading axis), rows of
+    `width` numbers in the pool's type. With `heads` the arena
+    is the head-major K/V layout `[slabs, heads, blocks, block_size, width]`
+    the paged kernels walk; without, a token's row is shared by the heads
+    (a latent, an index key): `[slabs, blocks, block_size, width]`,
+    addressed by the same block ids and tables. `window` (positions a query
+    may see, its own included) bounds the kind's lifetime: the pool then
+    keeps no blocks of it but a RING a lane, `[slabs, lanes, ring, width]`
+    with `ring = max(window - 1, chunk)` (the positions behind the step's
+    first; a step's own rows are in hand), held, donated and reset as the
+    lane state it is: its bytes do not grow with the sequence."""
+    name: str
+    slabs: int
+    width: int
+    heads: int | None = None
+    window: int | None = None
+
+
+def _paged_arena(name):
+    """The arena `paged[name]` as an attribute: how the pair `k`, `v` of a
+    model that declares no kinds is still read and replaced by name."""
+    return property(lambda self: self.paged[name],
+                    lambda self, arena: self.paged.__setitem__(name, arena))
+
+
+def kv_pair(slabs, heads, head_dim):
+    """The kinds of a model that declares none: a head's keys and values."""
+    return (CacheKind("k", slabs, head_dim, heads),
+            CacheKind("v", slabs, head_dim, heads))
+
+
+def rows_append(arena, slab, rows, slots, offs):
+    """Write a step's rows `[b, s, width]` to `arena[slab, slots, offs]` of a
+    headless arena `[slabs, blocks, block_size, width]`: one row scatter on
+    the arena seen as rows (a bitcast). Dead slots carry slot 0, the null
+    block."""
+    slabs, blocks, bs, width = arena.shape
+    flat = (slab * blocks + slots) * bs + offs
+    return arena.reshape(-1, width).at[flat.reshape(-1)].set(
+        rows.reshape(-1, width).astype(arena.dtype)).reshape(arena.shape)
+
+
+def rows_gather(arena, slab, table, positions):
+    """Rows `[..., width]` at `positions` `[...]` of the sequence whose
+    block table is `table` `[max_blocks]`, out of a headless arena."""
+    slabs, blocks, bs, width = arena.shape
+    flat = (slab * blocks + table[positions // bs]) * bs + positions % bs
+    return arena.reshape(-1, width)[flat]
+
+
+def blocks_gather(arena, slab, table):
+    """Every row the block table `table` `[max_blocks]` names, in the
+    sequence's order `[max_blocks x block_size, width]`, out of a headless
+    arena: whole blocks (rows behind the sequence's last are the null
+    block's, or stale: the caller masks by position)."""
+    slabs, blocks, bs, width = arena.shape
+    return arena.reshape(-1, bs, width)[slab * blocks + table].reshape(
+        -1, width)
 
 
 def positions_to_slots(block_size, blocks, start, count, width):
@@ -294,9 +366,14 @@ class PagedState:
     layer's scan.
 
     Arrays (all fixed-shape, jnp):
-      k, v          [slabs, heads, num_blocks, block_size, head_dim]; a slab
-                    is one layer's cache (a looped model's: one (pass,
-                    layer)'s, the model's `kv_slabs`)
+      paged         {kind: arena} — the pool's arena of every cache kind the
+                    model declares that lives in blocks (`CacheKind`), in the
+                    pool's order. A model that declares none has the pair
+      k, v          [slabs, heads, num_blocks, block_size, head_dim] (also
+                    `paged["k"]`, `paged["v"]`); a slab is one layer's cache
+                    (a looped model's: one (pass, layer)'s, the model's
+                    `kv_slabs`). A kind a WINDOW bounds is a ring a lane and
+                    travels in `lane_state` under its name
       qpos          int32, in the step's layout — absolute position of each
                     token (the model's position-embedding indices)
       k_scale, v_scale  [layers, heads, num_blocks] float32 — an int8
@@ -325,10 +402,9 @@ class PagedState:
 
     is_paged = True
 
-    def __init__(self, k, v, groups, mesh=None, k_scale=None, v_scale=None,
+    def __init__(self, paged, groups, mesh=None, k_scale=None, v_scale=None,
                  quant_collectives=frozenset(), lane_state=None):
-        self.k = k
-        self.v = v
+        self.paged = dict(paged)
         self.groups = tuple(groups)
         self.mesh = mesh
         self.k_scale = k_scale
@@ -337,6 +413,8 @@ class PagedState:
         self.lane_state = lane_state
         self.step_stats = None
         self.qpos = self.join([g.qpos for g in self.groups])
+
+    k, v = _paged_arena("k"), _paged_arena("v")
 
     def cut(self, g, x):
         """Group `g`'s slots of `x` (the step's layout) as `[rows, width,
@@ -375,8 +453,9 @@ class PagedState:
         """The arena arrays a layer's `attend` replaces, as a tuple: what a
         model that runs its layers inside a `lax` loop carries through it
         (models/looped.py), without knowing which arrays an engine has."""
-        kv = ((self.k, self.v) if self.k_scale is None
-              else (self.k, self.v, self.k_scale, self.v_scale))
+        kv = tuple(self.paged.values())
+        if self.k_scale is not None:
+            kv += (self.k_scale, self.v_scale)
         if self.lane_state is None:
             return kv
         return kv + tuple(self.lane_state.values())
@@ -386,10 +465,10 @@ class PagedState:
             n = len(arenas) - len(self.lane_state)
             self.lane_state = dict(zip(self.lane_state, arenas[n:]))
             arenas = arenas[:n]
-        if self.k_scale is None:
-            self.k, self.v = arenas
-        else:
-            self.k, self.v, self.k_scale, self.v_scale = arenas
+        if self.k_scale is not None:
+            self.k_scale, self.v_scale = arenas[-2:]
+            arenas = arenas[:-2]
+        self.paged = dict(zip(self.paged, arenas))
 
     def constrain(self, arr, *spec):
         """`with_sharding_constraint` on the serving mesh — the explicit
@@ -561,19 +640,31 @@ class BlockPool:
     arenas (`arenas()` hands all of it to the step program, donated, and
     `set_arenas` takes it back); nothing here addresses them by position,
     so prefix hits, copy-on-write and the host tier do not carry them.
+
+    `kinds` (a tuple of `CacheKind`, the model's `cache_kinds()`) replaces
+    the K/V pair by an arena a kind, all addressed by the same block ids:
+    `paged` `{name: arena}` for the kinds that live the sequence, and for a
+    kind a window bounds a ring a lane among the lane state (`chunk` is the
+    widest step the engine runs, which a ring must hold). `num_layers`,
+    `num_heads` and `head_dim` are then read by nothing.
     """
 
     def __init__(self, num_blocks, num_layers, block_size, num_heads,
                  head_dim, dtype=None, metrics=None, tracer=None,
-                 sharding=None, kv_dtype=None, lane_state=None, lanes=0):
+                 sharding=None, kv_dtype=None, lane_state=None, lanes=0,
+                 kinds=None, chunk=0):
         import jax.numpy as jnp
 
         if num_blocks < 2:
             raise ValueError("BlockPool needs >= 2 blocks (block 0 is null)")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
-        shape = (num_layers, num_heads, self.num_blocks, self.block_size,
-                 head_dim)
+        # the cache kinds the model declares (`CacheKind`), an arena a kind;
+        # a model that declares none keeps a head's keys and values, the
+        # pair `k`, `v` the paged kernels walk (the int8 arena and the tp
+        # sharding are the pair's: the engine refuses them to declared kinds)
+        self.kinds = tuple(kinds) if kinds is not None \
+            else kv_pair(num_layers, num_heads, head_dim)
         # `kv_dtype="int8"`: the arena stores int8 payloads with
         # per-(layer, head, block) f32 dequant scales in `k_scale`/
         # `v_scale` sidecars [layers, heads, num_blocks]. Anything else
@@ -583,6 +674,12 @@ class BlockPool:
                          else str(jnp.dtype(dtype or jnp.float32).name))
         self.quantized = self.kv_dtype == "int8"
         dt = jnp.int8 if self.quantized else (dtype or jnp.float32)
+
+        def arena_shape(kind):
+            lead = (kind.slabs,) if kind.heads is None \
+                else (kind.slabs, kind.heads)
+            return lead + (self.num_blocks, self.block_size, kind.width)
+
         # `sharding` (tensor-parallel serving, serving/sharded.py): a
         # NamedSharding placing the head axis over tp — each chip owns its
         # heads' slab of every block. ALL host bookkeeping below (free
@@ -590,12 +687,14 @@ class BlockPool:
         # to the single-chip pool: sharding changes where bytes live,
         # never which block ids exist.
         self._sharding = sharding
-        sc_shape = shape[:3]   # [layers, heads, num_blocks] sidecar
+        self.k_scale = self.v_scale = None
         if sharding is None:
-            self.k = jnp.zeros(shape, dt)
-            self.v = jnp.zeros(shape, dt)
-            self.k_scale = jnp.zeros(sc_shape) if self.quantized else None
-            self.v_scale = jnp.zeros(sc_shape) if self.quantized else None
+            self.paged = {k.name: jnp.zeros(arena_shape(k), dt)
+                          for k in self.kinds if k.window is None}
+            if self.quantized:
+                sc_shape = self.k.shape[:3]   # [layers, heads, num_blocks]
+                self.k_scale = jnp.zeros(sc_shape)
+                self.v_scale = jnp.zeros(sc_shape)
         else:
             # the shared cached jit-with-out_shardings builder: allocates
             # the arena SHARDED from the start — eager zeros + device_put
@@ -605,20 +704,25 @@ class BlockPool:
             # on real accelerators)
             from ..parallel.spmd import _sharded_zeros_fn
 
+            shape = arena_shape(self.kinds[0])
             zeros = _sharded_zeros_fn(shape, str(jnp.dtype(dt)), sharding)
-            self.k = zeros()
-            self.v = zeros()
-            self.k_scale = self.v_scale = None
+            self.paged = {"k": zeros(), "v": zeros()}
             if self.quantized:
                 # same NamedSharding: its PartitionSpec (None, 'tp')
                 # shards the sidecar's head axis exactly like the arena's
-                sc_zeros = _sharded_zeros_fn(sc_shape, "float32", sharding)
+                sc_zeros = _sharded_zeros_fn(shape[:3], "float32", sharding)
                 self.k_scale = sc_zeros()
                 self.v_scale = sc_zeros()
         self.lane_state = {
             name: jnp.zeros((shape[0], int(lanes)) + tuple(shape[1:]),
                             dt_ or dtype or jnp.float32)
             for name, (shape, dt_) in (lane_state or {}).items()}
+        # a kind a window bounds: a ring a lane, beside the lane state
+        self.lane_state.update({
+            k.name: jnp.zeros((k.slabs, int(lanes),
+                               max(k.window - 1, int(chunk), 1), k.width),
+                              dt)
+            for k in self.kinds if k.window is not None})
         # block 0 reserved as the null/scratch block
         self._free = list(range(self.num_blocks - 1, 0, -1))
         self._refcount = {}           # block -> holders (held blocks only)
@@ -635,21 +739,38 @@ class BlockPool:
         """Everything the step program is handed donated and hands back, in
         its order: the K/V arenas, the int8 scale sidecars, the lane
         state."""
-        kv = ((self.k, self.v, self.k_scale, self.v_scale) if self.quantized
-              else (self.k, self.v))
+        kv = tuple(self.paged.values())
+        if self.quantized:
+            kv += (self.k_scale, self.v_scale)
         return kv + tuple(self.lane_state.values())
 
     def set_arenas(self, arenas):
-        n = 4 if self.quantized else 2
+        n = len(self.paged)
+        self.paged = dict(zip(self.paged, arenas[:n]))
         if self.quantized:
-            self.k, self.v, self.k_scale, self.v_scale = arenas[:n]
-        else:
-            self.k, self.v = arenas[:n]
+            self.k_scale, self.v_scale = arenas[n:n + 2]
+            n += 2
         self.lane_state = dict(zip(self.lane_state, arenas[n:]))
 
+    k, v = _paged_arena("k"), _paged_arena("v")
+
     def state_bytes_per_lane(self):
-        """Device bytes of lane state one lane holds, all layers."""
+        """Device bytes of lane state one lane holds, all layers (a
+        window-bounded kind's rings among it)."""
         return sum(a.nbytes // a.shape[1] for a in self.lane_state.values())
+
+    def bytes_per_token(self):
+        """`{kind: device bytes a token of context costs}` of the kinds
+        that live in blocks (a windowed kind costs a lane, not a token:
+        `window_bytes_per_lane`)."""
+        return {name: a.nbytes // (self.num_blocks * self.block_size)
+                for name, a in self.paged.items()}
+
+    def window_bytes_per_lane(self):
+        """Device bytes one lane's rings of the windowed kinds take."""
+        return sum(self.lane_state[k.name].nbytes
+                   // self.lane_state[k.name].shape[1]
+                   for k in self.kinds if k.window is not None)
 
     def attach_tier(self, tier):
         """Install the host-memory tier (serving/kv_tier.py): evicted
@@ -688,10 +809,9 @@ class BlockPool:
         K + V payloads plus (int8 arenas) their per-head scale sidecar
         entries. The observability twin of `sharded.kv_capacity_blocks`'s
         per-shard formula: pool_stats/healthz/bench all report THIS."""
-        L, H, _, Bs, D = self.k.shape
-        per = 2 * L * H * Bs * D * self.k.dtype.itemsize
+        per = sum(a.nbytes // self.num_blocks for a in self.paged.values())
         if self.quantized:
-            per += 2 * L * H * self.k_scale.dtype.itemsize
+            per += 2 * (self.k_scale.nbytes // self.num_blocks)
         return per
 
     def refcount(self, block):

@@ -332,6 +332,14 @@ class LLMEngine:
         # cannot rebuild such state, so those options are refused
         spec = getattr(model, "lane_state_spec", None)
         self._lane_spec = spec() if spec is not None else {}
+        # the cache kinds the model declares (block_pool.py `CacheKind`), an
+        # arena a kind in the pool; None: a head's keys and values, the
+        # pair the paged kernels walk. A kind a window bounds is a ring a
+        # lane, state as above: the same options are refused for it
+        kinds = getattr(model, "cache_kinds", None)
+        self._cache_kinds = None if kinds is None else tuple(kinds())
+        self._lane_held = tuple(self._lane_spec) + tuple(
+            k.name for k in self._cache_kinds or () if k.window is not None)
         # int8 KV arena (`kv_dtype="int8"` / PADDLE_TPU_KV_DTYPE): payload
         # bytes quarter (vs f32) and the SAME kv_hbm_bytes budget admits
         # ~4x the blocks — behind the parity/perplexity quality gates in
@@ -345,6 +353,13 @@ class LLMEngine:
                 "the quantized arena or None for the weight dtype")
         self.kv_dtype = None if kv_dtype is None else str(kv_dtype)
         self.kv_quantized = self.kv_dtype == "int8"
+        if self.kv_quantized and self._cache_kinds is not None:
+            raise ValueError(
+                f"kv_dtype='int8' is not available for "
+                f"{type(model).__name__}: the int8 arena keeps a scale a "
+                "head and block of keys or values, and this model's cache "
+                f"rows ({', '.join(k.name for k in self._cache_kinds)}) are "
+                "not a head's")
         # int8 weights (AdaRound, quantization/adaround.py): QDQ in place
         # on the caller's model at construction, calibrated on
         # `calib_prompts` token sequences. Norms/embeddings stay f32.
@@ -423,14 +438,23 @@ class LLMEngine:
             # An int8 arena prices blocks at itemsize 1 plus the f32
             # scale-sidecar overhead — this is where the same budget
             # starts admitting ~4x (f32) / ~2x (bf16) the sequences.
-            num_blocks = kv_capacity_blocks(
-                kv_hbm_bytes, self.kv_slabs, self.kv_heads,
-                self.block_size, self._head_dim(),
-                1 if self.kv_quantized else self._weight_dtype().itemsize,
-                tp_degree=(1 if self._smesh is None
-                           else self._smesh.tp_degree),
-                scale_itemsize=4 if self.kv_quantized else 0,
-            )
+            if self._cache_kinds is not None:
+                # declared kinds: a block costs its rows in every kind that
+                # lives in blocks (a windowed kind costs a lane, not a block)
+                num_blocks = int(kv_hbm_bytes) // sum(
+                    k.slabs * (k.heads or 1) * self.block_size * k.width
+                    * self._weight_dtype().itemsize
+                    for k in self._cache_kinds if k.window is None)
+            else:
+                num_blocks = kv_capacity_blocks(
+                    kv_hbm_bytes, self.kv_slabs, self.kv_heads,
+                    self.block_size, self._head_dim(),
+                    1 if self.kv_quantized
+                    else self._weight_dtype().itemsize,
+                    tp_degree=(1 if self._smesh is None
+                               else self._smesh.tp_degree),
+                    scale_itemsize=4 if self.kv_quantized else 0,
+                )
             # validate()'s worst case for a max-length request: every
             # token but the final sampled one is cached — the gate must
             # mirror that bound exactly or it rejects budgets admission
@@ -476,7 +500,7 @@ class LLMEngine:
         # prefix caching: constructor arg wins, then the env kill switch
         self.prefix_cache = (
             _env_flag("PADDLE_TPU_PREFIX_CACHE", True)
-            and not self._lane_spec       # lane state: off by default
+            and not self._lane_held       # lane state: off by default
             if prefix_cache is None else bool(prefix_cache)
         )
         self._refuse_for_lane_state(
@@ -667,6 +691,7 @@ class LLMEngine:
                       else self._smesh.arena_sharding()),
             kv_dtype=self.kv_dtype,
             lane_state=self._lane_spec, lanes=self.max_batch,
+            kinds=self._cache_kinds, chunk=self.prefill_chunk,
         )
         # host-memory KV tier (serving/kv_tier.py): `host_kv_blocks` host
         # block slots make evicted cached prefixes swap-back-able instead
@@ -710,6 +735,11 @@ class LLMEngine:
                 default=0))
         for name, value in getattr(model, "serving_gauges", dict)().items():
             self.metrics.set_gauge(name, value)
+        if self._cache_kinds is not None:
+            for name, n in self.pool.bytes_per_token().items():
+                self.metrics.set_gauge(f"cache_bytes_per_token_{name}", n)
+            self.metrics.set_gauge("window_cache_bytes_per_lane",
+                                   self.pool.window_bytes_per_lane())
         # counts the model's step leaves on the paged state
         # (`step_stats`), which ride the packed result to the step span
         self._stat_names = tuple(getattr(model, "step_stat_names", ()))
@@ -809,11 +839,11 @@ class LLMEngine:
         return self.model.parameters()[0]._array.dtype
 
     def _refuse_for_lane_state(self, asked, option, why):
-        if asked and self._lane_spec:
+        if asked and self._lane_held:
             raise ValueError(
                 f"{option} is not available for "
                 f"{type(self.model).__name__}, which keeps state a lane "
-                f"({', '.join(self._lane_spec)}) that no block holds: {why}")
+                f"({', '.join(self._lane_held)}) that no block holds: {why}")
 
     def _head_dim(self):
         cfg = self.model.cfg
@@ -1395,7 +1425,8 @@ class LLMEngine:
             return packed
 
         layouts, _ = self._step_layout(W)
-        n_kv = 4 if quantized else 2
+        paged_names = tuple(self.pool.paged)
+        n_kv = len(paged_names) + (2 if quantized else 0)
         lane_names = tuple(self.pool.lane_state)
         n_arena = n_kv + len(lane_names)
         stats = bool(self._stat_names)
@@ -1435,10 +1466,10 @@ class LLMEngine:
                     fresh=a.get("fresh"), lanes=a.get("lanes")))
                 offset += n * width
             state = PagedState(
-                arenas[0], arenas[1], groups,
+                dict(zip(paged_names, arenas)), groups,
                 mesh=None if smesh is None else smesh.mesh,
-                k_scale=arenas[2] if quantized else None,
-                v_scale=arenas[3] if quantized else None,
+                k_scale=arenas[n_kv - 2] if quantized else None,
+                v_scale=arenas[n_kv - 1] if quantized else None,
                 quant_collectives=quant_ops,
                 lane_state=(dict(zip(lane_names, arenas[n_kv:]))
                             if lane_names else None))

@@ -1,6 +1,12 @@
 """What the serving tests share about the shape of a step program
 (`LLMEngine.step_groups`): where a planned row's tokens lie in a step's
-arrays, and an engine steered back to the one rectangle a step used to be."""
+arrays, an engine steered back to the one rectangle a step used to be, and
+an engine whose every step's logits are kept (`Served`)."""
+import numpy as np
+
+import jax
+
+from paddle_tpu.serving import LLMEngine
 
 
 def row_origins(engine, rows, W):
@@ -14,6 +20,78 @@ def row_origins(engine, rows, W):
         return [(i, 0) for _, i in at]
     first = [sum(n * w for n, w in groups[:g]) for g in range(len(groups))]
     return [(0, first[g] + i * groups[g][1]) for g, i in at]
+
+
+class Served:
+    """An engine over `model` whose every step's logits are kept:
+    `logits[request][position]` is the `[vocab]` row that chose the token at
+    `position + 1`; `plans` the steps' rows `(request, lane, start, count)`."""
+
+    def __init__(self, model, **engine_kw):
+        self.model = model
+        self.logits, self.plans = {}, []
+        self._plans, self._origins, self._seen = [], [], []
+        plain = model.forward
+
+        def forward(ids, caches=None):
+            logits, caches = plain(ids, caches=caches)
+            jax.debug.callback(lambda lg: self._seen.append(np.asarray(lg)),
+                               logits._array)
+            return logits, caches
+
+        model.forward = forward
+        self.engine = LLMEngine(model, **engine_kw)
+        run_rows = self.engine._run_rows
+
+        def planned(rows, W, step_id=0):
+            self._plans.append([
+                (r.req.request_id, r.req.lane, r.start, r.count)
+                for r in rows])
+            self._origins.append(row_origins(self.engine, rows, W))
+            return run_rows(rows, W, step_id)
+
+        self.engine._run_rows = planned
+
+    def step(self, only=None):
+        outs = self.engine.step(only=only)
+        jax.effects_barrier()
+        assert len(self._plans) == len(self._seen)
+        for plan, origins, lg in zip(self._plans, self._origins, self._seen):
+            for (rid, _, start, count), (b, s) in zip(plan, origins):
+                for j in range(count):
+                    self.logits.setdefault(rid, {})[start + j] = lg[b, s + j]
+        self.plans += self._plans
+        self._plans, self._origins, self._seen = [], [], []
+        return outs
+
+    def run(self):
+        while self.engine.has_unfinished():
+            self.step()
+
+    def serve(self, prompts, max_new_tokens):
+        """Serve `prompts` to the end; the request ids, and the answers
+        under them in `outputs`."""
+        rids = [self.engine.add_request(p, max_new_tokens=max_new_tokens)
+                for p in prompts]
+        reqs = [self.engine.get_request(r) for r in rids]
+        self.run()
+        self.outputs = {r: list(q.output_ids) for r, q in zip(rids, reqs)}
+        return rids
+
+    def worst(self, reference_logits, rid, prompt):
+        """Largest gap between the served logits of `rid` at every position
+        it was fed and `reference_logits(model, ids)`, the reference's full
+        forward over prompt + answer."""
+        ids = (prompt + list(self.outputs[rid]))[:-1]
+        want = np.asarray(reference_logits(self.model, ids))
+        got = self.logits[rid]
+        assert sorted(got) == list(range(len(ids)))
+        return max(np.abs(got[p] - want[p]).max() for p in got)
+
+
+def prompts_of(lengths, vocab, seed=0):
+    rng = np.random.default_rng(seed)
+    return [[int(t) for t in rng.integers(0, vocab, n)] for n in lengths]
 
 
 def rectangle(engine):

@@ -16,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import paddle_tpu.models.experts as experts
 import paddle_tpu.models.nemotron_h as nh
 
 from _nemotron_common import (VOCAB, build, load_reference, params_of,
@@ -199,5 +200,5 @@ def test_a_bfloat16_router_fails_the_comparison(monkeypatch):
     ids = _ids((1, 64), seed=13)
     want = _reference(model, ids[0])
     assert np.abs(_logits(model, ids)[0] - want).max() < TOL
-    monkeypatch.setattr(nh, "ROUTER_DTYPE", jnp.bfloat16)
+    monkeypatch.setattr(experts, "ROUTER_DTYPE", jnp.bfloat16)
     assert np.abs(_logits(model, ids)[0] - want).max() > 10 * TOL
