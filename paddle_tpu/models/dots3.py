@@ -58,11 +58,12 @@ of the step at a time (`PagedState.per_group`):
 
 - full layer (scopes `mla_index`, `mla_select`, `mla_full`): a tile of a
   row's queries scores the row's index keys (its blocks through its table),
-  takes the exact `top_k`, gathers the chosen latent rows and attends ABSORBED:
-  `q_nope_h W_kvb,K_h^T` against `c_kv` itself, `(sum p c_kv) W_kvb,V_h`: the
-  expanded form's numbers by another order of products, and a query reads
-  `index_topk` rows and the index keys, not the latent cache. Decode rows
-  and chunk rows alike (a chunk a tile of `QUERY_TILE` queries at a time).
+  selects the exact top `index_topk`, gathers the chosen latent rows and
+  attends ABSORBED: `q_nope_h W_kvb,K_h^T` against `c_kv` itself, `(sum p
+  c_kv) W_kvb,V_h`: the expanded form's numbers by another order of
+  products, and a query reads `index_topk` rows and the index keys, not the
+  latent cache. Decode rows and chunk rows alike (a chunk a tile of
+  `QUERY_TILE` queries at a time; the decode group's rows select together).
 - window layer (`mla_window`): the ring's rows and the step's own, banded
   and causal; absorbed at width 1, expanded (`k_nope`, `v` through `W_kvb`)
   over a chunk, where the keys are few and the queries many.
@@ -92,6 +93,7 @@ from .. import nn
 from ..core.tensor import Tensor
 from ..nn import initializer as I
 from ..ops.pallas.grouped_matmul import grouped_matmul
+from ..ops.pallas.topk_select import topk_select
 from .experts import (held_expert_sum, held_experts_hit, pair_counts,
                       sigmoid_route, sum_step_stats)
 from .looped import (_Gain, _Matrix, apply_rotary, gated_ffn, rms_norm,
@@ -227,11 +229,12 @@ def index_scores(q, w, keys):
 def select_keys(scores, qpos, topk):
     """`(chosen [Q, k], ok [Q, k], visible [Q, K])`: the `topk` key
     positions of largest index score among those a query may see (`visible`:
-    `s <= qpos`), exactly; `ok` says which of them it may see (not all
-    while `qpos + 1 < topk`)."""
+    `s <= qpos`), exactly, as a set: `jax.lax.top_k`'s, ties to the lower
+    position (`ops/pallas/topk_select.py`, which takes no sort where it
+    can); `ok` says which of them it may see (not all while `qpos + 1 <
+    topk`)."""
     visible = jnp.arange(scores.shape[-1])[None, :] <= qpos[:, None]
-    _, chosen = jax.lax.top_k(jnp.where(visible, scores, -jnp.inf),
-                              min(topk, scores.shape[-1]))
+    chosen = topk_select(scores, qpos, topk)
     return chosen, chosen <= qpos[:, None], visible
 
 
@@ -335,9 +338,10 @@ class LatentAttention(nn.Layer):
 
     def _full_paged(self, slab, st, g, q_nope, q_rope, rows, q_i, w_i, k_i):
         """A full layer over group `g`: the step's rows and index keys into
-        their arenas, then a row of the group at a time, a tile of its
-        queries at a time. Returns `(out, counts)`: the keys the live
-        queries' selections ranked and their attention read."""
+        their arenas, then the queries: the decode group's rows side by side
+        (one selection over the group), a chunk row's a tile at a time.
+        Returns `(out, counts)`: the keys the live queries' selections
+        ranked and their attention read."""
         from ..serving.block_pool import blocks_gather, rows_append, \
             rows_gather
 
@@ -346,49 +350,68 @@ class LatentAttention(nn.Layer):
             st.paged["latent"], slab, rows, g.slots, g.offs)
         st.paged["index_key"] = keys = rows_append(
             st.paged["index_key"], slab, k_i, g.slots, g.offs)
+
+        def scores(context, q_i, w_i):
+            with jax.named_scope("mla_index"):
+                return index_scores(q_i, w_i, context)
+
+        def select(scores, qpos):
+            with jax.named_scope("mla_select"):
+                return select_keys(scores, qpos, cfg.index_topk)
+
+        def attend(rows, q_nope, q_rope, ok):
+            with jax.named_scope("mla_full"):
+                return self.attend_absorbed(q_nope, q_rope, rows, ok)
+
+        def counts(visible, ok, live):
+            return {"attn_keys_visible": live_keys(visible, live),
+                    "attn_keys_selected": live_keys(ok, live)}
+
+        if g.width == 1:
+            # a decode row reads the rows its selection names and the index
+            # keys, not the latent cache; the group's one query a row select
+            # together, `[rows, K]`
+            s = jax.vmap(lambda table, q, w: scores(
+                blocks_gather(keys, slab, table), q, w))(
+                    g.block_tables, q_i, w_i)
+            chosen, ok, visible = select(s[:, 0], g.qpos[:, 0])
+
+            def row(table, chosen, q_nope, q_rope, ok):
+                with jax.named_scope("mla_select"):
+                    picked = rows_gather(latent, slab, table, chosen[None])
+                return attend(picked, q_nope, q_rope, ok[None])
+
+            out = jax.vmap(row)(g.block_tables, chosen, q_nope, q_rope, ok)
+            return out, counts(visible, ok, g.live_slots()[:, 0])
+
         tq = QUERY_TILE if g.width % QUERY_TILE == 0 else g.width
 
-        def row(x):
+        def chunk_row(x):
             table, *queries = x
             context = blocks_gather(keys, slab, table)
-            if g.width == 1:
-                # a decode row reads the rows its selection names and the
-                # index keys, not the latent cache
-                def picked(chosen):
-                    return rows_gather(latent, slab, table, chosen)
-            else:
-                # a chunk's queries share the row's context: its blocks
-                # once, side by side, and a tile's choices index that
-                mine = blocks_gather(latent, slab, table)
-
-                def picked(chosen):
-                    return mine[chosen]
+            # a chunk's queries share the row's context: its blocks once,
+            # side by side, and a tile's choices index that
+            mine = blocks_gather(latent, slab, table)
 
             def tile(y):
                 qpos, live, q_nope, q_rope, q_i, w_i = y
-                with jax.named_scope("mla_index"):
-                    scores = index_scores(q_i, w_i, context)
+                chosen, ok, visible = select(scores(context, q_i, w_i), qpos)
                 with jax.named_scope("mla_select"):
-                    chosen, ok, visible = select_keys(scores, qpos,
-                                                      cfg.index_topk)
-                    chosen = picked(chosen)
-                with jax.named_scope("mla_full"):
-                    out = self.attend_absorbed(q_nope, q_rope, chosen, ok)
-                return out, live_keys(visible, live), live_keys(ok, live)
+                    picked = mine[chosen]
+                out = attend(picked, q_nope, q_rope, ok)
+                return out, counts(visible, ok, live)
 
             tiles = [q.reshape((-1, tq) + q.shape[1:]) for q in queries]
-            out, visible, selected = tile([t[0] for t in tiles]) \
+            out, n = tile([t[0] for t in tiles]) \
                 if tiles[0].shape[0] == 1 else jax.lax.map(tile, tiles)
             return (out.reshape((g.width,) + out.shape[-2:]),
-                    jnp.sum(visible), jnp.sum(selected))
+                    jax.tree.map(jnp.sum, n))
 
-        xs = (g.block_tables, g.qpos, g.live_slots(), q_nope, q_rope, q_i,
-              w_i)
-        # decode rows side by side; chunk rows one after the other
-        out, visible, selected = jax.vmap(row)(xs) if g.width == 1 \
-            else jax.lax.map(row, xs)
-        return out, {"attn_keys_visible": jnp.sum(visible),
-                     "attn_keys_selected": jnp.sum(selected)}
+        # chunk rows one after the other
+        out, n = jax.lax.map(chunk_row, (
+            g.block_tables, g.qpos, g.live_slots(), q_nope, q_rope, q_i,
+            w_i))
+        return out, jax.tree.map(jnp.sum, n)
 
     def _window_paged(self, slab, st, g, q_nope, q_rope, rows):
         """A window layer over group `g`: the lanes' rings and the step's

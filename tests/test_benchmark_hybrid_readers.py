@@ -137,3 +137,37 @@ def test_a_capture_without_the_operation_reads_as_none():
                    kv_tokens=40)]
     run = _traced(_calls(11_000_000, 4), {1: (10_000_000, 20_000_000)}, plain)
     assert _read("kernel.grouped_decode_roofline", run) is None
+
+
+# -- PR 41: the reader of the full layers' selection kernel, beside the
+# grouped matmul's, on the same hand-made captures.
+
+SELECT = "%topk_select.{} = s32[64,16,128]{{2,1,0}} custom-call(...)"
+
+
+def test_select_time_share_reads_the_kernel_by_its_name():
+    """Two steps, 3 + 2 calls of 1 ms among 9 ms of other operations; the
+    kernel's name folded at its first dot, as the grouped matmul's is."""
+    runs = {1: (10_000_000, 20_000_000), 2: (30_000_000, 45_000_000)}
+    calls = [(s, e, SELECT.format(i)) for i, (s, e, _) in enumerate(
+        _calls(11_000_000, 3) + _calls(31_000_000, 2))]
+    ops = calls + [(16_000_000, 19_000_000, "%fusion.7 = ..."),
+                   (35_000_000, 41_000_000, "%sort.2 = ...")]
+    run = _traced(ops, runs, [_decode(1, 0.010, 0.021),
+                              _decode(2, 0.030, 0.046)])
+    share, n = _read("kernel.select_time_share", run)
+    assert n == 5
+    assert share == pytest.approx(100 * 5e-3 / 14e-3)
+
+
+def test_select_time_share_is_none_where_the_selection_sorts():
+    """The parent of PR 41: `lax.top_k`'s sort, no operation of the name;
+    and a run with no capture."""
+    steps = [_decode(1, 0.010, 0.021)]
+    ops = [(11_000_000, 12_000_000, "%sort.2 = f32[64,17408] ..."),
+           (13_000_000, 14_000_000, "%fusion.7 = ...")]
+    run = _traced(ops, {1: (10_000_000, 20_000_000)}, steps)
+    assert _read("kernel.select_time_share", run) is None
+    bare = Run({}, {"name": "cell"}, CONFIG, {}, None, V5E, None, 0.0)
+    bare.engine_steps = steps
+    assert _read("kernel.select_time_share", bare) is None

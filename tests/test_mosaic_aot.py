@@ -230,6 +230,18 @@ for rows in (704, 3520):
             sds((128, K, N), "bfloat16"), sds((128,), "int32"),
             case=case) == 1, case
 
+# the full layers' selection alone (ops/pallas/topk_select.py), at the dots3
+# cell's two shapes: a chunk row's tile of 64 queries and the decode group's
+# 32, each over a row's 17,408 slots, 2,048 chosen
+from paddle_tpu.ops.pallas import topk_select as tsel
+
+for rows in (64, 32):
+    case = f"topk-select-q{rows}"
+    assert compile_for_v5e(
+        lambda s, q: tsel.topk_select_kernel(s, q, 2048),
+        sds((rows, 17408), "float32"), sds((rows,), "int32"),
+        case=case) == 1, case
+
 # the hybrid block (models/nemotron_h.py): one Mamba, one attention and one
 # expert layer; the arena has the model's 2 K/V heads under 4 query heads,
 # the pool's lane state rides beside it, and the expert layer's two grouped
@@ -322,6 +334,8 @@ EXPECTED = {
     # the routed experts' grouped matmul at the hybrid cell's shapes
     **{f"grouped-matmul-m{m}-{k}x{n}": {"grouped_matmul": 1}
        for m in (704, 3520) for k, n in ((1024, 2688), (2688, 1024))},
+    # the full layers' selection at the dots3 cell's shapes
+    **{f"topk-select-q{q}": {"topk_select": 1} for q in (64, 32)},
 }
 
 

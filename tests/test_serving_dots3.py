@@ -219,7 +219,9 @@ def test_the_benchmark_has_the_configuration_and_its_cell():
     assert sorted(mine) == sorted([
         "step.latent_mfu", "attn.selected_key_share",
         "pool.window_cache_share", "moe.gated_experts_hit_share",
-        "moe.gated_load_max_over_mean"])
+        "moe.gated_load_max_over_mean",
+        # PR 41: the full layers' selection kernel, by its own name
+        "kernel.select_time_share"])
     # one chunk row a step, what the sweep on the chip settled (PERF.md
     # section 6): every step of the window then carries a chunk, and the
     # readers of pure decode steps find none and do not list the cell
