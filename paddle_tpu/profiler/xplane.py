@@ -471,9 +471,14 @@ def _runtime_stamps(order, notes, matched, said, issues, dones):
     Done carry no id and go by name: the first issue after the step's
     annotation began and before the next step's (else None), and the first
     Done after that (a Done belongs to one run, so they are handed out one
-    a step); ``done`` is the earlier of the two words of the end."""
+    a step); ``done`` is the earlier of the two words of the end. Where
+    the runtime names runs' ends itself (completion events that carry the
+    id, as a TPU's does), those alone are taken: a loop that launches a
+    step before its predecessor's run ends issues it before that run's
+    Done, which the hand-out would then give it."""
     import bisect
 
+    named = any(len(said[matched[sid]["run_id"]]) > 1 for sid in order)
     out, d = {}, 0
     for sid, nxt in zip(order, order[1:] + [None]):
         s0 = notes[sid][0]
@@ -483,7 +488,7 @@ def _runtime_stamps(order, notes, matched, said, issues, dones):
             nxt is None or issues[at] < notes[nxt][0]) else None
         while d < len(dones) and dones[d] < (s0 if issue is None else issue):
             d += 1
-        ends = mine[1:] + dones[d:d + 1]
+        ends = mine[1:] if named else mine[1:] + dones[d:d + 1]
         d += d < len(dones)
         out[sid] = {"enqueue": mine[0], "issue": issue,
                     "done": min(ends, default=None)}

@@ -40,6 +40,16 @@ program — the scheduler's mixed plan is the only program shape — keyed by
   sampling against the same temperature/top-k/top-p-processed
   distribution, so the output distribution is unchanged.
 
+**One step in flight ahead of the host**: a call of `step` plans and
+launches the next step as if the step in flight had emitted, and only then
+reads the step in flight (`_Launched`, `_finish`), so the device runs one
+step while the host reads, emits and plans around it. A decode row whose
+token the step in flight samples names that step's result row in the
+packed input (section ``src``) and the program takes the token there: the
+token never visits the host before it is fed. Drafts, ``only``, a fault
+plan, a host KV tier and a plan that would need a block the pool lacks
+keep a call synchronous (`step`).
+
 Prefill buckets are gone: a prompt of ANY length streams into the arena
 `prefill_chunk` tokens at a time while the running batch keeps decoding in
 the same steps, so time-to-first-token of in-flight requests no longer
@@ -138,16 +148,42 @@ from .block_pool import (BlockPool, PagedState, blocks_for,
                          chain_block_hashes)
 from .faults import FaultInjected
 from .metrics import ServingMetrics
-from .scheduler import WAITING, Request, Scheduler
+from .scheduler import RUNNING, WAITING, MustDrain, Request, Scheduler
 
 _request_log = logging.getLogger("paddle_tpu.serving.request")
 
 StepOutput = namedtuple("StepOutput", ["request_id", "token", "finished"])
 
+# a result row's words where the program drafts nothing (K = 0): the token,
+# the accept length, the row-finite flag. The previous step's result, which
+# a step takes to gather the tokens the host has not read, is laid out so
+_ROW_WORDS = 3
+
+
+class _Launched:
+    """A step launched on the device whose result the host has not read
+    (`LLMEngine._in_flight`, at most one between calls): what reading and
+    emitting it needs, and what the tracer records of it then. ``lanes[i]``
+    is planned row i's row of the result; ``result_row`` maps a request
+    that emits in this step to that row, where the next step's ``src`` finds
+    its token; ``gens[i]`` is row i's request's preemption count at the
+    launch, so a row whose request ended or was requeued since is dropped at
+    the read; ``read``: the result has been read."""
+
+    def __init__(self, rows, lanes, W, K, out, step_id, phases, args):
+        self.rows, self.lanes, self.W, self.K = rows, lanes, W, K
+        self.out, self.step_id = out, step_id
+        self.phases, self.args = phases, args
+        self.kind = None
+        self.gens = [row.req.preemptions for row in rows]
+        self.result_row = {row.req: lane for lane, row in zip(lanes, rows)
+                           if row.emit}
+        self.read = False
+
 
 @functools.lru_cache(maxsize=None)
 def step_input_layout(B, W, max_blocks, touched_width=None,
-                      lane_state=False, lanes=False):
+                      lane_state=False, lanes=False, src=False):
     """THE layout of a step's host inputs, a group of rows at a time:
     ``({name: (offset, shape, dtype)}, words)`` for one group of ``B`` rows
     of ``W`` slots (`LLMEngine.step_groups`). What the host hands the
@@ -160,11 +196,13 @@ def step_input_layout(B, W, max_blocks, touched_width=None,
     where a row starts its sequence from zeros; ``lanes`` adds the section
     of that name for a group behind the decode group: the decode group's
     row each of its rows stands in for, where the request is decided and
-    where state a lane holds lives). `_row_arrays` makes its views from
-    this table and the jitted step (`unpack_step_input`) its slices, so the
-    two cannot drift apart. The float32 sections are views of the same
-    words (``ndarray.view`` on the host, ``lax.bitcast_convert_type`` in
-    the program): bit for bit."""
+    where state a lane holds lives; ``src`` adds the decode group's section
+    of that name: the row of the previous step's result whose token the
+    row feeds, -1 where the host gives it). `_row_arrays` makes its views
+    from this table and the jitted step (`unpack_step_input`) its slices,
+    so the two cannot drift apart. The float32 sections are views of the
+    same words (``ndarray.view`` on the host, ``lax.bitcast_convert_type``
+    in the program): bit for bit."""
     i32, f32 = np.dtype(np.int32), np.dtype(np.float32)
     sections = [
         ("ids", (B, W), i32), ("tables", (B, max_blocks), i32),
@@ -174,6 +212,7 @@ def step_input_layout(B, W, max_blocks, touched_width=None,
           if touched_width else []),
         *([("fresh", (B,), i32)] if lane_state else []),
         *([("lanes", (B,), i32)] if lanes else []),
+        *([("src", (B,), i32)] if src else []),
         ("adapter_slots", (B,), i32), ("last_idx", (B,), i32),
         ("spec_lens", (B,), i32), ("temps", (B,), f32), ("top_ks", (B,), i32),
         ("top_ps", (B,), f32),
@@ -796,8 +835,9 @@ class LLMEngine:
         self._requests = {}
         self._step_fns = {}
         self._layouts = {}  # width bucket -> `_step_layout`
-        self._phases = {}   # current step's {phase: (t0, t1)} when tracing
-        self._span_args = {}  # and what its span says of grid and hand-over
+        # the step launched and not yet read (`_Launched`), or None: at most
+        # one is in flight between two calls of `step`
+        self._in_flight = None
         self._retrace_warned = False
         # stamped by AsyncLLMEngine.start(): while that thread is alive,
         # stepping from any OTHER thread would race the arena donation
@@ -813,6 +853,15 @@ class LLMEngine:
         # starts out replicated, as the program returns it, so the first
         # step and every later one offer the same kind of array
         self._key = jax.device_put(jax.random.PRNGKey(seed), self._rep)
+        # the previous step's result, where a step gathers the tokens its
+        # predecessor sampled and the host has not read (section `src`):
+        # zeros before the first step, after a step that drafts (whose
+        # result is wider) and after a step whose result is never read
+        n_stats = len(self._stat_names)
+        self._no_prev = jax.device_put(np.zeros(
+            (self.max_batch * _ROW_WORDS + n_stats,) if n_stats
+            else (self.max_batch, _ROW_WORDS), np.int32), self._rep)
+        self._prev = self._no_prev
         # fault injection (serving/faults.py): arm the PADDLE_TPU_FAULTS
         # plan if one is configured; with no plan every hook site below is
         # a single module-attribute pointer test (same discipline as the
@@ -1297,7 +1346,8 @@ class LLMEngine:
         return self._requests.get(request_id)
 
     def has_unfinished(self):
-        return self.scheduler.has_unfinished()
+        """Requests not yet finished, or a step launched and not read."""
+        return self._in_flight is not None or self.scheduler.has_unfinished()
 
     def get_request(self, request_id):
         return self._requests[request_id]
@@ -1432,15 +1482,22 @@ class LLMEngine:
         stats = bool(self._stat_names)
 
         # ONE signature for both arena kinds: (params, buffers, *arenas,
-        # lora_tables, packed, key). The arenas are the payload pair, plus
-        # the f32 scale sidecars right after it when the arena is int8,
+        # lora_tables, packed, prev, key). The arenas are the payload pair,
+        # plus the f32 scale sidecars right after it when the arena is int8,
         # plus the pool's lane state after those where a model keeps any;
         # `packed` is the step's whole host input (`step_input_layout`, a
-        # group after the other), `key` the engine's sampling key, which
-        # lives on the device
+        # group after the other), `prev` the previous step's result and
+        # `key` the engine's sampling key, both on the device
         def step(params, buffers, *rest):
-            arenas, (lora_tables, packed, key) = rest[:n_arena], rest[n_arena:]
+            arenas, (lora_tables, packed, prev, key) = (rest[:n_arena],
+                                                        rest[n_arena:])
             parts = [unpack_step_input(packed, fields) for fields in layouts]
+            # a decode row whose token the previous step sampled and the
+            # host has not read takes it from that step's result
+            a = parts[0]
+            fed = prev.reshape(-1)[jnp.maximum(a["src"], 0) * _ROW_WORDS]
+            a["ids"] = a["ids"].at[:, 0].set(
+                jnp.where(a["src"] >= 0, fed, a["ids"][:, 0]))
             # the engine's stream: one split a step, the new key handed
             # back as the program's LAST output
             key, sub = jax.random.split(key)
@@ -1507,8 +1564,9 @@ class LLMEngine:
                          donate_argnums=arena_args)
         else:
             # mesh-aware program, same (B, W) keying: weights and arenas
-            # pinned to their tp shardings, the packed host input and the
-            # key (and the packed result and the new key out) replicated.
+            # pinned to their tp shardings, the packed host input, the
+            # previous result and the key (and the packed result and the
+            # new key out) replicated.
             # Arena donation routes through the JL004 gate — the
             # host-platform CPU mesh miscompiles donated sharded buffers,
             # so donation is off exactly there and in-place on real
@@ -1519,7 +1577,7 @@ class LLMEngine:
             arena = smesh.arena_sharding()
             in_sh = (self._param_shardings, self._buffer_shardings,
                      ) + (arena,) * n_arena + (self._lora_shardings,
-                     rep, rep)
+                     rep, rep, rep)
             out_sh = (rep,) + (arena,) * n_arena + (rep,)
             fn = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
                          donate_argnums=mesh_donate_argnums(arena_args))
@@ -1577,8 +1635,9 @@ class LLMEngine:
                 self._touched_width(width) if self.pool.quantized else None,
                 bool(self.pool.lane_state),
                 # a later group names the decode group's row each of its
-                # rows stands in for
-                lanes=i > 0)
+                # rows stands in for; the decode group's rows may feed a
+                # token the device holds
+                lanes=i > 0, src=i == 0)
             layouts.append({name: (words + off, shape, dtype)
                             for name, (off, shape, dtype) in fields.items()})
             words += size
@@ -1647,11 +1706,12 @@ class LLMEngine:
 
     def step_program_args(self, W):
         """THE statement of the width-``W`` step program's arguments, in
-        its order ``(params, buffers, *arenas, lora_tables, packed, key)``:
-        weights, arenas and adapter tables as their real placed arrays,
-        the packed host input (`step_input_layout`) and the sampling key
-        as ShapeDtypeStructs. `lowered_step_programs` lowers with exactly
-        these; tests and AOT tools map them to shapes of their own."""
+        its order ``(params, buffers, *arenas, lora_tables, packed, prev,
+        key)``: weights, arenas and adapter tables as their real placed
+        arrays, the packed host input (`step_input_layout`), the previous
+        step's result and the sampling key as ShapeDtypeStructs.
+        `lowered_step_programs` lowers with exactly these; tests and AOT
+        tools map them to shapes of their own."""
         import jax
         import jax.numpy as jnp
 
@@ -1659,6 +1719,7 @@ class LLMEngine:
         return (self._params, self._buffers, *self._arenas(),
                 self._lora_tables,
                 jax.ShapeDtypeStruct((words,), jnp.int32),
+                jax.ShapeDtypeStruct(self._prev.shape, self._prev.dtype),
                 jax.ShapeDtypeStruct(self._key.shape, self._key.dtype))
 
     def step_program_spec(self):
@@ -1767,22 +1828,23 @@ class LLMEngine:
             "no_alias": ("swap_out",),
         }
 
-    def _run_step(self, fn, packed, step_id=0, t_disp=0.0):
+    def _run_step(self, fn, packed, step_id=0, t_disp=0.0, args=None):
         """Hand the step's packed host input to the device and dispatch
         the unified step program; returns the DEVICE result array (the
-        caller's single np.asarray on it is the step's ONE host sync).
-        The program splits the sampling key itself and returns the
-        advanced one, stored here without being read; a launch that
-        raises assigns nothing, so the key (never donated) stays the one
-        the failed step was offered and a retry samples with it again.
-        While tracing, the step span's ``h2d_arrays`` is the number of
-        host arrays handed over (one, on every step of every engine),
-        ``h2d_bytes`` its size and ``h2d_ms`` the time from ``t_disp``
-        (the start of the ``dispatch`` phase) to just before the program
-        is launched: that one transfer. The launch then runs under the
-        step's annotation (`Tracer.launch`: the join key to a device
-        capture and, with the ``launch_ns`` stamped before it, the step's
-        clock pair)."""
+        caller's single np.asarray on it is the step's ONE host sync). The
+        program takes the previous step's result as it lies on the device
+        (`_prev`), from which it gathers the tokens the host has not read,
+        splits the sampling key itself and returns the advanced one, stored
+        here without being read; a launch that raises assigns nothing, so
+        the key (never donated) stays the one the failed step was offered
+        and a retry samples with it again. While tracing, `args` (the step
+        span's) gets ``h2d_arrays``, the number of host arrays handed over
+        (one, on every step of every engine), ``h2d_bytes`` its size and
+        ``h2d_ms`` the time from ``t_disp`` (the start of the ``dispatch``
+        phase) to just before the program is launched: that one transfer.
+        The launch then runs under the step's annotation (`Tracer.launch`:
+        the join key to a device capture and, with the ``launch_ns``
+        stamped before it, the step's clock pair)."""
         import jax
 
         dev = jax.device_put(packed, self._rep)
@@ -1790,15 +1852,17 @@ class LLMEngine:
         if tr is None:
             launch = contextlib.nullcontext()
         else:
-            self._span_args.update(
-                h2d_arrays=1, h2d_bytes=packed.nbytes,
-                h2d_ms=(time.monotonic() - t_disp) * 1e3)
-            launch = tr.launch(step_id, self._span_args)
+            args.update(h2d_arrays=1, h2d_bytes=packed.nbytes,
+                        h2d_ms=(time.monotonic() - t_disp) * 1e3)
+            launch = tr.launch(step_id, args)
         with launch:
             out, *arenas, self._key = fn(
                 self._params, self._buffers, *self._arenas(),
-                self._lora_tables, dev, self._key)
+                self._lora_tables, dev, self._prev, self._key)
         self.pool.set_arenas(arenas)
+        # a result with drafts is wider than the next step takes
+        self._prev = out if out.shape == self._no_prev.shape \
+            else self._no_prev
         return out
 
     # -- fault hooks (serving/faults.py; armed plans only) -----------------
@@ -1882,83 +1946,71 @@ class LLMEngine:
         a failed batch while everyone else holds still. Rows the engine
         had to contain this step (non-finite logits) emit no StepOutput;
         they are aborted internally and reported in ``self.step_faults``
-        as ``(request_id, detail)`` pairs."""
+        as ``(request_id, detail)`` pairs.
+
+        At most one step is in flight ahead of the host. Where the engine
+        may (`_may_launch_ahead`), a call plans the NEXT step as if the
+        step in flight had emitted (a row that emits there has one more
+        token, fed by the device from that step's result: section `src`),
+        launches it, and only then reads the step in flight and emits its
+        tokens: the device goes from one step to the next while the host
+        reads, emits and plans. A request whose last token is in flight is
+        not planned again; one that ends otherwise (eos, abort, deadline, a
+        non-finite row) is found at the read, and the token the step behind
+        made for it is dropped at ITS read. A call still returns one step's
+        outputs, in the order a synchronous loop returns them. A call that
+        may not launch ahead (drafts, ``only``, a fault plan, a host KV
+        tier, or a plan that needs a block the pool does not have) reads
+        the step in flight first, then plans and runs its own step
+        synchronously as before, and returns both steps' outputs. Anything
+        raised drains: a step whose result was not read is taken back
+        (`_unlaunch`) and ``last_planned`` names the rows of every step the
+        call touched."""
         self._guard_thread("step()")
-        tr = self.tracer
-        t_plan0 = time.monotonic() if tr is not None else 0.0
         self.step_faults = []
-        # cleared BEFORE planning: if schedule() itself raises (config
-        # error, injected alloc pressure) the supervisor must not recover
-        # against the PREVIOUS step's plan — an empty plan routes the
-        # failure to the unattributable path instead of re-queueing and
-        # catch-up-flipping bystanders
-        self.last_planned = []
-        rows = self.scheduler.schedule(only=only)
-        if self.policy is not None:
-            # deadline early-rejects decided during admission: surface
-            # each as an aborted request on the step_faults channel (the
-            # supervisor relays faults as failures, so frontend streams
-            # get a terminal "error" event with the policy reason) —
-            # drained BEFORE the empty-plan early return so a step whose
-            # only outcome was rejection still finalizes its victims
-            for req, reason in self.scheduler.drain_policy_rejects():
-                self.metrics.inc("policy_early_rejections")
-                self.metrics.inc_labeled("policy_early_rejections",
-                                         self.policy.class_labels(req))
-                self.step_faults.append((req.request_id, reason))
-                self.abort(req.request_id, reason=reason)
-        if self.tier is not None:
-            # arena-write ordering (kv_tier.py rule 1): demotions buffered
-            # by this plan's evictions must gather their bytes before the
-            # step program's donated scatters land on those blocks
-            self.tier.flush_saves()
-        if not rows:
-            return []
-        self.step_count += 1
-        self.last_planned = [row.req.request_id for row in rows]
-        if faults._PLAN is not None:
-            self._fire_step_faults()
-        # ONE program shape per step — the smallest ragged width bucket
-        # covering the widest planned row (chunk tokens + drafts). The
-        # dominant all-decode steps resolve to width 1; step KINDS are
-        # metrics/trace labels only and no longer key programs.
-        W = self._width_for(max(r.count + len(r.draft) for r in rows))
-        if any(r.count > 1 for r in rows):
-            kind = "mixed"
-        elif any(r.draft for r in rows):
-            kind = "verify"
-        else:
-            kind = "decode"
-        step_id = tr.next_step_id() if tr is not None else 0
-        if tr is not None:
-            self._phases = {"plan": (t_plan0, time.monotonic())}
-            self._span_args = {}
-        t_step0 = time.monotonic()
-        with self.metrics.timed(f"{kind}_step"):
-            outs = self._run_rows(rows, W, step_id)
-        if self.policy is not None:
-            self.policy.observe_step(time.monotonic() - t_step0)
-        if tr is not None:
-            tr.record_step(step_id, kind, self._phases, {
-                "rows": len(rows),
-                "width": W,
-                "decode_rows": sum(1 for r in rows
-                                   if r.count == 1 and not r.draft),
-                "prefill_rows": sum(1 for r in rows if r.count > 1),
-                "spec_lanes": sum(1 for r in rows if r.draft),
-                "fed_tokens": sum(r.count + len(r.draft) for r in rows),
-                "emitted_tokens": len(outs),
-                # every fed token runs the layers `loop_steps` times and
-                # writes `cache_slabs` slabs; a GPT: 1 and its layers
-                "loop_steps": getattr(self.model.cfg, "loop_steps", 1),
-                "cache_slabs": self.kv_slabs,
-                # tokens of context the rows attend over, this step's
-                # included: what attention reads of every slab
-                "kv_tokens": sum(r.start + r.count + len(r.draft)
-                                 for r in rows),
-                **self._span_args,
-            })
-        self.metrics.inc(f"{kind}_steps")
+        ahead = only is None and self._may_launch_ahead()
+        steps, outs = [], []     # the steps this call touched, in order
+        t_mark = time.monotonic()
+        try:
+            own = self._in_flight is None
+            if own:
+                self._in_flight = self._launch(only)
+            if self._in_flight is not None:
+                steps.append(self._in_flight)
+            nxt = None
+            if self._in_flight is not None and ahead:
+                try:
+                    nxt = self._launch(ahead=True)
+                except MustDrain:
+                    ahead = False
+                if nxt is not None:
+                    steps.append(nxt)
+            if self._in_flight is not None:
+                outs += self._finish(self._in_flight)
+                t_mark = self._count_step(self._in_flight, t_mark)
+            self._in_flight = nxt
+            if not own and not ahead:
+                # this call's own step, planned on read tokens
+                s = self._in_flight = self._launch(only)
+                if s is not None:
+                    steps.append(s)
+                    outs += self._finish(s)
+                    t_mark = self._count_step(s, t_mark)
+                    self._in_flight = None
+        except BaseException:
+            for s in reversed(steps):
+                if not s.read:
+                    self._unlaunch(s)
+            self._in_flight = None
+            touched = [r.req.request_id for s in steps for r in s.rows]
+            self.last_planned = list(dict.fromkeys(
+                touched + list(self.last_planned)))
+            raise
+        read = [s for s in steps if s.read]
+        if not read:
+            return outs
+        self.last_planned = list(dict.fromkeys(
+            r.req.request_id for s in read for r in s.rows))
         self.metrics.set_gauge(
             "tokens_in_flight",
             sum(r.num_tokens for r in self.scheduler.running),
@@ -2037,6 +2089,92 @@ class LLMEngine:
                 )
         return outs
 
+    def _may_launch_ahead(self):
+        """Whether the next step may be planned and launched while the one
+        in flight is unread, by what the engine can observe: no drafter
+        (it proposes from tokens on the host), no fault plan (its hooks
+        match steps and rows the host has read) and no host KV tier (its
+        `flush_saves` orders arena writes against the plan). Where a plan
+        made ahead needs a block the pool does not have, the scheduler
+        says so (`MustDrain`) and the call drains instead."""
+        return (self.scheduler.drafter is None and faults._PLAN is None
+                and self.tier is None)
+
+    def _launch(self, only=None, ahead=False):
+        """Plan the next step and launch it: its `_Launched`, or None where
+        nothing is planned. ``ahead``: a step is in flight unread and this
+        one is planned as if it had emitted (the scheduler raises
+        `MustDrain` where that plan would preempt or defer)."""
+        tr = self.tracer
+        t_plan0 = time.monotonic() if tr is not None else 0.0
+        # cleared BEFORE planning: if schedule() itself raises (config
+        # error, injected alloc pressure) the supervisor must not recover
+        # against an earlier plan
+        self.last_planned = []
+        rows = self.scheduler.schedule(only=only, ahead=ahead)
+        if self.policy is not None:
+            # deadline early-rejects decided during admission: surface
+            # each as an aborted request on the step_faults channel (the
+            # supervisor relays faults as failures, so frontend streams
+            # get a terminal "error" event with the policy reason) —
+            # drained BEFORE the empty-plan early return so a step whose
+            # only outcome was rejection still finalizes its victims
+            for req, reason in self.scheduler.drain_policy_rejects():
+                self.metrics.inc("policy_early_rejections")
+                self.metrics.inc_labeled("policy_early_rejections",
+                                         self.policy.class_labels(req))
+                self.step_faults.append((req.request_id, reason))
+                self.abort(req.request_id, reason=reason)
+        if self.tier is not None:
+            # arena-write ordering (kv_tier.py rule 1): demotions buffered
+            # by this plan's evictions must gather their bytes before the
+            # step program's donated scatters land on those blocks
+            self.tier.flush_saves()
+        if not rows:
+            return None
+        self.step_count += 1
+        self.last_planned = [row.req.request_id for row in rows]
+        if faults._PLAN is not None:
+            self._fire_step_faults()
+        # ONE program shape per step — the smallest ragged width bucket
+        # covering the widest planned row (chunk tokens + drafts). The
+        # dominant all-decode steps resolve to width 1; step KINDS are
+        # metrics/trace labels only and no longer key programs.
+        W = self._width_for(max(r.count + len(r.draft) for r in rows))
+        step_id = tr.next_step_id() if tr is not None else 0
+        t_plan1 = time.monotonic() if tr is not None else 0.0
+        s = self._run_rows(rows, W, step_id)
+        if any(r.count > 1 for r in rows):
+            s.kind = "mixed"
+        elif any(r.draft for r in rows):
+            s.kind = "verify"
+        else:
+            s.kind = "decode"
+        if tr is not None:
+            s.phases["plan"] = (t_plan0, t_plan1)
+        return s
+
+    def _unlaunch(self, s):
+        """Take back what launching `s` told the host (its rows' tokens
+        counted in the arena, its samples unread) for a step whose result
+        will never be read, and feed the next step no token of it."""
+        for row, gen in zip(s.rows, s.gens):
+            req = row.req
+            if req.state == RUNNING and req.preemptions == gen:
+                req.num_cached -= row.count
+                req.unread -= row.emit
+        self._prev = self._no_prev
+
+    def _count_step(self, s, t_mark):
+        """A read step's counters and its wall time: from `t_mark` (the
+        call's start, or the read before it in the same call) to now."""
+        now = time.monotonic()
+        self.metrics.observe(f"{s.kind}_step", now - t_mark)
+        if self.policy is not None:
+            self.policy.observe_step(now - t_mark)
+        self.metrics.inc(f"{s.kind}_steps")
+        return now
+
     def _row_arrays(self, S):
         """A fresh packed host input for the unified ragged step and its
         named views, a dict a group (so fill sites cannot drift apart on a
@@ -2045,7 +2183,8 @@ class LLMEngine:
         0: the ragged kernel runs no iteration there), read the all-zeros
         adapter slot 0 and, with an int8 arena, touch only the null block
         (``touched`` slot 0, ``touch_idx`` 0) — but ``top_ps``, which
-        reads 1.0, and ``lanes``, which names no lane. A buffer a step was
+        reads 1.0, ``lanes``, which names no lane, and ``src``, which names
+        no row of the previous result (-1). A buffer a step was
         launched with is never written again: a `device_put` on the CPU
         backend may alias it."""
         layouts, words = self._step_layout(S)
@@ -2058,6 +2197,8 @@ class LLMEngine:
             a["top_ps"][:] = 1.0
             if "lanes" in a:
                 a["lanes"][:] = self.max_batch
+            if "src" in a:
+                a["src"][:] = -1
             parts.append(a)
         return packed, parts
 
@@ -2113,18 +2254,20 @@ class LLMEngine:
         return at, lanes
 
     def _run_rows(self, rows, W, step_id=0):
-        """Run one unified ragged step at width bucket `W`: every
+        """Build and launch one unified ragged step at width bucket `W`;
+        returns its `_Launched` (`_finish` reads and emits it). Every
         scheduled row feeds its `count` chunk tokens at positions
-        [start, start+count) plus its (possibly empty) drafted
-        candidates after them; the program samples each emitting row's
-        next token, verifies its drafts, and decides the accepted run ON
-        DEVICE — the host reads ONE packed array (the step's single
-        device→host transfer) and publishes. Rejected speculative tails
-        roll back: their KV slots are stale (overwritten before they are
-        ever attended, exactly like any future position) and their
-        reserved blocks return to the pool via `reclaim_spec_blocks`."""
+        [start, start+count) plus its (possibly empty) drafted candidates
+        after them; a decode row whose token the step in flight
+        (`_in_flight`) samples names that step's result row in ``src`` and
+        the program gathers it there. The program samples each emitting
+        row's next token, verifies its drafts, and decides the accepted run
+        ON DEVICE. Once launched, the rows' fed tokens count as in the arena
+        and an emitting row's sample as unread (`Request.unread`): what the
+        next plan may assume before the read."""
         tr = self.tracer
         t_build = time.monotonic() if tr is not None else 0.0
+        behind = self._in_flight
         packed, parts = self._row_arrays(W)
         groups = self.step_groups(W)
         lane_state = bool(self.pool.lane_state)
@@ -2134,7 +2277,11 @@ class LLMEngine:
             a = parts[g]
             if g:
                 a["lanes"][i] = lane
-            if start == req.num_tokens - 1:
+            if req.unread:
+                # its pending token is the step in flight's sample: the
+                # device takes it from that step's result
+                a["src"][i] = behind.result_row[req]
+            elif start == req.num_tokens - 1:
                 # decode fast path: the single pending token is always the
                 # last one — skip rebuilding prompt+outputs every step
                 a["ids"][i, 0] = req.last_token
@@ -2153,6 +2300,7 @@ class LLMEngine:
             self._fill_row(a, i, req, start, count + k, groups[g][1])
         fn = self._get_step_fn(self.max_batch, W)
         K = self._draft_capacity(W)
+        args, phases = {}, {}
         if tr is not None:
             # what the ragged kernel walks for this step, a head a layer,
             # summed over the groups' launches: rows past the planned ones
@@ -2166,32 +2314,60 @@ class LLMEngine:
                                             self.block_size)
                 live, total = live + lv, total + tot
                 blocks += append_blocks(a["slots"])
-            self._span_args.update(
+            args.update(
                 grid_live=live, grid_total=total, append_blocks=blocks,
                 # the token slots of the program that ran, beside the
                 # `fed_tokens` that were live in them
-                slots=sum(n * width for n, width in groups))
+                slots=sum(n * width for n, width in groups),
+                # launched while the step before it was unread
+                ahead=int(behind is not None))
         t_disp = time.monotonic() if tr is not None else 0.0
-        out_dev = self._run_step(fn, packed, step_id=step_id, t_disp=t_disp)
+        out = self._run_step(fn, packed, step_id=step_id, t_disp=t_disp,
+                             args=args)
+        if tr is not None:
+            phases.update(build=(t_build, t_disp),
+                          dispatch=(t_disp, time.monotonic()))
+        if behind is not None:
+            self.metrics.inc("steps_launched_ahead")
+        for row in rows:
+            row.req.num_cached += row.count
+            row.req.unread += row.emit
+        return _Launched(rows, lanes, W, K, out, step_id, phases, args)
+
+    def _finish(self, s):
+        """Read the launched step `s` (THE host sync: one packed [B, K+3]
+        transfer carries the emitted runs, accept lengths and row-finite
+        flags) and publish it; returns its StepOutputs. A row whose request
+        ended or was requeued since the launch is dropped: the token made
+        for it is never emitted. Rejected speculative tails roll back:
+        their KV slots are stale (overwritten before they are ever
+        attended, exactly like any future position) and their reserved
+        blocks return to the pool via `reclaim_spec_blocks`. While tracing,
+        the step's span is recorded here, with its phases from the launch
+        (plan, build, dispatch) and from this read (sync, emit)."""
+        tr = self.tracer
         t_sync = time.monotonic() if tr is not None else 0.0
-        # THE host sync: one packed [B, K+3] transfer carries the emitted
-        # runs, accept lengths, and row-finite flags for the whole step
-        out = np.asarray(out_dev)
+        out = np.asarray(s.out)
+        s.read = True
         self.metrics.inc("host_syncs")
+        K = s.K
         if self._stat_names:
             n = len(self._stat_names)
             if tr is not None:
-                self._span_args.update(
-                    zip(self._stat_names, map(int, out[-n:])))
+                s.args.update(zip(self._stat_names, map(int, out[-n:])))
             out = out[:-n].reshape(self.max_batch, K + 3)
         run, n_accs, row_ok = (out[:, :K + 1], out[:, K + 1],
                                out[:, K + 2])
         if faults._PLAN is not None:
-            row_ok = self._corrupt_row_ok(zip(lanes, rows), row_ok)
+            row_ok = self._corrupt_row_ok(zip(s.lanes, s.rows), row_ok)
         t_emit = time.monotonic() if tr is not None else 0.0
+        t_disp = s.phases["dispatch"][0] if tr is not None else 0.0
         outs = []
-        for i, row in zip(lanes, rows):
+        for i, row, gen in zip(s.lanes, s.rows, s.gens):
             req, k = row.req, len(row.draft)
+            if req.state != RUNNING or req.preemptions != gen:
+                continue   # ended or requeued while this step ran
+            req.unread -= row.emit
             if not row_ok[i]:
                 # NaN/Inf logits: abort this row only — its KV and token
                 # are garbage; everyone else's step output is unaffected
@@ -2203,12 +2379,12 @@ class LLMEngine:
                 self.metrics.inc("spec_proposed_tokens", k)
                 self.metrics.inc("spec_accepted_tokens", n_acc)
                 req.spec_accepted += n_acc
-            # the fed run [chunk tokens, accepted drafts] is real
-            # sequence content, so its KV is valid — advance num_cached
-            # BEFORE emitting (an eos inside the run finishes the
-            # request, and release publishes full prompt blocks off
-            # num_cached)
-            req.num_cached += row.count + n_acc
+            # the fed run [chunk tokens, accepted drafts] is real sequence
+            # content, so its KV is valid: the chunk was counted at the
+            # launch, the accepted drafts are counted BEFORE emitting (an
+            # eos inside the run finishes the request, and release
+            # publishes full prompt blocks off num_cached)
+            req.num_cached += n_acc
             if self.policy is not None:
                 # fairness accounting charges device work actually
                 # consumed: fed chunk tokens + accepted drafts
@@ -2219,7 +2395,7 @@ class LLMEngine:
                     ("verify" if k else
                      "prefill_chunk" if row.count > 1 else "decode"),
                     t_disp, t_emit,
-                    {"step": step_id, "start": row.start,
+                    {"step": s.step_id, "start": row.start,
                      "count": row.count, "emit": row.emit,
                      **({"drafted": k, "accepted": n_acc} if k else {})})
             if not row.emit:
@@ -2233,10 +2409,28 @@ class LLMEngine:
             if k and not req.finished:
                 self.scheduler.reclaim_spec_blocks(req)
         if tr is not None:
-            self._phases.update(build=(t_build, t_disp),
-                                dispatch=(t_disp, t_sync),
-                                sync=(t_sync, t_emit),
-                                emit=(t_emit, time.monotonic()))
+            s.phases.update(sync=(t_sync, t_emit),
+                            emit=(t_emit, time.monotonic()))
+            rows = s.rows
+            tr.record_step(s.step_id, s.kind, s.phases, {
+                "rows": len(rows),
+                "width": s.W,
+                "decode_rows": sum(1 for r in rows
+                                   if r.count == 1 and not r.draft),
+                "prefill_rows": sum(1 for r in rows if r.count > 1),
+                "spec_lanes": sum(1 for r in rows if r.draft),
+                "fed_tokens": sum(r.count + len(r.draft) for r in rows),
+                "emitted_tokens": len(outs),
+                # every fed token runs the layers `loop_steps` times and
+                # writes `cache_slabs` slabs; a GPT: 1 and its layers
+                "loop_steps": getattr(self.model.cfg, "loop_steps", 1),
+                "cache_slabs": self.kv_slabs,
+                # tokens of context the rows attend over, this step's
+                # included: what attention reads of every slab
+                "kv_tokens": sum(r.start + r.count + len(r.draft)
+                                 for r in rows),
+                **s.args,
+            })
         return outs
 
     def _emit(self, req, token):

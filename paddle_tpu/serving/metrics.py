@@ -99,6 +99,8 @@ _HELP = {
     "mixed_steps": "Device steps carrying at least one prefill chunk",
     "decode_steps": "Pure-decode device steps",
     "verify_steps": "Speculative verify device steps",
+    "steps_launched_ahead": "Steps launched while the step before them "
+                            "was unread",
     "jit_traces": "XLA program traces (recompile alarm; constant after "
                   "warmup)",
     "mixed_step": "Mixed-step wall time",

@@ -88,6 +88,14 @@ _arrival_counter = itertools.count()
 WAITING, RUNNING, FINISHED = "waiting", "running", "finished"
 ABORTED = "aborted"
 
+
+class MustDrain(Exception):
+    """A plan made ahead of an unread step (`Scheduler.schedule(ahead=True)`)
+    needs a block the pool does not have: growing it would preempt or defer,
+    which only a plan on read tokens may decide. The engine reads the step
+    in flight and plans again."""
+
+
 # One planned row of the next mixed step: feed `req.all_ids[start:start+count]`
 # at positions [start, start+count); `emit` marks rows whose last fed position
 # is the sequence's final pending token — the engine samples their next token.
@@ -138,6 +146,10 @@ class Request:
             raise ValueError("num_spec_tokens must be >= 0")
         self.eos_token_id = eos_token_id
         self.output_ids = []
+        # tokens a launched step samples for this request that the host has
+        # not read yet (0 or 1): the engine plans the next step as if they
+        # were read, and the device feeds them (engine.py `_run_rows`)
+        self.unread = 0
         self.state = WAITING
         self.blocks = []      # arena block ids owned by this sequence
         self.num_cached = 0   # tokens whose K/V currently live in the arena
@@ -201,8 +213,9 @@ class Request:
     @property
     def num_pending(self):
         """Tokens not yet fed through the model (>= 1 while running: during
-        decode the freshly sampled token is always pending)."""
-        return self.num_tokens - self.num_cached
+        decode the freshly sampled token is always pending), an unread one
+        included."""
+        return self.num_tokens + self.unread - self.num_cached
 
     @property
     def finished(self):
@@ -219,7 +232,7 @@ class Request:
         return self.output_ids[-1] if self.output_ids else self.prompt_ids[-1]
 
     def remaining_new_tokens(self):
-        return self.max_new_tokens - len(self.output_ids)
+        return self.max_new_tokens - len(self.output_ids) - self.unread
 
 
 class Scheduler:
@@ -285,6 +298,7 @@ class Scheduler:
         self.waiting = deque()
         self.running = []
         self._free_lanes = list(range(self.max_batch - 1, -1, -1))
+        self._ahead = False     # planning ahead of an unread step
 
     def _precedence(self, req):
         """The planning/preemption total order: the policy's
@@ -342,6 +356,7 @@ class Scheduler:
             req.blocks = []
         req.num_cached = 0
         req.num_matched_blocks = 0
+        req.unread = 0
 
     def _leave(self, req):
         """Out of the running set, its lane free again."""
@@ -479,6 +494,8 @@ class Scheduler:
             got = self.pool.allocate(1)
             if got is not None:
                 return got[0]
+            if self._ahead:
+                raise MustDrain(req.request_id)
             if self.policy is not None:
                 victim = self.policy.select_victim(self.running, req)
                 if victim is not None:
@@ -582,14 +599,24 @@ class Scheduler:
         req.lane = self._free_lanes.pop()
         self.running.append(req)
 
-    def schedule(self, only=None):
+    def schedule(self, only=None, ahead=False):
         """Plan one mixed step. Returns the list of ScheduledRows (empty =
         idle). Every running sequence gets its decode token or its next
         prefill chunk (budget and pool permitting); waiting requests are
         admitted FCFS into free lanes first. ``only`` (a set of request
         ids) restricts BOTH admission and planning to those requests —
         the supervisor's bisection probes step a suspect subset while
-        every other sequence holds its state untouched."""
+        every other sequence holds its state untouched. ``ahead``: the
+        plan is made while a launched step is unread (its tokens counted
+        in `Request.unread`), so a block the pool lacks raises `MustDrain`
+        instead of preempting or deferring."""
+        self._ahead = ahead
+        try:
+            return self._schedule(only)
+        finally:
+            self._ahead = False
+
+    def _schedule(self, only):
         if only is None:
             if self.policy is None:
                 while self.waiting and len(self.running) < self.max_batch:
@@ -634,6 +661,8 @@ class Scheduler:
                 continue  # preempted while an earlier row grew its blocks
             if only is not None and req.request_id not in only:
                 continue  # held still while a probe steps the suspects
+            if req.remaining_new_tokens() < 1:
+                continue  # its last token is in flight: nothing to plan
             pending = req.num_pending
             if pending == 1:
                 # decode row (also a prefill's final 1-token chunk): always

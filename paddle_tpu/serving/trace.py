@@ -14,10 +14,13 @@ exactly those two views as Chrome/Perfetto trace events:
   first token), block-pool instants (``alloc``, ``cow``,
   ``spec_reserve``/``spec_reclaim``, ``preempt``), and the closing
   ``request`` span (arrival → finish/abort) with the request's summary;
-- an **engine step timeline** — one ``step`` span per `LLMEngine.step()`
-  with phase children ``plan`` (scheduling), ``build`` (host batch
-  assembly), ``dispatch`` (device program launch), ``sync`` (host sync on
-  the sampled tokens), ``emit`` (token emission), tagged with the batch
+- an **engine step timeline** — one ``step`` span per device step,
+  recorded when the step is read (the engine launches a step before it
+  reads the one in flight, so consecutive spans overlap; ``ahead`` is 1 on
+  a step so launched), with phase children ``plan`` (scheduling),
+  ``build`` (host batch assembly), ``dispatch`` (device program launch),
+  ``sync`` (host sync on the sampled tokens), ``emit`` (token emission),
+  tagged with the batch
   composition (decode rows, prefill chunks, spec lanes), program kind
   (mixed/decode/verify), token counts, the ragged kernel's live and
   total grid steps (``grid_live``/``grid_total``), the blocks the

@@ -125,21 +125,29 @@ def serve(engine, arrivals, only=None, **knobs):
     plans)`: the tokens of each request in the order given, as the steps
     emitted them, and `spy_plans`' list with a request named by its place
     in `arrivals`. `only`: `{step: indices}` steps
-    restricted to those requests (`LLMEngine.step(only=...)`)."""
-    plans, streams, rids, n = spy_plans(engine), {}, {}, 0
+    restricted to those requests (`LLMEngine.step(only=...)`). A step is
+    named by its place among the steps LAUNCHED (`step_count`): a call may
+    launch the next step before it reads the one in flight, and an idle
+    engine takes the next arrival at once. A step a call launched ahead of
+    the read cannot be a probe's: every key of `only` must be reached."""
+    plans, streams, rids, calls = spy_plans(engine), {}, {}, 0
     todo = sorted(enumerate(arrivals), key=lambda ia: ia[1][0])
+    probes = dict(only or {})
     while todo or engine.has_unfinished():
-        while todo and todo[0][1][0] <= n:
+        n = engine.step_count          # the step the next launch makes
+        while todo and (todo[0][1][0] <= n or not engine.has_unfinished()):
             i, (_, prompt, tokens, *own) = todo.pop(0)
             rids[i] = engine.add_request(
                 prompt, max_new_tokens=tokens, **{**knobs, **dict(*own)})
             streams[rids[i]] = []
-        probe = None if only is None or n not in only \
-            else {rids[i] for i in only[n] if i in rids}
+        assert not any(k < n for k in probes), ("launched ahead", probes, n)
+        probe = None if n not in probes \
+            else {rids[i] for i in probes.pop(n) if i in rids}
         for out in engine.step(only=probe):
             streams[out.request_id].append(out.token)
-        n += 1
-        assert n < 500, "the serve does not end"
+        calls += 1
+        assert calls < 500, "the serve does not end"
+    assert not probes, probes
     index = {rid: i for i, rid in rids.items()}
     return ([streams[rids[i]] for i in range(len(arrivals))],
             [(W, [(index[rid], *row) for rid, *row in rows])

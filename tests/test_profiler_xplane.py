@@ -391,6 +391,35 @@ def test_join_keys_on_a_runs_enqueue_not_on_its_completion(tmp_path):
     assert len({r["run_id"] for r in join.steps}) == len(steps)
 
 
+def test_join_places_a_loop_that_launches_a_step_before_its_predecessor_ends(
+        tmp_path):
+    """One step in flight ahead of the host (serving/engine.py `step`): a
+    step is launched and issued while the run before it is on the device,
+    the runs go back to back, and the capture began inside a run whose step
+    it did not see. A Done names no run: handed out one a step, step 0
+    would take that run's (the first after its issue), and every later
+    step its predecessor's, each before its own run ends. Every run's own
+    completion event carries its id, and those alone bound the ends: each
+    step is placed, beside its own end."""
+    run = 3000.0
+    ghost = {"id": 99, "launch": 100.0, "call": 400.0, "run_id": None,
+             "dev": (1000.0, 1000.0 + run)}
+    steps = [{"id": i, "launch": 1500.0 + run * i, "call": 400.0,
+              "issue": 1800.0 + run * i,
+              "dev": (1000.0 + run * (i + 1), 1000.0 + run * (i + 2)),
+              "complete": 1020.0 + run * (i + 2)} for i in range(8)]
+    join = _join(tmp_path, [ghost] + steps, skew_us=-1150.0)
+    clock = join.clocks[0]
+    assert clock["why"] is None and clock["violations"] == 0
+    placed = {r["step"]: r for r in join.steps}
+    assert placed[99]["device_start_us"] is None
+    for st in steps:
+        r = placed[st["id"]]
+        assert r["run_id"] == 100 + st["id"]
+        assert r["done_us"] == pytest.approx(st["complete"])
+        assert r["device_end_us"] <= r["done_us"] <= r["read_us"]
+
+
 def test_join_holds_steps_of_one_width_to_one_program(tmp_path):
     """Another program's enqueue between a step's annotation and its own
     (a swap, a prefix copy) would hand the step that run: the span's

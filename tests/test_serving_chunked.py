@@ -317,8 +317,9 @@ def test_each_feature_rides_a_grouped_step(model, case):
         # a motif the n-gram drafter continues: B drafts while A prefills
         prompts[1] = [5, 6, 7, 5, 6]
     elif case == "only":
-        # steps 3 and 5 hold B and D still
-        only = {3: (0, 2), 5: (0, 2)}
+        # steps 3 and 6 hold B and D still (the call after a probe launches
+        # two steps, its own and the next one ahead of the read)
+        only = {3: (0, 2), 6: (0, 2)}
     elif case == "preempt":
         kw["num_blocks"] = 9        # 8 usable blocks of 8 for 4 lanes
     wave = wave_of(prompts)
@@ -348,7 +349,7 @@ def test_each_feature_rides_a_grouped_step(model, case):
                    for rows in wide for *_, k in rows)
         assert grouped.metrics.counters["spec_accepted_tokens"] > 0
     elif case == "only":
-        assert [sorted({r for r, *_ in plans[n][1]}) for n in (3, 5)] == [
+        assert [sorted({r for r, *_ in plans[n][1]}) for n in (3, 6)] == [
             [0, 2], [0, 2]]
     elif case == "preempt":
         assert grouped.metrics.counters["preemptions"] >= 1

@@ -194,13 +194,19 @@ def test_hybrid_step_programs_are_textually_the_parents(setup, kw):
     kinds keeps the pair `k`, `v`, and every lowered hybrid step program has
     the text it had at PR 39 (sha256 of `as_text()`, recorded on the parent
     commit with this jax). GPT's and the looped decoder's are held by
-    `tests/test_serving_looped.py` and `tests/test_append_dispatch.py`."""
-    want = json.loads(
+    `tests/test_serving_looped.py` and `tests/test_append_dispatch.py`.
+    Every program moved once since, when a step took the previous step's
+    result as one more operand (`tests/step_programs_prev_operand.json`)."""
+    parents = json.loads(
         (REPO / "tests/hybrid_step_programs_pr39.json").read_text())[setup]
+    want = json.loads((REPO / "tests/step_programs_prev_operand.json")
+                      .read_text())["hybrid"][setup]
     engine = LLMEngine(hybrid.build(**kw), **KW)
     got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()
            for name, low in engine.lowered_step_programs().items()}
     assert got == want
+    assert sorted(parents) == sorted(got)
+    assert all(parents[name] != h for name, h in got.items())
 
 
 def test_the_benchmark_has_the_configuration_and_its_cell():

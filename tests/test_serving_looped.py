@@ -71,8 +71,8 @@ class Served:
 
         self.engine._run_rows = planned
 
-    def step(self):
-        outs = self.engine.step()
+    def step(self, only=None):
+        outs = self.engine.step(only=only)
         jax.effects_barrier()
         for plan, (lg, gates) in zip(self._plans, self._seen):
             for rid, start, count, (b, s) in plan:
@@ -152,11 +152,12 @@ def test_pass_t_reads_its_own_slabs_and_no_other(corrupt_pass):
                         max_batch=2, max_seq_len=64, prefill_chunk=32,
                         prefix_cache=False)
         rid = served.engine.add_request(prompt, max_new_tokens=4)
-        served.step()                       # the prompt, one chunk
+        # a probe (`only`) is read before the next step is planned
+        served.step(only={rid})             # the prompt, one chunk
         if spoil is not None:
             pool = served.engine.pool
             pool.k = pool.k.at[spoil].multiply(-3.0)
-        served.step()                       # one decode step
+        served.step(only={rid})             # one decode step
         return served.gates[rid][len(prompt)]
 
     clean = decode_gates(None)
@@ -268,8 +269,8 @@ def test_a_grouped_steps_logits_are_the_references():
     plans = spy_plans(served.engine)
     rids = [served.engine.add_request(p, max_new_tokens=n)
             for _, p, n in wave[:2]]
-    served.step()
-    served.step()
+    while served.engine.step_count < 2:     # steps 0 and 1 launched
+        served.step()
     rids += [served.engine.add_request(p, max_new_tokens=n)
              for _, p, n in wave[2:]]
     served.run()
@@ -316,12 +317,22 @@ def test_gpt_step_programs_are_textually_the_parents():
     operation); **a program that holds a chunk group behind the decode
     group has a text of its own** (`tests/gpt_step_programs_pr37.json`, the
     six `w8`): its tokens lie on one flat axis, and the append and the
-    attention run once a group."""
+    attention run once a group. **Every program has moved since, and is
+    recorded in `tests/step_programs_prev_operand.json`**: a step takes the
+    previous step's result as one more operand and gathers from it the
+    decode rows' tokens the host has not read (section `src`)."""
     from test_append_dispatch import SETUPS, _engine
 
     assert sorted(_wanted("gpt")) == sorted(SETUPS)
     for setup in sorted(SETUPS):
         _hashes_are_the_recorded(_engine(setup), "gpt", setup)
+
+
+def _recorded(kind):
+    """`{setup: {program: sha256}}` of the programs as they are: each takes
+    the previous step's result (`tests/step_programs_prev_operand.json`)."""
+    return json.loads((REPO / "tests/step_programs_prev_operand.json")
+                      .read_text())[kind]
 
 
 def _wanted(kind):
@@ -341,7 +352,11 @@ def _wanted(kind):
 def _hashes_are_the_recorded(engine, kind, setup):
     got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()
            for name, low in engine.lowered_step_programs().items()}
-    assert got == _wanted(kind)[setup], setup
+    assert got == _recorded(kind)[setup], setup
+    # every program took one more operand, so every text moved
+    parents = _wanted(kind)[setup]
+    assert sorted(parents) == sorted(got)
+    assert all(parents[name] != h for name, h in got.items())
     # what PR 37 recorded anew is the programs of more than one group
     new = json.loads((REPO / f"tests/{kind}_step_programs_pr37.json")
                      .read_text())[setup]
@@ -363,6 +378,8 @@ def test_looped_step_programs_are_textually_the_parents():
     that commit with this jax); since PR 37 the four of one group of rows
     have, and the three `w16` the text of
     `tests/looped_step_programs_pr37.json`."""
+    # all seven moved since, when a step took the previous step's result
+    # (`_recorded`)
     assert sorted(_wanted("looped")) == sorted(LOOPED_SETUPS)
     for setup, kw in sorted(LOOPED_SETUPS.items()):
         engine = LLMEngine(build(2), block_size=8, max_batch=2,

@@ -249,11 +249,12 @@ def test_real_nan_forward_is_contained_and_never_cached(model):
     eng._params = jax.tree_util.tree_map(
         lambda x: x * float("nan"), eng._params)
     eng.add_request(p, max_new_tokens=4, temperature=0.0, request_id="bad")
-    outs = []
+    outs, contained = [], []
     while eng.has_unfinished():
         outs += eng.step()
+        contained += eng.step_faults
     assert outs == []                              # no token ever emitted
-    assert eng.step_faults == [("bad", "nonfinite_logits")]
+    assert contained == [("bad", "nonfinite_logits")]
     assert eng.pool._hash_index == {}              # nothing published
     assert _idle(eng)
 

@@ -134,12 +134,12 @@ def test_every_step_span_says_grid_and_handover(model, kv_dtype):
         assert a["grid_total"] >= nq
         assert a["grid_total"] - a["grid_live"] <= nq
         # one packed buffer a step, whatever the arena: its sections are
-        # a group's thirteen host arrays (fifteen with an int8 arena; a
-        # group behind the decode group has `lanes` besides)
+        # a group's thirteen host arrays (fifteen with an int8 arena) and
+        # one more: `src` in the decode group, `lanes` in a group behind it
         layouts, words = engine._step_layout(a["width"])
         assert a["h2d_arrays"] == 1
         assert [len(fields) for fields in layouts] == [
-            (15 if kv_dtype else 13) + (g > 0) for g in range(len(groups))]
+            (15 if kv_dtype else 13) + 1] * len(groups)
         assert a["h2d_bytes"] == 4 * words
         # ts/dur are rounded to 3 decimals of a microsecond
         assert 0.0 <= a["h2d_ms"] <= phases["dispatch"]["dur"] / 1e3 + 1e-5
@@ -219,8 +219,10 @@ def test_a_request_behind_a_full_batch_waits_out_the_steps_before_it(model):
     assert lat["count"] == 2
     # the second request arrived before the first step and got the lane
     # only after the first one's last: it waited out every step in between
+    # (a step is planned before the one in flight is read: spans overlap)
     steps = _steps(engine)
     first_request = [s for s, _ in steps][:3]      # prefill + 2 decodes
-    waited_ms = sum(s["dur"] for s in first_request) / 1e3
+    waited_ms = (max(s["ts"] + s["dur"] for s in first_request)
+                 - min(s["ts"] for s in first_request)) / 1e3
     assert lat["max_ms"] >= waited_ms
     assert lat["max_ms"] >= max(s["dur"] for s in first_request) / 1e3

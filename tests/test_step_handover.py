@@ -5,10 +5,12 @@ packed int32 buffer, and a sampling key that lives on the device.
 - the layout round-trips: what the program unpacks is, bit for bit, what
   the thirteen (fifteen) separate host arrays of PR 34 held for the same
   plan, the float sections included, and an idle lane reads zeros and
-  ``top_ps`` 1.0 whatever an earlier step planned there;
+  ``top_ps`` 1.0 whatever an earlier step planned there; a row whose token
+  the step in flight samples reads no id and names that step's result row
+  in ``src`` (-1 elsewhere);
 - the key's stream is the one a host-side ``key, sub = split(key)`` a
   step gives: the sampler is fed that chain's ``sub`` and ``engine._key``
-  is that chain's key, after every step of every width;
+  is that chain's key, after every step launched, of every width;
 - a step that raises leaves the key defined and the engine able to go on;
 - tp=2 on the CPU mesh emits tp=1's tokens from the same replicated
   buffer and key.
@@ -75,7 +77,9 @@ def _pr34_arrays(engine, placed, B, S):
     `(row of the rectangle, planned row)` pairs; PR 34's step was the one
     rectangle `[max_batch, S]` with every planned row in its place in the
     plan, and a step is one such rectangle a group now
-    (`LLMEngine.step_groups`)."""
+    (`LLMEngine.step_groups`). A row whose pending token the step in flight
+    samples has no id on the host; those arrays had no such row, their step
+    being read before the next was planned."""
     a = {
         "ids": np.zeros((B, S), np.int32),
         "qpos": np.zeros((B, S), np.int32),
@@ -98,7 +102,7 @@ def _pr34_arrays(engine, placed, B, S):
         req, start, count, k = row.req, row.start, row.count, len(row.draft)
         if start == req.num_tokens - 1:
             a["ids"][i, 0] = req.last_token
-        else:
+        elif not req.unread:
             a["ids"][i, :count] = req.all_ids[start:start + count]
         if k:
             a["ids"][i, count:count + k] = row.draft
@@ -113,7 +117,8 @@ def _group_arrays(engine, rows, W):
     each: a planned row that feeds one token (and its drafts) in its place
     in the plan in the decode group, a wider one in the chunk group's next
     row, which also names that place (`lanes`; an idle row names none:
-    `max_batch`)."""
+    `max_batch`); the decode group's `src` names, for a row fed a token the
+    step in flight samples, that step's result row (-1 elsewhere)."""
     groups = engine.step_groups(W)
     wide = [i for i, r in enumerate(rows)
             if r.count + len(r.draft) > groups[0][1]]
@@ -125,6 +130,11 @@ def _group_arrays(engine, rows, W):
         if g:
             a["lanes"] = np.full(B, engine.max_batch, np.int32)
             a["lanes"][:len(wide)] = wide
+        else:
+            a["src"] = np.full(B, -1, np.int32)
+            for i, row in placed:
+                if row.req.unread:
+                    a["src"][i] = engine._in_flight.result_row[row.req]
         out.append((a, {i for i, _ in placed}))
     return out
 
@@ -185,7 +195,7 @@ def test_the_program_unpacks_what_pr34s_separate_arrays_held(
             assert list(fields) == [
                 "ids", "tables", "slots", "offs", "qpos", "q_start",
                 "kv_live", *(["touched", "touch_idx"] if kv_dtype else []),
-                *(["lanes"] if g else []),
+                *(["lanes"] if g else ["src"]),
                 "adapter_slots", "last_idx", "spec_lens", "temps", "top_ks",
                 "top_ps"]
             got = jax.jit(functools.partial(unpack_step_input,
@@ -196,17 +206,23 @@ def test_the_program_unpacks_what_pr34s_separate_arrays_held(
                 assert got[name].shape == want[name].shape, name
                 assert np.array_equal(_bits(got[name]), _bits(want[name])), (
                     n, W, g, name)
-            # rows without a planned row: zeros everywhere, top_ps 1.0, and
-            # no lane named
+            # rows without a planned row: zeros everywhere, top_ps 1.0, no
+            # lane named and no result row
             idle = [i for i in range(len(want["last_idx"])) if i not in used]
             for name in want:
-                fill = {"top_ps": 1.0, "lanes": engine.max_batch}.get(name, 0)
+                fill = {"top_ps": 1.0, "lanes": engine.max_batch,
+                        "src": -1}.get(name, 0)
                 assert (np.asarray(got[name])[idle] == fill).all(), name
         chunk_beside_decode |= len(groups) == 2 and all(
             used for _, used in groups)
         fewer |= n > 0 and n_rows < steps[n - 1][1]
     assert chunk_beside_decode
     assert fewer
+    # a decode row of a step launched before its predecessor was read names
+    # that step's result row; a drafting engine reads every step first
+    fed = any((a["src"] >= 0).any() for _, _, groups, _ in steps
+              for a, _ in groups[:1])
+    assert fed != extras
     # the sampling knobs reached the program as the floats they were
     first = steps[0][2][-1][0]     # the prompts' first chunks
     assert first["temps"][0] == np.float32(0.7)
@@ -263,9 +279,11 @@ def test_a_seeded_engine_samples_with_the_hosts_chain_of_keys(
         emitted.append({o.request_id: o.token for o in engine.step()})
         lanes.append(list(engine.last_planned))
         jax.effects_barrier()
-        assert _same_key(engine._key, _chain(len(emitted))[-1][0])
+        # a call may launch the next step before it reads this one
+        assert _same_key(engine._key, _chain(engine.step_count)[-1][0])
     n = len(emitted)
-    assert len(seen) == n == engine.metrics.counters["host_syncs"]
+    assert len(seen) == n == engine.step_count \
+        == engine.metrics.counters["host_syncs"]
     assert {w for _, w in engine._step_fns} == {1, CHUNK}
     assert any(emitted)
     for (_, sub), args, toks, plan in zip(_chain(n), seen, emitted, lanes):
@@ -343,12 +361,18 @@ def test_a_step_that_raises_leaves_a_defined_key(model, monkeypatch, how):
     assert engine.metrics.counters["engine_step_errors"] == 1
     assert not failures and got == want
     # the key advanced once a launch that returned, never otherwise, and
-    # was neither donated nor left half-assigned
+    # was neither donated nor left half-assigned: one planned step was
+    # never launched
     assert not engine._key.is_deleted()
-    launches = int(engine.metrics.counters["host_syncs"])
+    launches = engine.step_count - 1
     assert _same_key(engine._key, _chain(launches)[-1][0])
+    syncs = int(engine.metrics.counters["host_syncs"])
     if how == "program-call":
         assert calls["n"] == launches + 1
+        # the step in flight when the launch behind it raised: never read
+        assert syncs == launches - 1
+    else:
+        assert syncs == launches
 
 
 def test_a_raising_step_raises_to_a_bare_caller_and_the_next_one_runs(model):
@@ -397,9 +421,10 @@ def test_tp2_emits_tp1s_tokens_from_one_replicated_buffer(model):
     # the program's argument list is stated once, for the mesh too
     for W in two.width_buckets:
         args = two.step_program_args(W)
-        packed, key = args[-2:]
+        packed, prev, key = args[-3:]
         assert packed.shape == (two._step_layout(W)[1],)
         assert packed.dtype == np.int32 and key.shape == two._key.shape
+        assert prev.shape == two._prev.shape == (two.max_batch, 3)
         assert args[2] is two.pool.k and args[3] is two.pool.v
     assert sorted(two.lowered_step_programs()) == sorted(
         two.step_program_shapes())
