@@ -8,8 +8,9 @@ full width of models the repo ships, with seeded random weights:
 - kernels: the ragged paged-attention kernel and the append's block writer
   (bf16 and int8 arenas, every width the serve phase compiles) and the
   three flash kernels (forward, dK/dV, dQ), and the routed experts'
-  grouped matmul at the hybrid configuration's shapes, compiled (not
-  interpreted), against their XLA references, the writer bit for bit;
+  grouped matmul and the decode group's Mamba state update at the hybrid
+  configuration's shapes, compiled (not interpreted), against their XLA
+  references, the writer bit for bit;
 - serve:   `gpt_1p3b` in bf16 behind `ServingServer` on 127.0.0.1:0, nine
   requests over `/v1/completions`, one of them streamed (SSE);
 - train:   `gpt_small` in bf16, AdamW, five `make_sharded_train_step` steps;
@@ -406,8 +407,60 @@ def check_grouped_matmul(key, *, rows, inner, outer, groups, hit):
     return err
 
 
+def check_ssm_state_update(key, *, layers, lanes, heads, head_dim, state,
+                           groups):
+    """The decode group's Mamba state update (ops/pallas/ssm_state_update.py)
+    on a leaf of `layers` layers, layer 1 of it, against `ssm_chunk` at
+    width 1 in float32 at `highest` (models/nemotron_h.py): lane 0 starts
+    from zeros, lane 1 carries no token. `y` and the new state differ in
+    float32's order of sums only: 1e-5 of their scale holds with room, and a
+    wrong lane, head, group or decay moves them by the scale itself. The
+    idle lane and every other layer must come back bit for bit. Returns the
+    worst error over the scale."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.models.nemotron_h import ssm_chunk
+    from paddle_tpu.ops.pallas.ssm_state_update import ssm_state_update
+
+    ks = jax.random.split(key, 6)
+    live = jnp.arange(lanes) != 1
+    fresh = jnp.arange(lanes) == 0
+    S = jax.random.normal(ks[0], (layers, lanes, heads, head_dim, state))
+    x = jnp.where(live[:, None, None],
+                  jax.random.normal(ks[1], (lanes, heads, head_dim)), 0.0)
+    B = jax.random.normal(ks[2], (lanes, groups, state))
+    C = jax.random.normal(ks[3], (lanes, groups, state))
+    dt = jnp.where(live[:, None], jax.random.uniform(
+        ks[4], (lanes, heads), minval=0.001, maxval=0.1), 0.0)
+    A = -jax.random.uniform(ks[5], (heads,), minval=1.0, maxval=16.0)
+    with jax.default_matmul_precision("highest"):
+        want_y, want_S = jax.jit(lambda S: ssm_chunk(
+            x[:, None], B[:, None], C[:, None], dt[:, None], A,
+            jnp.where(fresh[:, None, None, None], 0.0, S[1])))(S)
+    want_y, want_S = np.asarray(want_y[:, 0]), np.asarray(want_S)
+    before = np.asarray(S)
+    y, S = jax.jit(lambda S: ssm_state_update(S, 1, x, B, C, dt, A, fresh,
+                                              live), donate_argnums=0)(S)
+    y, S = np.asarray(y), np.asarray(S)
+    what = (f"ssm state update {layers} x {lanes} x {heads} x {head_dim} x "
+            f"{state}, {groups} groups")
+    require(np.array_equal(S[1, 1], before[1, 1]),
+            f"{what}: the idle lane's state moved")
+    require(all(np.array_equal(S[i], before[i])
+                for i in range(layers) if i != 1),
+            f"{what}: another layer's state moved")
+    err = max(float(np.abs(got - want)[2:].max() / np.abs(want[2:]).max())
+              for got, want in ((y, want_y), (S[1], want_S)))
+    err = max(err, float(np.abs(S[1, 0] - want_S[0]).max()
+                         / np.abs(want_S[0]).max()))
+    require(err < 1e-5, f"{what}: off by {err:.3g} of the scale")
+    return err
+
+
 def kernel_phase(*, ragged_shapes, widths, flash_shapes, grouped_shapes=(),
-                 seed=0):
+                 state_shapes=(), seed=0):
     """Every kernel variant the later phases compile, at their shapes."""
     import jax
 
@@ -450,6 +503,10 @@ def kernel_phase(*, ragged_shapes, widths, flash_shapes, grouped_shapes=(),
         key, sub = jax.random.split(key)
         err = check_grouped_matmul(sub, **shape)
         log(f"grouped matmul {shape}: worst |err| {err:.3g} of the scale")
+    for shape in state_shapes:
+        key, sub = jax.random.split(key)
+        err = check_ssm_state_update(sub, **shape)
+        log(f"ssm state update {shape}: worst |err| {err:.3g} of the scale")
     require(not _backend.XLA_FALLBACKS,
             f"a kernel's dispatch took the XLA path: "
             f"{dict(_backend.XLA_FALLBACKS)}")
@@ -777,9 +834,13 @@ def run(report, clock):
     grouped = [dict(rows=r, inner=k, outer=n, groups=128, hit=h)
                for r, h in ((704, 85), (3520, 128))
                for k, n in ((1024, 2688), (2688, 1024))]
+    # its decode group's Mamba state: the leaf of 5 layers and 32 lanes
+    state = [dict(layers=5, lanes=32, heads=128, head_dim=64, state=128,
+                  groups=8)]
     with phase("kernels", clock, report):
         kernel_phase(ragged_shapes=ragged, widths=(1, 5, 128),
-                     flash_shapes=flash, grouped_shapes=grouped)
+                     flash_shapes=flash, grouped_shapes=grouped,
+                     state_shapes=state)
 
     # nine requests: eight at once, mixed lengths, one longer than a
     # prefill chunk (128); then the first again. No depth cut: 24 layers.

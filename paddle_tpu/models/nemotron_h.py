@@ -62,8 +62,14 @@ the same with one token. Slots past a row's live tokens and lanes without a
 request get `dt = 0` (decay 1, nothing added) and leave the convolution's
 window alone, so the state a row carries is a function of its live tokens
 only. A row flagged `fresh` (its first chunk, or its first after
-preemption) starts from zeros. The plain path (`caches` None) runs the same
-chunk form over the whole sequence, `chunk_size` tokens at a time.
+preemption) starts from zeros. The decode group (one token a row, row i
+lane i) updates its lanes' state where it lies: the kernel
+`ssm_state_update` (`ops/pallas/ssm_state_update.py`, where the kernels are
+on and the state is float32 with a state size that is a multiple of 128)
+reads a layer of the leaf once and writes the new state back into it; a
+chunk group gathers its rows' lanes of the leaf and scatters them back. The
+plain path (`caches` None) runs the same chunk form over the whole
+sequence, `chunk_size` tokens at a time.
 
 Precision. Weights in the type they are given (bfloat16 when served);
 matrix products take operands in that type and accumulate in float32; norms,
@@ -88,6 +94,8 @@ import jax.numpy as jnp
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn import initializer as I
+from ..ops.pallas import ssm_state_update as state_update
+from ..ops.pallas._backend import note_xla_fallback
 from ..ops.pallas.grouped_matmul import grouped_matmul
 from .experts import (held_expert_sum, pair_counts, sigmoid_route,
                       sum_step_stats)
@@ -228,6 +236,16 @@ class MambaMixer(nn.Layer):
         `live` `[b, w]` which slots hold a token (a prefix of each row) and
         `n_live` `[b]` how many. Returns `(out [b, w, hidden], S_out,
         window_out)`."""
+        z, x, B, C, dt, window_out = self.mix_in(a, window, live, n_live)
+        y, S_out = ssm_chunk(x, B, C, dt, self.decay_rate(),
+                             S_in.astype(jnp.float32))
+        return self.mix_out(y, x, z), S_out.astype(S_in.dtype), window_out
+
+    def mix_in(self, a, window, live, n_live):
+        """What the scan reads, from the stream and the convolution's
+        window (arguments as `forward`'s): `(z [b, w, inner], x [b, w,
+        heads, p], B, C [b, w, groups, n], dt [b, w, heads], window_out)`,
+        `x` and `dt` 0 in the slots that hold no token."""
         cfg = self.cfg
         b, w, _ = a.shape
         inner, heads, groups = (cfg.mamba_inner, cfg.mamba_num_heads,
@@ -255,8 +273,19 @@ class MambaMixer(nn.Layer):
         C = C.reshape(b, w, groups, N)
         dt = jax.nn.softplus(dt + self.dt_bias._array.astype(jnp.float32))
         dt = jnp.where(live[..., None], dt, 0.0)
-        A = -jnp.exp(self.A_log._array.astype(jnp.float32))
-        y, S_out = ssm_chunk(x, B, C, dt, A, S_in.astype(jnp.float32))
+        return z, x, B, C, dt, window_out
+
+    def decay_rate(self):
+        """`A` `[heads]` float32: a head's state decays by `exp(dt A)`."""
+        return -jnp.exp(self.A_log._array.astype(jnp.float32))
+
+    def mix_out(self, y, x, z):
+        """The layer's output `[b, w, hidden]` from the scan's `y` `[b, w,
+        heads, p]` and `mix_in`'s `x` and `z`: `D x`, the gated norm by
+        group and `out_proj`."""
+        cfg = self.cfg
+        b, w = z.shape[:2]
+        inner, groups = cfg.mamba_inner, cfg.n_groups
         y = y + self.D._array.astype(jnp.float32)[:, None] * x
         g = (y.reshape(b, w, inner) * jax.nn.silu(z)).reshape(
             b, w, groups, inner // groups)
@@ -264,7 +293,7 @@ class MambaMixer(nn.Layer):
                               + cfg.norm_eps)
         g = g.reshape(b, w, inner) \
             * self.gate_norm.weight._array.astype(jnp.float32)
-        return _project(g, self.out_proj), S_out.astype(S_in.dtype), window_out
+        return _project(g, self.out_proj)
 
 
 class GroupedQueryAttention(nn.Layer):
@@ -442,14 +471,32 @@ class NemotronH(nn.Layer):
         """Mamba layer `i` over one group `g` of a step's rows, `a` `[rows,
         width, hidden]`: the rows' state out of the pool's `st` and back.
         A group whose row i is lane i (`g.lanes` None) uses the state in
-        place; another gathers its rows' lanes and scatters them back (an
+        place: one token a row, through the kernel `ssm_state_update`
+        where the leaf takes it (`ops/pallas/ssm_state_update.py
+        why_xla`), which writes the new state into the leaf itself.
+        Another group gathers its rows' lanes and scatters them back (an
         idle row names no lane: its update is dropped)."""
         live = g.live_slots()
         n_live = jnp.sum(live, axis=1).astype(jnp.int32)
-        S_in, window = st["ssm"][i], st["conv"][i]
-        if g.lanes is not None:
-            S_in = jnp.take(S_in, g.lanes, axis=0, mode="clip")
-            window = jnp.take(window, g.lanes, axis=0, mode="clip")
+        if g.lanes is None and g.width == 1:
+            why = state_update.why_xla(st["ssm"])
+            if why is None:
+                fresh = g.fresh != 0
+                z, x, B, C, dt, window = mixer.mix_in(
+                    a, jnp.where(fresh[:, None, None], 0, st["conv"][i]),
+                    live, n_live)
+                y, st["ssm"] = state_update.ssm_state_update(
+                    st["ssm"], i, x[:, 0], B[:, 0], C[:, 0], dt[:, 0],
+                    mixer.decay_rate(), fresh, live[:, 0])
+                st["conv"] = st["conv"].at[i].set(window)
+                return mixer.mix_out(y[:, None], x, z)
+            note_xla_fallback("ssm_state_update", why)
+        if g.lanes is None:
+            S_in, window = st["ssm"][i], st["conv"][i]
+        else:
+            # the rows' lanes alone, not the whole layer first
+            S_in = st["ssm"].at[i, g.lanes].get(mode="clip")
+            window = st["conv"].at[i, g.lanes].get(mode="clip")
         fresh = g.fresh != 0
         part, S_out, window = mixer(
             a, jnp.where(fresh[:, None, None, None], 0, S_in),

@@ -171,3 +171,68 @@ def test_select_time_share_is_none_where_the_selection_sorts():
     bare = Run({}, {"name": "cell"}, CONFIG, {}, None, V5E, None, 0.0)
     bare.engine_steps = steps
     assert _read("kernel.select_time_share", bare) is None
+
+
+# -- the two readers of the decode group's Mamba state update, on the
+# same hand-made captures. CONFIG has two Mamba layers of 2 heads x 3 x 5:
+# a row's state is 120 bytes a layer, read and written: 480 bytes a step.
+
+UPDATE = "%ssm_state_update.{} = (f32[2,4,2,3,5]{{4,3,2,1,0}}, ...)"
+
+
+def _updates(start, n, each=1_000_000, first=0):
+    return [(s, e, UPDATE.format(first + i)) for i, (s, e, _) in enumerate(
+        _calls(start, n, each=each))]
+
+
+def test_state_update_readers_on_whole_steps():
+    """Two decode steps the trace holds whole, 2 x 1 ms and 2 x 2 ms of
+    kernel over 4 and 3 rows, beside the grouped products and a fusion;
+    a chunk-carrying step whose calls count for the time share alone."""
+    runs = {1: (10_000_000, 20_000_000), 2: (30_000_000, 45_000_000),
+            3: (50_000_000, 60_000_000)}
+    ops = (_updates(11_000_000, 2) + _updates(31_000_000, 2, each=2_000_000)
+           + _updates(51_000_000, 2, first=4)
+           + _calls(14_000_000, 4, each=500_000)
+           + [(40_000_000, 44_000_000, "%fusion.7 = ...")])
+    steps = [_decode(1, 0.010, 0.021), _decode(2, 0.030, 0.046,
+                                               state_rows=3),
+             _step(3, "mixed", 0.050, 0.061, fed_tokens=9, emitted_tokens=1,
+                   moe_pairs=36, moe_pairs_local=9, moe_experts_hit=4,
+                   moe_max_load=5, state_rows=2, kv_tokens=9)]
+    run = _traced(ops, runs, steps)
+    share, calls = _read("kernel.state_update_time_share", run)
+    assert calls == 6
+    # busy: 2 + 4 + 2 of updates, 4 x 0.5 of products, 4 of fusion
+    assert share == pytest.approx(100 * 8e-3 / 14e-3)
+    value, n = _read("kernel.state_update_decode_roofline", run)
+    assert n == 2
+    assert value == pytest.approx(100 * (4 + 3) * 480 / 819e9 / 6e-3)
+    # a step whose run holds one of its two calls is not counted
+    runs[2] = (30_000_000, 32_500_000)
+    value, n = _read("kernel.state_update_decode_roofline",
+                     _traced(ops, runs, steps))
+    assert n == 1 and value == pytest.approx(100 * 4 * 480 / 819e9 / 2e-3)
+
+
+def test_state_update_readers_are_none_without_the_kernel():
+    """A program whose update is the compiler's fusion and
+    `bitcast_dynamic-update-slice_fusion`, no operation of the name; a run
+    with no capture; spans without `state_rows`."""
+    ops = [(11_000_000, 12_000_000,
+            "%bitcast_dynamic-update-slice_fusion.3 = f32[5,32,128,64,128]"),
+           (13_000_000, 14_000_000, "%fusion.7 = ...")]
+    steps = [_decode(1, 0.010, 0.021)]
+    names = ("kernel.state_update_time_share",
+             "kernel.state_update_decode_roofline")
+    run = _traced(ops, {1: (10_000_000, 20_000_000)}, steps)
+    for name in names:
+        assert _read(name, run) is None
+        bare = Run({}, {"name": "cell"}, CONFIG, {}, None, V5E, None, 0.0)
+        bare.engine_steps = steps
+        assert _read(name, bare) is None
+    plain = [_step(1, "decode", 0.010, 0.021, fed_tokens=4, emitted_tokens=4,
+                   kv_tokens=40)]
+    run = _traced(_updates(11_000_000, 2), {1: (10_000_000, 20_000_000)},
+                  plain)
+    assert _read("kernel.state_update_decode_roofline", run) is None

@@ -8,6 +8,7 @@ import os
 import subprocess
 import sys
 
+import jax.numpy as jnp
 import pytest
 
 import paddle_tpu as paddle
@@ -58,6 +59,32 @@ def test_kernel_phase_catches_a_wrong_grouped_matmul(monkeypatch):
     with pytest.raises(chip_smoke.SmokeFailure, match="grouped matmul"):
         chip_smoke.kernel_phase(ragged_shapes=[], widths=(), flash_shapes=[],
                                 grouped_shapes=[dict(GROUPED[0], rows=64)])
+
+
+STATE = dict(layers=3, lanes=4, heads=16, head_dim=8, state=128, groups=2)
+
+
+def test_kernel_phase_ssm_state_update_interpreted():
+    chip_smoke.kernel_phase(ragged_shapes=[], widths=(), flash_shapes=[],
+                            state_shapes=[STATE])
+
+
+def test_kernel_phase_catches_a_wrong_state_update(monkeypatch):
+    """A state update that hands a lane's new state to the next lane fails
+    the phase."""
+    from paddle_tpu.ops.pallas import ssm_state_update as module
+
+    real = module.ssm_state_update
+
+    def shifted(S, layer, *a):
+        y, S = real(S, layer, *a)
+        return y, S.at[layer].set(S[layer, (jnp.arange(S.shape[1]) + 1)
+                                    % S.shape[1]])
+
+    monkeypatch.setattr(module, "ssm_state_update", shifted)
+    with pytest.raises(chip_smoke.SmokeFailure, match="ssm state update"):
+        chip_smoke.kernel_phase(ragged_shapes=[], widths=(), flash_shapes=[],
+                                state_shapes=[STATE])
 
 
 def test_kernel_phase_catches_a_wrong_kernel(monkeypatch):

@@ -71,7 +71,19 @@ def compile_for_v5e(fn, *args, case=None):
     if case is not None:
         NAMES[case] = kernel_names(compiled)
         SCATTERS[case] = len(re.findall(r" scatter\(", compiled.as_text()))
+        COMPILED[case] = compiled
     return n
+
+
+COMPILED = {}  # case -> the compiled program
+
+
+def leaf_facts(compiled, shape):
+    """What a compiled program does with the array of `shape` (float32):
+    copies of it, and the bytes aliased input to output."""
+    relaid = re.compile(r"= f32\[%s\]\S* copy\(" % ",".join(map(str, shape)))
+    return {"copies": len(relaid.findall(compiled.as_text())),
+            "alias_bytes": compiled.memory_analysis().alias_size_in_bytes}
 
 
 from paddle_tpu.ops.pallas import flash_attention as fa
@@ -242,20 +254,41 @@ for rows in (64, 32):
         sds((rows, 17408), "float32"), sds((rows,), "int32"),
         case=case) == 1, case
 
-# the hybrid block (models/nemotron_h.py): one Mamba, one attention and one
+# the decode group's Mamba state update alone (ops/pallas/ssm_state_update.py)
+# at the hybrid cell's leaf, [5 layers, 32 lanes, 128 heads, 64, 128] float32
+# with 8 groups: layer 2 read and written in place, the leaf aliased
+from paddle_tpu.ops.pallas import ssm_state_update as su
+
+STATE = {}     # case -> what the compiled program does with the state leaf
+LEAF = (5, 32, 128, 64, 128)
+assert compile_for_v5e(
+    jax.jit(lambda S, x, B, C, dt, A, fresh, live: su.ssm_state_update(
+        S, 2, x, B, C, dt, A, fresh, live), donate_argnums=0),
+    sds(LEAF, "float32"), sds((32, 128, 64), "float32"),
+    sds((32, 8, 128), "float32"), sds((32, 8, 128), "float32"),
+    sds((32, 128), "float32"), sds((128,), "float32"), sds((32,), "bool"),
+    sds((32,), "bool"), case="ssm-state-update-cell") == 1
+STATE["ssm-state-update-cell"] = dict(
+    leaf_facts(COMPILED["ssm-state-update-cell"], LEAF),
+    leaf_bytes=4 * 5 * 32 * 128 * 64 * 128)
+
+# the hybrid block (models/nemotron_h.py): two Mamba, one attention and one
 # expert layer; the arena has the model's 2 K/V heads under 4 query heads,
-# the pool's lane state rides beside it, and the expert layer's two grouped
+# the pool's lane state rides beside it, the decode group's Mamba layers
+# update it through `ssm_state_update`, and the expert layer's two grouped
 # products (latent 128, expert 256: widths the kernel takes) are
-# `grouped_matmul` kernels (the dispatch asks the default backend, the CPU
-# here, so its gate is opened by hand as the attention's is above)
+# `grouped_matmul` kernels (the dispatches ask the default backend, the CPU
+# here, so their gates are opened by hand as the attention's is above)
 from paddle_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 
 gm.use_pallas = lambda: True
 gm.interpret_mode = lambda: False
+su.use_pallas = lambda: True
+su.interpret_mode = lambda: False
 
 engine = LLMEngine(
     NemotronH(NemotronHConfig(
-        vocab_size=256, hidden_size=256, pattern="M*E", num_heads=4,
+        vocab_size=256, hidden_size=256, pattern="MM*E", num_heads=4,
         num_kv_heads=2, head_dim=128, mamba_num_heads=8, mamba_head_dim=64,
         ssm_state_size=128, n_groups=2, chunk_size=32, moe_latent_size=128,
         moe_intermediate_size=256, shared_intermediate_size=256,
@@ -263,9 +296,15 @@ engine = LLMEngine(
         max_seq_len=256)),
     block_size=16, max_batch=4, max_seq_len=256, prefill_chunk=32)
 assert engine.pool.k.shape[:2] == (1, 2)
+leaf = engine.pool.lane_state["ssm"]
 for W in engine.width_buckets:
     compile_for_v5e(engine._get_step_fn(B, W), *step_args(engine, W),
                     case=f"hybrid-step-w{W}")
+    STATE[f"hybrid-step-w{W}"] = dict(
+        leaf_facts(COMPILED[f"hybrid-step-w{W}"], leaf.shape),
+        leaf_bytes=leaf.nbytes)
+print("STATE_FACTS " + json.dumps(STATE))
+COMPILED.clear()
 
 # the arena through a step program: appended to and read by the kernel in
 # one layout, so the compiler relays it nowhere. Heads of 128 and arenas of
@@ -336,6 +375,8 @@ EXPECTED = {
        for m in (704, 3520) for k, n in ((1024, 2688), (2688, 1024))},
     # the full layers' selection at the dots3 cell's shapes
     **{f"topk-select-q{q}": {"topk_select": 1} for q in (64, 32)},
+    # the decode group's Mamba state update at the hybrid cell's leaf
+    "ssm-state-update-cell": {"ssm_state_update": 1},
 }
 
 
@@ -352,7 +393,24 @@ def test_hybrid_step_programs_hold_one_kernel_pair_a_group(aot):
         assert got.get("paged_kv_append") == groups, got
         assert got.get("ragged_paged_attention") == groups, got
         assert got.get("grouped_matmul") == 2, got
+        # the decode group's two Mamba layers; the chunk group's rows
+        # gather and scatter their lanes' state on the XLA path
+        assert got.get("ssm_state_update") == 2, got
         assert not any("ragged-dot" in name for name in got), got
+
+
+@pytest.mark.parametrize("case", ["ssm-state-update-cell", "hybrid-step-w1",
+                                  "hybrid-step-w32"])
+def test_the_state_leaf_is_updated_where_it_lies(aot, case):
+    """The kernel writes the layer it updates into the leaf it was handed
+    (aliased), and no program copies the leaf: not the kernel alone at the
+    hybrid cell's shapes, not the step programs, in which the decode group's
+    two Mamba layers update it one after the other and the chunk group's
+    rows gather and scatter their own lanes. The step programs still alias
+    the donated leaf to their outputs."""
+    f = _facts(aot, "STATE_FACTS")[case]
+    assert f["copies"] == 0, f
+    assert f["alias_bytes"] >= f["leaf_bytes"], f
 
 
 @pytest.fixture(scope="module")

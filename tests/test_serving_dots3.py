@@ -196,17 +196,26 @@ def test_hybrid_step_programs_are_textually_the_parents(setup, kw):
     commit with this jax). GPT's and the looped decoder's are held by
     `tests/test_serving_looped.py` and `tests/test_append_dispatch.py`.
     Every program moved once since, when a step took the previous step's
-    result as one more operand (`tests/step_programs_prev_operand.json`)."""
+    result as one more operand (`tests/step_programs_prev_operand.json`).
+    The hybrid's chunk-carrying program moved again when its chunk group
+    came to read only its rows' lanes of the state leaf (the record before,
+    when it sliced the whole layer first:
+    `tests/hybrid_step_programs_layer_slice.json`). The decode-only program
+    keeps its text here, where the decode group's state update is the XLA
+    form (off the chip the kernel `ssm_state_update` is not taken)."""
     parents = json.loads(
         (REPO / "tests/hybrid_step_programs_pr39.json").read_text())[setup]
+    before = json.loads((REPO / "tests/hybrid_step_programs_layer_slice.json")
+                        .read_text())[setup]
     want = json.loads((REPO / "tests/step_programs_prev_operand.json")
                       .read_text())["hybrid"][setup]
     engine = LLMEngine(hybrid.build(**kw), **KW)
     got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()
            for name, low in engine.lowered_step_programs().items()}
     assert got == want
-    assert sorted(parents) == sorted(got)
+    assert sorted(parents) == sorted(got) == sorted(before)
     assert all(parents[name] != h for name, h in got.items())
+    assert got["w1"] == before["w1"] and got["w8"] != before["w8"]
 
 
 def test_the_benchmark_has_the_configuration_and_its_cell():

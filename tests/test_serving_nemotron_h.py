@@ -321,7 +321,10 @@ def test_the_benchmark_has_the_configuration_and_its_cell():
         "moe.local_pair_share", "moe.experts_hit_share",
         "moe.load_max_over_mean", "pool.state_hbm_share",
         # PR 39: the routed experts' grouped matmul, by its own name
-        "kernel.grouped_time_share", "kernel.grouped_decode_roofline"])
+        "kernel.grouped_time_share", "kernel.grouped_decode_roofline",
+        # the decode group's Mamba state update, by its own name
+        "kernel.state_update_time_share",
+        "kernel.state_update_decode_roofline"])
     for named in (entry["file"], config["reference"],
                   "benchmark/traffic/turns.json",
                   "benchmark/reduce/hybrid.py",
