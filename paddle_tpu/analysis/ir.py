@@ -359,10 +359,10 @@ def tiny_gpt_config():
 def build_serving_engine(model, tp_degree, kv_dtype=None,
                          quant_allreduce=None, lora_slots=0, lora_rank=4):
     """The harness engine: spec decoding ON so every default width
-    bucket exists (w1 decode, w4 spec, w8 chunk); mesh=1 is the explicit
-    single-chip request (beats a stray PADDLE_TPU_TP env,
-    serving/sharded.py). ``kv_dtype``/``quant_allreduce`` select the
-    int8 program family (quantized arena + EQuARX collectives);
+    bucket exists (w1 decode, w4 spec, w8 chunk); mesh=1 is the
+    single-chip engine (serving/sharded.py).
+    ``kv_dtype``/``quant_allreduce`` select the int8 program family
+    (quantized arena + EQuARX collectives);
     ``lora_slots`` the serve_lora family (stacked adapter tables gathered
     per row inside the same unified step)."""
     from ..serving.engine import LLMEngine

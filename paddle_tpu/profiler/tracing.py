@@ -31,17 +31,16 @@ the join fits ONE offset over all of them; the export carries the epoch
 (``otherData.epoch_ns``), so a dumped trace is enough to redo the fit.
 
 **Off by default, free when off**: training code asks `train_tracer()`
-for the process-wide tracer and gets None unless ``PADDLE_TPU_TRACE`` is
-set (or `enable_train_tracing()` was called); every hook site is a single
-``if tr is not None`` pointer test, so the untraced step is byte-identical
-to the pre-trace code path. ``PADDLE_TPU_TRACE_BUF`` bounds the ring
-(default 65536 events) exactly as it does for serving.
+for the process-wide tracer and gets None unless `enable_train_tracing()`
+was called; every hook site is a single ``if tr is not None`` pointer
+test, so the untraced step is byte-identical to the pre-trace code path.
+Its ``capacity`` bounds the ring (default 65536 events), as the engine's
+``trace_buffer`` does for serving.
 """
 from __future__ import annotations
 
 import contextlib
 import json
-import os
 import threading
 import time
 from collections import deque
@@ -49,28 +48,6 @@ from collections import deque
 # The xplane join key: host step spans and the TraceAnnotation wrapping the
 # matching device dispatch share "paddle_tpu.step <id>".
 STEP_ANNOTATION_PREFIX = "paddle_tpu.step "
-
-
-def trace_sample_from_env(env="PADDLE_TPU_TRACE"):
-    """The PADDLE_TPU_TRACE knob as a sampling fraction: unset/falsy -> 0.0
-    (tracing off), truthy -> 1.0, a float string -> that fraction of
-    requests (clamped to [0, 1]; step spans are always on while > 0)."""
-    v = os.environ.get(env, "").strip().lower()
-    if v in ("", "0", "0.0", "false", "off", "no"):
-        return 0.0
-    try:
-        f = float(v)
-    except ValueError:
-        return 1.0
-    return min(max(f, 0.0), 1.0)
-
-
-def trace_capacity_from_env(env="PADDLE_TPU_TRACE_BUF", default=65536):
-    try:
-        cap = int(os.environ.get(env, "") or default)
-    except ValueError:
-        cap = default
-    return max(16, cap)
 
 
 class Tracer:
@@ -307,49 +284,31 @@ class InstrumentedStep:
 # process singleton: every producer asks `train_tracer()` per step and gets
 # None (one pointer test, nothing else) unless tracing is on.
 
-_explicit = None        # set by enable_/disable_train_tracing
-_explicit_set = False
-_env_tracer = None      # lazily created when PADDLE_TPU_TRACE asks for it
+_tracer = None          # set by enable_/disable_train_tracing
 
 
 def train_tracer():
     """The process-wide `TrainTracer`, or None when training tracing is
-    off. `enable_train_tracing()`/`disable_train_tracing()` win; otherwise
-    ``PADDLE_TPU_TRACE`` (any truthy value — sampling fractions apply to
-    serving requests, not training steps) turns it on with a
-    ``PADDLE_TPU_TRACE_BUF``-sized ring."""
-    if _explicit_set:
-        return _explicit
-    if trace_sample_from_env() <= 0.0:
-        return None
-    global _env_tracer
-    if _env_tracer is None:
-        _env_tracer = TrainTracer(capacity=trace_capacity_from_env())
-    return _env_tracer
+    off (`enable_train_tracing` turns it on)."""
+    return _tracer
 
 
-def enable_train_tracing(capacity=None):
-    """Turn training tracing on programmatically (overrides the env);
+def enable_train_tracing(capacity=65536):
+    """Turn training tracing on with a fresh ring of ``capacity`` events;
     returns the tracer."""
-    global _explicit, _explicit_set
-    _explicit = TrainTracer(
-        capacity=trace_capacity_from_env() if capacity is None
-        else max(16, int(capacity)))
-    _explicit_set = True
-    return _explicit
+    global _tracer
+    _tracer = TrainTracer(capacity=max(16, int(capacity)))
+    return _tracer
 
 
 def disable_train_tracing():
-    """Force training tracing off regardless of the environment."""
-    global _explicit, _explicit_set
-    _explicit = None
-    _explicit_set = True
+    """Turn training tracing off."""
+    global _tracer
+    _tracer = None
 
 
 def reset_train_tracing():
-    """Back to env-driven behavior with a fresh tracer (tests; long
-    processes that want to drop a recorded trace)."""
-    global _explicit, _explicit_set, _env_tracer
-    _explicit = None
-    _explicit_set = False
-    _env_tracer = None
+    """Back to off, dropping the tracer and what it recorded (tests; long
+    processes that want to drop a recorded trace): the same as
+    `disable_train_tracing`."""
+    disable_train_tracing()

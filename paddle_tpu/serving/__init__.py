@@ -11,24 +11,23 @@ decoding only) one verify step — regardless of traffic or prompt lengths.
 Automatic prefix caching (ref-counted content-hashed blocks with a
 cached-free LRU tier and copy-on-write) is on by default — shared system
 prompts/few-shot templates skip their prefill on every hit; disable with
-``PADDLE_TPU_PREFIX_CACHE=0`` or ``LLMEngine(prefix_cache=False)``.
-A host-memory KV tier (serving/kv_tier.py, ``LLMEngine(host_kv_blocks=N)``
-or ``PADDLE_TPU_HOST_KV_BLOCKS=N``) catches cached blocks the device LRU
-evicts, swaps them back on a prefix hit via a donated scatter dispatched
-at plan time, and doubles as the fleet's block-transport substrate for
-zero-rewarm drains and cross-replica migration. See README "Tiered KV
-cache".
+``LLMEngine(prefix_cache=False)``. A host-memory KV tier
+(serving/kv_tier.py, ``LLMEngine(host_kv_blocks=N)``) catches cached
+blocks the device LRU evicts, swaps them back on a prefix hit via a
+donated scatter dispatched at plan time, and doubles as the fleet's
+block-transport substrate for zero-rewarm drains and cross-replica
+migration. See README "Tiered KV cache".
 Speculative decoding (serving/spec.py: prompt-lookup n-gram drafting +
 batched parallel verification, no draft model) is OFF by default — enable
-with ``LLMEngine(spec_decoding=True)`` or ``PADDLE_TPU_SPEC_DECODE=1`` to
-score up to ``num_spec_tokens + 1`` decode positions per step; greedy
-outputs stay token-for-token identical to non-speculative decode.
+with ``LLMEngine(spec_decoding=True)`` to score up to
+``num_spec_tokens + 1`` decode positions per step; greedy outputs stay
+token-for-token identical to non-speculative decode.
 
 **Tensor-parallel serving** (serving/sharded.py): pass ``mesh=N`` (or a
-`build_serving_mesh` handle, or ``PADDLE_TPU_TP=N``) to shard weights and
-the head-major KV arena over a ``tp`` NamedSharding mesh — attention
-heads and FFN columns on ``tp``, block tables/scheduler/prefix-cache
-refcounts host-side and unchanged, still one unified ragged program compiled per width bucket
+`build_serving_mesh` handle) to shard weights and the head-major KV arena
+over a ``tp`` NamedSharding mesh — attention heads and FFN columns on
+``tp``, block tables/scheduler/prefix-cache refcounts host-side and
+unchanged, still one unified ragged program compiled per width bucket
 programs. Greedy sharded output is token-for-token identical to the
 single-chip engine. See README "Sharded serving".
 
@@ -76,22 +75,23 @@ drain — and runs every step under the fault-tolerance layer
 (supervisor.py): poison-request isolation by bisection, a stuck-step
 watchdog, crash-safe thread exit, and non-finite containment, all
 testable on demand via deterministic fault injection (faults.py,
-``PADDLE_TPU_FAULTS``). See README "Failure model".
+`faults.install`; the demo server arms ``PADDLE_TPU_FAULTS``). See README
+"Failure model".
 `ServingServer` (server.py, stdlib-only) exposes it over HTTP:
 OpenAI-style `/v1/completions` with SSE streaming, `/healthz` (with pool
 saturation gauges), and a Prometheus `/metrics` endpoint. Observability
-(serving/trace.py, ``PADDLE_TPU_TRACE``): a ring-buffered per-request
+(serving/trace.py, ``LLMEngine(trace=...)``): a ring-buffered per-request
 lifecycle + engine-step tracer exporting Perfetto-loadable JSON at
 ``GET /debug/trace``, joinable to device xplane captures by step id;
-``PADDLE_TPU_REQUEST_LOG=1`` adds one JSON summary log line per request.
-The SLO ledger (serving/slo.py, ``PADDLE_TPU_SLO``) decomposes every
-request's wall time into exhaustive phases (queued / prefill / decode /
-preempted / stalled / emit — they sum to e2e by construction), rolls up
+``request_log=True`` adds one JSON summary log line per request. The SLO
+ledger (serving/slo.py, ``slo=True``) decomposes every request's wall
+time into exhaustive phases (queued / prefill / decode / preempted /
+stalled / emit — they sum to e2e by construction), rolls up
 per-tenant/priority classes (p95 TTFT, TPOT, deadline attainment) at
 ``GET /debug/slo``, and exports true labeled Prometheus histograms; the
-fault flight recorder (serving/postmortem.py,
-``PADDLE_TPU_POSTMORTEM_DIR``) writes one pruned on-disk postmortem
-bundle per supervisor fault event, listable at ``GET /debug/postmortem``.
+fault flight recorder (serving/postmortem.py, ``postmortem_dir=``) writes
+one pruned on-disk postmortem bundle per supervisor fault event, listable
+at ``GET /debug/postmortem``.
 See README "Observability".
 """
 from . import faults  # noqa: F401

@@ -34,7 +34,7 @@ program — the scheduler's mixed plan is the only program shape — keyed by
   emitted-run tokens, accept lengths, row-finite flags — so every step
   makes exactly one device→host transfer (the ``host_syncs`` counter /
   the step trace's ``sync`` phase). Enable speculation with
-  ``spec_decoding=True`` or ``PADDLE_TPU_SPEC_DECODE=1``; with greedy
+  ``spec_decoding=True``; with greedy
   sampling the output is token-for-token identical to non-speculative
   decode, and with temperature sampling verification runs rejection
   sampling against the same temperature/top-k/top-p-processed
@@ -68,22 +68,22 @@ ops/pallas/paged_attention.py's XLA fallback; the Pallas ragged kernel on
 TPU matches to kernel-accumulation tolerance).
 
 **Automatic prefix caching** is on by default (disable with
-``prefix_cache=False`` or ``PADDLE_TPU_PREFIX_CACHE=0``): the engine
-chains each request's full-block prompt hashes ONCE at `add`, the
-scheduler pins any cached prefix at admission so prefill starts at the
-first uncached token, and freed blocks park in the pool's cached-free LRU
-tier. A cache-hit serve is token-for-token identical to a cold serve
-(tests/test_prefix_cache.py): reused blocks hold exactly the K/V a replay
-would recompute, and writes into shared blocks copy-on-write first.
+``prefix_cache=False``): the engine chains each request's full-block
+prompt hashes ONCE at `add`, the scheduler pins any cached prefix at
+admission so prefill starts at the first uncached token, and freed blocks
+park in the pool's cached-free LRU tier. A cache-hit serve is
+token-for-token identical to a cold serve (tests/test_prefix_cache.py):
+reused blocks hold exactly the K/V a replay would recompute, and writes
+into shared blocks copy-on-write first.
 
-**Tensor-parallel serving** (``mesh=...`` / ``PADDLE_TPU_TP``,
-serving/sharded.py): weights and the head-major KV arena shard over a
-``tp`` NamedSharding mesh — the same width-bucket programs compile mesh-aware
-(weights/arena pinned to their tp layouts, host-marshalled step inputs
-replicated, arena donation through the ``mesh_donate_argnums`` gate),
-while block tables, scheduler, prefix cache, and refcounts stay host-side
-and identical to the single-chip engine. Greedy sharded output is
-token-for-token identical to single-chip serving.
+**Tensor-parallel serving** (``mesh=...``, serving/sharded.py): weights
+and the head-major KV arena shard over a ``tp`` NamedSharding mesh — the
+same width-bucket programs compile mesh-aware (weights/arena pinned to
+their tp layouts, host-marshalled step inputs replicated, arena donation
+through the ``mesh_donate_argnums`` gate), while block tables, scheduler,
+prefix cache, and refcounts stay host-side and identical to the
+single-chip engine. Greedy sharded output is token-for-token identical to
+single-chip serving.
 
 **Fault tolerance**: the step programs report per-row logit finiteness,
 and a NaN/Inf row is aborted with ``error:nonfinite_logits`` (its blocks
@@ -92,38 +92,36 @@ reported in ``step_faults``. ``step(only=...)`` restricts one step to a
 set of request ids: the supervision layer (serving/supervisor.py) uses it
 to bisect a raising step down to the one poisoned request, re-queueing
 everyone else via ``requeue`` (preempt-by-recompute). Deterministic fault
-injection (serving/faults.py, ``PADDLE_TPU_FAULTS``) is compiled into the
+injection (serving/faults.py, `faults.install`) is compiled into the
 step/alloc hot paths as one-pointer-test hook sites, off by default.
 
-**Observability** (serving/trace.py, off by default): ``trace=...`` or
-``PADDLE_TPU_TRACE=1`` (or a sampling fraction) turns on the
-ring-buffered lifecycle/step tracer — per-request span trees and a
-per-`step()` phase timeline exported as Perfetto-loadable trace-event
-JSON (``GET /debug/trace`` on the HTTP server, `engine.tracer.dump()`
-anywhere else), with step ids stamped into `jax.profiler` annotations so
-device captures join back to host spans. Disabled, ``self.tracer`` is
-None and every hook is one pointer test. Independently,
-``request_log=True`` / ``PADDLE_TPU_REQUEST_LOG=1`` logs ONE structured
-JSON line per finished/aborted request (queue wait, TTFT, TPOT,
-tenant/priority/deadline, the phase decomposition, cached/spec tokens,
-preemptions) on the ``paddle_tpu.serving.request`` logger — the
-greppable fallback when full tracing is off.
+**Observability** (serving/trace.py, off by default): ``trace=True`` (or
+a sampling fraction) turns on the ring-buffered lifecycle/step tracer —
+per-request span trees and a per-`step()` phase timeline exported as
+Perfetto-loadable trace-event JSON (``GET /debug/trace`` on the HTTP
+server, `engine.tracer.dump()` anywhere else), with step ids stamped into
+`jax.profiler` annotations so device captures join back to host spans.
+Disabled, ``self.tracer`` is None and every hook is one pointer test.
+Independently, ``request_log=True`` logs ONE structured JSON line per
+finished/aborted request (queue wait, TTFT, TPOT, tenant/priority/deadline,
+the phase decomposition, cached/spec tokens, preemptions) on the
+``paddle_tpu.serving.request`` logger — the greppable fallback when full
+tracing is off.
 
-**SLO ledger** (serving/slo.py, ``slo=True`` / ``PADDLE_TPU_SLO=1``):
-a per-request phase clock decomposes every request's wall time into
+**SLO ledger** (serving/slo.py, ``slo=True``): a per-request phase clock
+decomposes every request's wall time into
 ``queued`` / ``prefill_compute`` / ``decode_compute`` / ``preempted`` /
 ``stalled`` / ``emit`` (summing to e2e exactly, by construction), and
 per-(tenant, priority) rollups — p50/p95 TTFT, TPOT, tokens/s,
 preemption share, deadline attainment against ``deadline_s`` — export
 as ``GET /debug/slo`` JSON and true labeled Prometheus histograms on
 ``/metrics``. **Flight recorder** (serving/postmortem.py,
-``postmortem_dir=`` / ``PADDLE_TPU_POSTMORTEM_DIR``): every supervisor
-fault event (poison isolation, watchdog trip, non-finite row,
-engine-thread death) writes one bounded on-disk postmortem bundle
-(trace ring, metrics/pool/health snapshots, fault plan, the victim's
-ledger decomposition, recent request-log lines), pruned to a cap and
-listable at ``GET /debug/postmortem``. Both off by default behind one
-pointer test per hook site.
+``postmortem_dir=``): every supervisor fault event (poison isolation,
+watchdog trip, non-finite row, engine-thread death) writes one bounded
+on-disk postmortem bundle (trace ring, metrics/pool/health snapshots,
+fault plan, the victim's ledger decomposition, recent request-log lines),
+pruned to a cap and listable at ``GET /debug/postmortem``. Both off by
+default behind one pointer test per hook site.
 """
 from __future__ import annotations
 
@@ -132,7 +130,6 @@ import functools
 import json
 import logging
 import math
-import os
 import threading
 import time
 import warnings
@@ -238,13 +235,6 @@ def unpack_step_input(packed, fields):
     return out
 
 
-def _env_flag(name, default):
-    v = os.environ.get(name)
-    if v is None:
-        return default
-    return v.strip().lower() not in ("0", "false", "off", "no", "")
-
-
 def _refuse_option(model, option, asked, why):
     """Three of the engine's options are written against GPT's modules:
     "tp" (`validate_model` and the Megatron placement), "quantize"
@@ -326,12 +316,12 @@ def _adaround_model_int8(model, calib_prompts, iters=300):
 class LLMEngine:
     def __init__(self, model, block_size=16, num_blocks=None, max_batch=4,
                  prefill_chunk=None, token_budget=None, max_seq_len=None,
-                 seed=0, prefix_cache=None, spec_decoding=None,
+                 seed=0, prefix_cache=None, spec_decoding=False,
                  num_spec_tokens=4, spec_max_ngram=3, spec_min_ngram=1,
-                 trace=None, trace_buffer=None, request_log=None, mesh=None,
-                 kv_hbm_bytes=None, slo=None, postmortem_dir=None,
-                 postmortem_keep=None, width_buckets=None,
-                 host_kv_blocks=None, host_swap_chunk=4,
+                 trace=None, trace_buffer=65536, request_log=False,
+                 mesh=None, kv_hbm_bytes=None, slo=False,
+                 postmortem_dir=None, postmortem_keep=16, width_buckets=(),
+                 host_kv_blocks=0, host_swap_chunk=4,
                  kv_dtype=None, quantize=None, calib_prompts=None,
                  quantize_iters=300, quant_allreduce=None,
                  checkpoint_path=None, param_hbm_bytes=None,
@@ -345,12 +335,8 @@ class LLMEngine:
         self.model = model
         cfg = model.cfg
         # tensor-parallel serving (serving/sharded.py): `mesh` is a
-        # ServingMesh / jax Mesh with a 'tp' axis / int tp degree; the
-        # PADDLE_TPU_TP env var supplies a default degree when unset.
-        # None (degree 1) keeps the single-chip engine byte-identical.
-        if mesh is None:
-            env_tp = int(os.environ.get("PADDLE_TPU_TP", "1") or 1)
-            mesh = env_tp if env_tp > 1 else None
+        # ServingMesh / jax Mesh with a 'tp' axis / int tp degree. None
+        # (degree 1) keeps the single-chip engine byte-identical.
         self._smesh = as_serving_mesh(mesh)
         # heads of a cache slab: the model's key/value heads (fewer than
         # its query heads under grouped-query attention). THE one place the
@@ -379,13 +365,10 @@ class LLMEngine:
         self._cache_kinds = None if kinds is None else tuple(kinds())
         self._lane_held = tuple(self._lane_spec) + tuple(
             k.name for k in self._cache_kinds or () if k.window is not None)
-        # int8 KV arena (`kv_dtype="int8"` / PADDLE_TPU_KV_DTYPE): payload
-        # bytes quarter (vs f32) and the SAME kv_hbm_bytes budget admits
-        # ~4x the blocks — behind the parity/perplexity quality gates in
-        # tests/test_int8_kv.py. Anything other than "int8" keeps the
-        # weight-dtype arena.
-        if kv_dtype is None:
-            kv_dtype = os.environ.get("PADDLE_TPU_KV_DTYPE", "") or None
+        # int8 KV arena (`kv_dtype="int8"`): payload bytes quarter (vs
+        # f32) and the SAME kv_hbm_bytes budget admits ~4x the blocks —
+        # behind the parity/perplexity quality gates in
+        # tests/test_int8_kv.py. None keeps the weight-dtype arena.
         if kv_dtype is not None and str(kv_dtype) not in ("int8",):
             raise ValueError(
                 f"kv_dtype {kv_dtype!r} not supported — pass 'int8' for "
@@ -430,19 +413,9 @@ class LLMEngine:
         # EQuARX quantized tp all-reduce (serving/sharded.py
         # `quantized_row_parallel`), gated PER OP so IR001 can lock the
         # resulting collective shape: True = both RowParallel projections,
-        # or an iterable drawn from {"attn_proj", "ffn_fc2"}; the
-        # PADDLE_TPU_QUANT_ALLREDUCE env ("1" or a comma list) supplies a
-        # default. Meaningless (and ignored) single-chip — there is no
-        # collective to quantize at tp=1.
-        if quant_allreduce is None:
-            qa = os.environ.get("PADDLE_TPU_QUANT_ALLREDUCE", "").strip()
-            if qa.lower() in ("", "0", "false", "off", "no"):
-                quant_allreduce = None
-            elif qa.lower() in ("1", "true", "on", "yes"):
-                quant_allreduce = True
-            else:
-                quant_allreduce = [s.strip() for s in qa.split(",")
-                                   if s.strip()]
+        # or an iterable drawn from {"attn_proj", "ffn_fc2"}; None: none.
+        # Meaningless (and ignored) single-chip — there is no collective
+        # to quantize at tp=1.
         if quant_allreduce is True:
             quant_allreduce = ("attn_proj", "ffn_fc2")
         self.quant_collectives = frozenset(quant_allreduce or ())
@@ -536,22 +509,16 @@ class LLMEngine:
             # may carry a full chunk a step)
             token_budget = self.prefill_chunk
         self.prefill_chunk = min(self.prefill_chunk, int(token_budget))
-        # prefix caching: constructor arg wins, then the env kill switch
-        self.prefix_cache = (
-            _env_flag("PADDLE_TPU_PREFIX_CACHE", True)
-            and not self._lane_held       # lane state: off by default
-            if prefix_cache is None else bool(prefix_cache)
-        )
+        # prefix caching: None is on, except where a lane holds state
+        self.prefix_cache = (not self._lane_held if prefix_cache is None
+                             else bool(prefix_cache))
         self._refuse_for_lane_state(
             self.prefix_cache, "prefix_cache=True",
             "a prefix hit hands a request blocks and no state")
-        # speculative decoding: default OFF; constructor arg wins over the
-        # PADDLE_TPU_SPEC_DECODE env gate. num_spec_tokens fixes the verify
-        # program's width (per-request knobs can only lower the draft cap)
-        self.spec_decoding = (
-            _env_flag("PADDLE_TPU_SPEC_DECODE", False)
-            if spec_decoding is None else bool(spec_decoding)
-        )
+        # speculative decoding: default OFF. num_spec_tokens fixes the
+        # verify program's width (per-request knobs can only lower the
+        # draft cap)
+        self.spec_decoding = bool(spec_decoding)
         self._refuse_for_lane_state(
             self.spec_decoding, "spec_decoding=True",
             "a rejected draft has already moved the state")
@@ -573,15 +540,12 @@ class LLMEngine:
         # compiles — (max_batch, W) for W in this sorted set. Defaults:
         # width 1 (the dominant all-decode steps), 1 + num_spec_tokens
         # (spec engines: a drafted pure-decode step), prefill_chunk (the
-        # widest possible chunk). `width_buckets` / PADDLE_TPU_WIDTH_BUCKETS
-        # ("8,32") adds intermediate widths so short prefill tails stop
-        # paying chunk width — each bucket is one more compiled program,
-        # which is why the default set stays minimal. Coinciding widths
-        # (e.g. 1 + num_spec == prefill_chunk) dedup: the table is keyed
-        # by width, not by step kind.
-        if width_buckets is None:
-            wb = os.environ.get("PADDLE_TPU_WIDTH_BUCKETS", "")
-            width_buckets = [int(w) for w in wb.split(",") if w.strip()]
+        # widest possible chunk). `width_buckets` (e.g. (8, 32)) adds
+        # intermediate widths so short prefill tails stop paying chunk
+        # width — each bucket is one more compiled program, which is why
+        # the default set stays minimal. Coinciding widths (e.g.
+        # 1 + num_spec == prefill_chunk) dedup: the table is keyed by
+        # width, not by step kind.
         buckets = {1, self.prefill_chunk}
         if self.spec_decoding:
             buckets.add(min(1 + self.num_spec_tokens, self.max_seq_len))
@@ -603,31 +567,18 @@ class LLMEngine:
         from .lifecycle import ReplicaLifecycle
 
         self.lifecycle = ReplicaLifecycle(metrics=self.metrics)
-        # tracing: off unless trace/PADDLE_TPU_TRACE asks for it. A value
-        # in (0, 1) samples that fraction of requests; the step timeline
-        # is always recorded while the tracer exists. When off, tracer is
-        # None and every hook site below is a single pointer test — the
-        # untraced serve is byte-identical to the pre-trace engine.
-        from ..profiler.tracing import (trace_capacity_from_env,
-                                        trace_sample_from_env)
+        # tracing: off unless `trace` asks for it (True, or a fraction of
+        # requests to sample); the step timeline is always recorded while
+        # the tracer exists. When off, tracer is None and every hook site
+        # below is a single pointer test — the untraced serve is
+        # byte-identical to the pre-trace engine.
         from .trace import EngineTracer
 
-        if trace is None:
-            sample = trace_sample_from_env()
-        elif trace is True:
-            sample = 1.0
-        elif trace is False:
-            sample = 0.0
-        else:
-            sample = min(max(float(trace), 0.0), 1.0)
-        cap = (trace_capacity_from_env() if trace_buffer is None
-               else max(16, int(trace_buffer)))
-        self.tracer = (EngineTracer(capacity=cap, sample=sample)
+        sample = min(max(float(trace or 0.0), 0.0), 1.0)
+        self.tracer = (EngineTracer(capacity=max(16, int(trace_buffer)),
+                                    sample=sample)
                        if sample > 0.0 else None)
-        self.request_log = (
-            _env_flag("PADDLE_TPU_REQUEST_LOG", False)
-            if request_log is None else bool(request_log)
-        )
+        self.request_log = bool(request_log)
         # flight recorder (serving/postmortem.py): a configured directory
         # turns supervisor events (poison isolation, watchdog trip,
         # non-finite row, thread death) into pruned on-disk postmortem
@@ -635,23 +586,17 @@ class LLMEngine:
         from .postmortem import FlightRecorder
         from .slo import SLOLedger
 
-        pm_dir = (os.environ.get("PADDLE_TPU_POSTMORTEM_DIR")
-                  if postmortem_dir is None else postmortem_dir) or None
         self.recorder = None
-        if pm_dir:
-            keep = (int(postmortem_keep) if postmortem_keep is not None
-                    else int(os.environ.get("PADDLE_TPU_POSTMORTEM_KEEP",
-                                            "16") or 16))
-            self.recorder = FlightRecorder(pm_dir, keep=keep).attach(self)
+        if postmortem_dir:
+            self.recorder = FlightRecorder(
+                postmortem_dir, keep=int(postmortem_keep)).attach(self)
         # SLO attribution ledger (serving/slo.py): per-request phase
         # clock + per-(tenant, priority) rollups/histograms and
         # /debug/slo. On when asked — and whenever the request log or
         # the flight recorder is on, since both embed the decomposition;
         # otherwise None and every hook is one pointer test.
-        slo_on = (_env_flag("PADDLE_TPU_SLO", False) if slo is None
-                  else bool(slo))
         self.slo = (SLOLedger(metrics=self.metrics)
-                    if slo_on or self.request_log
+                    if slo or self.request_log
                     or self.recorder is not None else None)
         # weight placement — two paths:
         #  - eager (checkpoint_path=None): the model's resident arrays are
@@ -735,11 +680,8 @@ class LLMEngine:
         # host-memory KV tier (serving/kv_tier.py): `host_kv_blocks` host
         # block slots make evicted cached prefixes swap-back-able instead
         # of dying (and carry them across replicas on drain/eject).
-        # None/0 = off, one pointer, every hook a single test — the
-        # tierless engine is byte-identical to the pre-tier engine.
-        if host_kv_blocks is None:
-            host_kv_blocks = int(
-                os.environ.get("PADDLE_TPU_HOST_KV_BLOCKS", "0") or 0)
+        # 0 = off, one pointer, every hook a single test — the tierless
+        # engine is byte-identical to the pre-tier engine.
         self.tier = None
         self._refuse_for_lane_state(
             host_kv_blocks, "host_kv_blocks (the host KV tier)",
@@ -862,11 +804,6 @@ class LLMEngine:
             (self.max_batch * _ROW_WORDS + n_stats,) if n_stats
             else (self.max_batch, _ROW_WORDS), np.int32), self._rep)
         self._prev = self._no_prev
-        # fault injection (serving/faults.py): arm the PADDLE_TPU_FAULTS
-        # plan if one is configured; with no plan every hook site below is
-        # a single module-attribute pointer test (same discipline as the
-        # tracer — the disabled path is free)
-        faults.maybe_install_from_env()
         # supervision surface (serving/supervisor.py reads these):
         self.step_count = 0      # planned steps run (bisection probes too)
         self.last_planned = []   # request ids of the most recent plan

@@ -38,11 +38,12 @@ every call):
 - ``times=K``        — cap total fires at K (default unlimited; the
   triggers above already bound one-shot cases).
 
-The plan installs process-globally (`install`/`clear`, or the
-``PADDLE_TPU_FAULTS`` JSON env var picked up at engine construction), and
-every hook site is **one pointer test** (``faults._PLAN is not None``) —
-the same discipline as the tracer, so the disabled path costs one global
-load per hook and serving speed is unchanged when no plan is armed.
+The plan installs process-globally (`install`/`clear`; the demo server,
+`server.main`, arms the ``PADDLE_TPU_FAULTS`` JSON env var once at start,
+before it builds engines), and every hook site is **one pointer test**
+(``faults._PLAN is not None``) — the same discipline as the tracer, so the
+disabled path costs one global load per hook and serving speed is
+unchanged when no plan is armed.
 
 Test API::
 
@@ -57,7 +58,8 @@ Test API::
         plan.release_hangs()
         faults.clear()
 
-Env: ``PADDLE_TPU_FAULTS='[{"point": "step_hang", "at_step": 12}]'``.
+Demo server: ``PADDLE_TPU_FAULTS='[{"point": "step_hang", "at_step": 12}]'
+python -m paddle_tpu.serving.server``.
 """
 from __future__ import annotations
 
@@ -258,7 +260,8 @@ def plan_from_json(text):
 def maybe_install_from_env():
     """Arm the ``PADDLE_TPU_FAULTS`` plan if the env var is set and no
     plan is already installed (an explicit `install` wins over the env).
-    Called once per engine construction — never on a hot path."""
+    Called once by `server.main`, the one process boundary, before it
+    builds engines — never on a hot path."""
     if _PLAN is not None:
         return _PLAN
     text = os.environ.get("PADDLE_TPU_FAULTS")

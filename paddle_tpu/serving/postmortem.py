@@ -7,7 +7,7 @@ cumulative blurs, and the request log scrolls away. The flight recorder
 turns each supervisor event into ONE bounded on-disk bundle an operator
 can open after the replica is gone:
 
-    <PADDLE_TPU_POSTMORTEM_DIR>/pm-00042-watchdog_trip/
+    <postmortem_dir>/pm-00042-watchdog_trip/
         bundle.json   # everything below, one JSON document
         trace.json    # the trace ring at event time (Perfetto-loadable;
                       # only when the engine runs with tracing on)
@@ -21,9 +21,9 @@ the current per-class SLO rollup, and the last N request-log lines
 (whether or not the log itself is enabled — the engine feeds the
 recorder's ring directly).
 
-Bundles are pruned oldest-first to ``keep`` (``PADDLE_TPU_POSTMORTEM_KEEP``)
-so a crash-looping replica cannot fill a disk, and are listable without
-shell access at ``GET /debug/postmortem`` (serving/server.py).
+Bundles are pruned oldest-first to ``keep`` (``postmortem_keep``, 16 by
+default) so a crash-looping replica cannot fill a disk, and are listable
+without shell access at ``GET /debug/postmortem`` (serving/server.py).
 
 Off by default: without a directory configured ``engine.recorder`` is
 None and every hook site is one pointer test. `record` never raises into

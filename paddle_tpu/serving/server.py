@@ -46,20 +46,21 @@ health-aware ejection, retry-elsewhere, rolling drain). Endpoints:
 - ``GET /debug/trace`` — the engine's lifecycle/step trace as
   Chrome/Perfetto trace-event JSON (open at https://ui.perfetto.dev).
   404 with a hint unless the engine was built with tracing on
-  (``PADDLE_TPU_TRACE=1`` or ``LLMEngine(trace=...)``); a request body
+  (``--trace`` or ``LLMEngine(trace=...)``); a request body
   may set ``"trace": true`` to force itself into a sampled trace.
 - ``GET /debug/slo`` — the SLO ledger's per-(tenant, priority) rollup
   (serving/slo.py): p50/p95 TTFT and TPOT, tokens/s, preemption share,
   phase-decomposition totals, deadline attainment. 404 with a hint
-  unless the ledger is on (``PADDLE_TPU_SLO=1`` / ``LLMEngine(slo=True)``
-  / request log / flight recorder). Request bodies may carry ``tenant``
+  unless the ledger is on (``--slo`` / ``LLMEngine(slo=True)`` / request
+  log / flight recorder). Request bodies may carry ``tenant``
   (alias ``user``) and ``priority`` to label their class; ``timeout_s``
   doubles as the deadline-attainment target. On the RouterServer this is
   the FLEET rollup (`SLOLedger.merged_rollup` across replica ledgers).
 - ``GET /debug/postmortem`` — manifests of the flight recorder's
   postmortem bundles (serving/postmortem.py; one bundle per poison
   isolation, watchdog trip, non-finite row, or engine-thread death).
-  404 with a hint unless ``PADDLE_TPU_POSTMORTEM_DIR`` is configured.
+  404 with a hint unless ``--postmortem-dir`` (``postmortem_dir=``) is
+  configured.
 - ``GET /debug/router`` (RouterServer only) — the routing table: every
   replica's state machine + healthz word, recent lifecycle events
   (ejections, probes, restarts, drains), and the routing knobs.
@@ -486,9 +487,9 @@ class ServingServer(_HTTPServerBase):
                     "404 Not Found",
                     _error_body(
                         404,
-                        "the SLO ledger is off — start the engine with "
-                        "PADDLE_TPU_SLO=1 (or LLMEngine(slo=True)) for "
-                        "per-class latency attribution rollups",
+                        "the SLO ledger is off — start the server with "
+                        "--slo (or LLMEngine(slo=True)) for per-class "
+                        "latency attribution rollups",
                         "not_found"),
                 ))
                 return await writer.drain()
@@ -506,8 +507,8 @@ class ServingServer(_HTTPServerBase):
                     "404 Not Found",
                     _error_body(
                         404,
-                        "the flight recorder is off — set "
-                        "PADDLE_TPU_POSTMORTEM_DIR (or "
+                        "the flight recorder is off — start the server "
+                        "with --postmortem-dir DIR (or "
                         "LLMEngine(postmortem_dir=...)) to write "
                         "postmortem bundles on fault events",
                         "not_found"),
@@ -527,9 +528,9 @@ class ServingServer(_HTTPServerBase):
                     "404 Not Found",
                     _error_body(
                         404,
-                        "tracing is off — start the engine with "
-                        "PADDLE_TPU_TRACE=1 (or LLMEngine(trace=...)) to "
-                        "record a lifecycle/step trace", "not_found"),
+                        "tracing is off — start the server with --trace "
+                        "1.0 (or LLMEngine(trace=...)) to record a "
+                        "lifecycle/step trace", "not_found"),
                 ))
                 return await writer.drain()
             # a full ring is a multi-MB payload: snapshot + serialize OFF
@@ -546,9 +547,8 @@ class ServingServer(_HTTPServerBase):
                     "404 Not Found",
                     _error_body(
                         404,
-                        "the host KV tier is off — start the engine with "
-                        "LLMEngine(host_kv_blocks=N) (or "
-                        "PADDLE_TPU_HOST_KV_BLOCKS=N) to spill evicted "
+                        "the host KV tier is off — build the engine with "
+                        "LLMEngine(host_kv_blocks=N) to spill evicted "
                         "cache blocks to a host slab", "not_found"),
                 ))
                 return await writer.drain()
@@ -712,9 +712,9 @@ class RouterServer(_HTTPServerBase):
                     "404 Not Found",
                     _error_body(
                         404,
-                        "no replica runs the SLO ledger — build the "
-                        "replica engines with PADDLE_TPU_SLO=1 (or "
-                        "LLMEngine(slo=True)) for the fleet rollup",
+                        "no replica runs the SLO ledger — start the "
+                        "server with --slo (or build the replica engines "
+                        "with LLMEngine(slo=True)) for the fleet rollup",
                         "not_found"),
                 ))
                 return await writer.drain()
@@ -734,8 +734,7 @@ class RouterServer(_HTTPServerBase):
                         404,
                         "no replica runs the host KV tier — build the "
                         "replica engines with LLMEngine(host_kv_blocks=N) "
-                        "(or PADDLE_TPU_HOST_KV_BLOCKS=N) for the fleet "
-                        "view", "not_found"),
+                        "for the fleet view", "not_found"),
                 ))
                 return await writer.drain()
             # each snapshot takes that replica's tier lock — off the
@@ -814,8 +813,7 @@ def main(argv=None):
     p.add_argument("--tp-degree", type=int, default=None,
                    help="tensor-parallel degree: shard weights + the KV "
                         "arena over a 'tp' mesh of this many devices "
-                        "(serving/sharded.py; same as PADDLE_TPU_TP; "
-                        "1/unset = single-chip)")
+                        "(serving/sharded.py; 1/unset = single-chip)")
     p.add_argument("--kv-hbm-bytes", type=int, default=None,
                    help="size the KV pool from a per-chip byte budget "
                         "(per-shard under --tp-degree) instead of "
@@ -871,42 +869,44 @@ def main(argv=None):
                    help="worst-case KV admission gate: reject (429 "
                         "kv_capacity) when admitted requests could need "
                         "more than this many blocks at their longest")
-    p.add_argument("--no-prefix-cache", action="store_true",
-                   help="disable automatic prefix caching (same as "
-                        "PADDLE_TPU_PREFIX_CACHE=0)")
+    p.add_argument("--no-prefix-cache", dest="prefix_cache",
+                   action="store_false", default=None,
+                   help="disable automatic prefix caching")
     p.add_argument("--spec-decode", action="store_true",
                    help="enable speculative decoding (prompt-lookup "
-                        "drafting + batched verify; same as "
-                        "PADDLE_TPU_SPEC_DECODE=1)")
+                        "drafting + batched verify)")
     p.add_argument("--num-spec-tokens", type=int, default=4,
                    help="drafted tokens per decode row when speculative "
                         "decoding is on (sets the spec width bucket)")
     p.add_argument("--trace", type=float, default=None, metavar="FRACTION",
                    help="enable lifecycle/step tracing for this fraction "
                         "of requests (1.0 = all; export at GET "
-                        "/debug/trace; same as PADDLE_TPU_TRACE)")
+                        "/debug/trace)")
     p.add_argument("--request-log", action="store_true",
                    help="log one JSON summary line per finished/aborted "
-                        "request (same as PADDLE_TPU_REQUEST_LOG=1)")
+                        "request")
     p.add_argument("--slo", action="store_true",
                    help="enable the SLO attribution ledger: per-request "
                         "phase decomposition, per-tenant/priority "
                         "rollups at GET /debug/slo, and slo_* Prometheus "
-                        "histograms (same as PADDLE_TPU_SLO=1)")
+                        "histograms")
     p.add_argument("--postmortem-dir", default=None, metavar="DIR",
                    help="enable the fault flight recorder: write one "
                         "postmortem bundle per supervisor event to DIR, "
-                        "listable at GET /debug/postmortem (same as "
-                        "PADDLE_TPU_POSTMORTEM_DIR)")
-    p.add_argument("--postmortem-keep", type=int, default=None,
+                        "listable at GET /debug/postmortem")
+    p.add_argument("--postmortem-keep", type=int, default=16,
                    help="bundles kept before oldest-first pruning "
-                        "(default 16; same as PADDLE_TPU_POSTMORTEM_KEEP)")
+                        "(default 16)")
     args = p.parse_args(argv)
 
     import paddle_tpu as paddle
     from ..models.gpt import gpt_small, gpt_tiny
+    from . import faults
     from .engine import LLMEngine
 
+    # the one process boundary: a PADDLE_TPU_FAULTS drill is armed here,
+    # once, before any engine exists
+    faults.maybe_install_from_env()
     paddle.seed(0)
     build_model = gpt_tiny if args.model == "tiny" else gpt_small
     if args.checkpoint:
@@ -923,18 +923,14 @@ def main(argv=None):
         return LLMEngine(
             model, block_size=args.block_size, max_batch=args.max_batch,
             max_seq_len=args.max_seq_len, prefill_chunk=args.prefill_chunk,
-            prefix_cache=False if args.no_prefix_cache else None,
-            spec_decoding=True if args.spec_decode else None,
+            prefix_cache=args.prefix_cache,
+            spec_decoding=args.spec_decode,
             num_spec_tokens=args.num_spec_tokens,
             trace=args.trace,
-            request_log=True if args.request_log else None,
-            slo=True if args.slo else None,
+            request_log=args.request_log,
+            slo=args.slo,
             postmortem_dir=args.postmortem_dir,
             postmortem_keep=args.postmortem_keep,
-            # pass the degree through untouched: --tp-degree 1 is an
-            # EXPLICIT single-chip request and must beat a PADDLE_TPU_TP
-            # env default (the engine only consults the env when mesh is
-            # None/unset)
             mesh=args.tp_degree,
             kv_hbm_bytes=args.kv_hbm_bytes,
             checkpoint_path=args.checkpoint or None,

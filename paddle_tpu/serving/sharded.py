@@ -191,10 +191,9 @@ def as_serving_mesh(mesh):
     """Coerce `LLMEngine(mesh=...)`'s accepted forms — ServingMesh,
     jax Mesh (must carry a tp axis), or int tp degree — to a ServingMesh.
     Any form that resolves to tp degree <= 1 coerces to None: ``mesh=1``
-    (or a 1-device Mesh) is the EXPLICIT single-chip request (it beats
-    the PADDLE_TPU_TP env default, which only applies when mesh is
-    unset), and degree 1 must take the true single-chip path — the
-    sharded engine would otherwise disable donation for nothing."""
+    (or a 1-device Mesh) is the single-chip engine, and degree 1 must
+    take the true single-chip path — the sharded engine would otherwise
+    disable donation for nothing."""
     if mesh is None:
         return mesh
     if isinstance(mesh, (int, np.integer)):

@@ -47,9 +47,9 @@ disagree on the same traffic:
 - the per-request decomposition on the request-log JSON line
   (``phase_<name>_ms`` fields) and in postmortem bundles.
 
-Off by default (``PADDLE_TPU_SLO=1`` / ``LLMEngine(slo=True)``): when
-off, ``engine.slo`` is None and every hook site is one pointer test —
-the disabled serve is byte-identical. The ledger rides along whenever
+Off by default (``LLMEngine(slo=True)`` turns it on): when off,
+``engine.slo`` is None and every hook site is one pointer test — the
+disabled serve is byte-identical. The ledger rides along whenever
 the request log or the flight recorder is on (both embed the
 decomposition). Label cardinality is bounded: past ``max_classes``
 distinct (tenant, priority) pairs, new classes fold into ``_other``.

@@ -47,11 +47,10 @@ exactly those two views as Chrome/Perfetto trace events:
 The ring buffer, clocks, export, and the xplane join annotation are the
 shared recorder in `paddle_tpu.profiler.tracing` (`Tracer`), which the
 training stack's `TrainTracer` builds on too — this module adds only the
-serving-specific tracks and span vocabulary. The env knobs
-(``PADDLE_TPU_TRACE`` as an on/off switch or request sampling fraction,
-``PADDLE_TPU_TRACE_BUF`` as the ring bound) and the one-pointer-test
-off-by-default discipline are shared verbatim; see the base module's
-docstring for both.
+serving-specific tracks and span vocabulary. The engine's ``trace``
+(on, or a request sampling fraction) and ``trace_buffer`` (the ring bound)
+arguments set it up, and the one-pointer-test off-by-default discipline is
+shared verbatim; see the base module's docstring.
 
 Export: `chrome_trace()` returns the standard trace-event JSON object
 (``{"traceEvents": [...]}``) — serve it from ``GET /debug/trace``
@@ -76,8 +75,6 @@ import time
 from ..profiler.tracing import (  # noqa: F401  (re-exported API)
     STEP_ANNOTATION_PREFIX,
     Tracer,
-    trace_capacity_from_env,
-    trace_sample_from_env,
 )
 
 # process ids of the two fixed tracks groups

@@ -43,14 +43,6 @@ def model():
     return m
 
 
-@pytest.fixture(autouse=True)
-def _no_env_knobs(monkeypatch):
-    """Developer env must not flip dtypes/meshes under the gates."""
-    for var in ("PADDLE_TPU_TP", "PADDLE_TPU_KV_DTYPE",
-                "PADDLE_TPU_QUANT_ALLREDUCE", "PADDLE_TPU_HOST_KV_BLOCKS"):
-        monkeypatch.delenv(var, raising=False)
-
-
 def _wave_prompts(seed=0):
     """The acceptance mixed wave: two prompts sharing a cached prefix,
     one longer than the prefill chunk, one with drafter fodder."""

@@ -49,14 +49,6 @@ def ref_engine(model):
     return LLMEngine(model, block_size=8, max_batch=4, max_seq_len=64)
 
 
-@pytest.fixture(autouse=True)
-def _no_env_knobs(monkeypatch):
-    """Developer env must not shard the single-chip engines or resize the
-    host tier out from under the capacity-pressure tests."""
-    monkeypatch.delenv("PADDLE_TPU_TP", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_HOST_KV_BLOCKS", raising=False)
-
-
 def _prompts(lengths, seed=0):
     rs = np.random.RandomState(seed)
     return [rs.randint(0, 128, (n,)).tolist() for n in lengths]

@@ -373,7 +373,7 @@ def test_cache_hit_serve_matches_reference_mid_traffic(model):
     assert_pool_idle(engine.pool)
 
 
-def test_prefix_cache_disable_flag_and_env(model, monkeypatch):
+def test_prefix_cache_disable_flag(model):
     engine = LLMEngine(model, block_size=8, max_batch=2, max_seq_len=64,
                        prefix_cache=False)
     prompts = _prompts((17, 19), seed=7, shared=16)
@@ -383,13 +383,7 @@ def test_prefix_cache_disable_flag_and_env(model, monkeypatch):
     assert "prefix_cache_hit_tokens" not in engine.metrics.counters
     assert "prefix_cache_lookup_tokens" not in engine.metrics.counters
     assert engine.pool.num_cached_blocks == 0
-    # env kill switch drives the default; explicit ctor arg wins over it
-    monkeypatch.setenv("PADDLE_TPU_PREFIX_CACHE", "0")
-    assert not LLMEngine(model, block_size=8, max_batch=2,
-                         max_seq_len=64).prefix_cache
-    assert LLMEngine(model, block_size=8, max_batch=2, max_seq_len=64,
-                     prefix_cache=True).prefix_cache
-    monkeypatch.setenv("PADDLE_TPU_PREFIX_CACHE", "1")
+    # on by default; the flag turns it off
     assert LLMEngine(model, block_size=8, max_batch=2,
                      max_seq_len=64).prefix_cache
 

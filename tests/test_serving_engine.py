@@ -16,7 +16,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models.gpt import GPT, GPTConfig
-from paddle_tpu.serving import BlockPool, LLMEngine
+from paddle_tpu.serving import BlockPool, LLMEngine, faults
 from paddle_tpu.serving.scheduler import Request, Scheduler
 
 
@@ -91,10 +91,10 @@ def test_width_bucket_collision_dedups_programs(model):
     assert eng.metrics.counters["jit_traces"] <= 2
 
 
-def test_width_buckets_knob(model, monkeypatch):
-    """`width_buckets` (and PADDLE_TPU_WIDTH_BUCKETS) add intermediate
-    ragged widths: a short prefill rides the smallest covering bucket
-    instead of full chunk width, tokens unchanged."""
+def test_width_buckets_knob(model):
+    """`width_buckets` adds intermediate ragged widths: a short prefill
+    rides the smallest covering bucket instead of full chunk width, tokens
+    unchanged."""
     prompts = _prompts((5, 30), seed=8)
     base = LLMEngine(model, block_size=8, max_batch=2, max_seq_len=64)
     want = base.generate(prompts, max_new_tokens=4, temperature=0.0)
@@ -109,14 +109,77 @@ def test_width_buckets_knob(model, monkeypatch):
     (o2,) = eng.generate([prompts[1]], max_new_tokens=4, temperature=0.0)
     assert o2 == want[1]
     assert set(eng._step_fns) == {(2, 1), (2, 8), (2, 64)}
-    # env spelling + validation
-    monkeypatch.setenv("PADDLE_TPU_WIDTH_BUCKETS", "8,32")
-    env_eng = LLMEngine(model, block_size=8, max_batch=2, max_seq_len=64)
-    assert env_eng.width_buckets == [1, 8, 32, 64]
-    monkeypatch.delenv("PADDLE_TPU_WIDTH_BUCKETS")
     with pytest.raises(ValueError, match="width_buckets"):
         LLMEngine(model, block_size=8, max_batch=2, max_seq_len=64,
                   width_buckets=[0])
+
+
+def _options(engine):
+    """What the engine's options came to, read off its attributes."""
+    return dict(
+        tp=1 if engine._smesh is None else engine._smesh.tp_degree,
+        kv_dtype=engine.kv_dtype,
+        quant_collectives=engine.quant_collectives,
+        prefix_cache=engine.prefix_cache,
+        spec_decoding=engine.spec_decoding,
+        width_buckets=engine.width_buckets,
+        trace_capacity=(None if engine.tracer is None
+                        else engine.tracer.capacity),
+        request_log=engine.request_log,
+        slo=engine.slo is not None,
+        postmortem_keep=(None if engine.recorder is None
+                         else engine.recorder.keep),
+        tier=engine.tier is not None,
+        fault_plan=faults._PLAN is not None,
+    )
+
+
+_DEFAULTS = dict(tp=1, kv_dtype=None, quant_collectives=frozenset(),
+                 prefix_cache=True, spec_decoding=False, width_buckets=[1, 64],
+                 trace_capacity=None, request_log=False, slo=False,
+                 postmortem_keep=None, tier=False, fault_plan=False)
+
+
+# (variable, the value that once turned its option on, the arguments it
+# acted beside, what those arguments change of the defaults)
+_ENV_CASES = [
+    ("PADDLE_TPU_TP", "2", {}, {}),
+    ("PADDLE_TPU_KV_DTYPE", "int8", {}, {}),
+    ("PADDLE_TPU_QUANT_ALLREDUCE", "1", {"mesh": 2}, {"tp": 2}),
+    ("PADDLE_TPU_PREFIX_CACHE", "0", {}, {}),
+    ("PADDLE_TPU_SPEC_DECODE", "1", {}, {}),
+    ("PADDLE_TPU_WIDTH_BUCKETS", "8,32", {}, {}),
+    ("PADDLE_TPU_TRACE", "1", {}, {}),
+    ("PADDLE_TPU_TRACE_BUF", "32", {"trace": 1.0},
+     {"trace_capacity": 65536}),
+    ("PADDLE_TPU_REQUEST_LOG", "1", {}, {}),
+    ("PADDLE_TPU_POSTMORTEM_DIR", "{tmp}", {}, {}),
+    ("PADDLE_TPU_POSTMORTEM_KEEP", "3", {"postmortem_dir": "{tmp}"},
+     {"postmortem_keep": 16, "slo": True}),
+    ("PADDLE_TPU_SLO", "1", {}, {}),
+    ("PADDLE_TPU_HOST_KV_BLOCKS", "8", {}, {}),
+    ("PADDLE_TPU_FAULTS", '[{"point": "slow_step_ms", "ms": 1}]', {}, {}),
+]
+
+
+@pytest.mark.parametrize("var, value, kwargs, differs",
+                         [pytest.param(*c, id=c[0]) for c in _ENV_CASES])
+def test_environment_sets_no_engine_option(model, monkeypatch, tmp_path,
+                                           var, value, kwargs, differs):
+    """The engine is what its arguments say: a variable that once set an
+    option (or armed a fault plan) at construction sets nothing. The
+    cases that pass an argument are the variables that acted only beside
+    it, and they expect that argument's own defaults."""
+    def tmp(v):
+        return str(tmp_path) if v == "{tmp}" else v
+
+    monkeypatch.setenv(var, tmp(value))
+    try:
+        engine = LLMEngine(model, block_size=8, max_batch=2, max_seq_len=64,
+                           **{k: tmp(v) for k, v in kwargs.items()})
+        assert _options(engine) == {**_DEFAULTS, **differs}
+    finally:
+        faults.clear()
 
 
 def test_one_host_sync_per_step(model):

@@ -404,7 +404,8 @@ def test_server_debug_trace_and_healthz_pool(model):
 
     (t_status, t_body, status, d_status, trace, h_status, health,
      engine) = asyncio.run(main())
-    assert t_status == 404 and b"PADDLE_TPU_TRACE" in t_body
+    assert t_status == 404 and b"--trace" in t_body
+    assert b"LLMEngine(trace=...)" in t_body and b"PADDLE_TPU" not in t_body
     assert status == 200 and d_status == 200
     names = {e["name"] for e in trace["traceEvents"]}
     assert {"request", "ttft", "queued", "decode"} <= names

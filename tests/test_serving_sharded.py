@@ -72,10 +72,7 @@ def _serve_wave(model, mesh, **kw):
 @pytest.fixture(scope="module")
 def ref_wave(model):
     """Single-chip reference serve of the mixed wave (the parity anchor
-    for every sharded run in this file). mesh=1, not None: this fixture
-    is module-scoped, so it builds BEFORE the function-scoped _no_env_tp
-    guard — only the explicit single-chip request ignores PADDLE_TPU_TP
-    regardless of fixture ordering."""
+    for every sharded run in this file)."""
     eng, outs = _serve_wave(model, mesh=1)
     return eng, outs
 
@@ -83,13 +80,6 @@ def ref_wave(model):
 def _idle(engine):
     assert engine.pool._refcount == {}
     return engine.pool.num_free == engine.pool.num_blocks - 1
-
-
-@pytest.fixture(autouse=True)
-def _no_env_tp(monkeypatch):
-    """A PADDLE_TPU_TP left in the developer's env must not shard this
-    file's single-chip reference engines and make parity vacuous."""
-    monkeypatch.delenv("PADDLE_TPU_TP", raising=False)
 
 
 # ---------------------------------------------------------------------------
@@ -108,6 +98,7 @@ def test_build_serving_mesh_validation():
     assert sm.backend == jax.devices()[0].platform
     # coercions: int, Mesh, ServingMesh, None
     assert as_serving_mesh(None) is None
+    assert as_serving_mesh(1) is None
     assert as_serving_mesh(sm) is sm
     assert as_serving_mesh(2).tp_degree == 2
     assert as_serving_mesh(sm.mesh).tp_degree == 2
@@ -186,17 +177,6 @@ def test_kv_hbm_bytes_admission_per_shard(model):
     with pytest.raises(ValueError, match="not both"):
         LLMEngine(model, block_size=8, num_blocks=64,
                   kv_hbm_bytes=budget)
-
-
-def test_explicit_tp1_beats_env(model, monkeypatch):
-    """mesh=1 (and --tp-degree 1) is an EXPLICIT single-chip request: it
-    must win over a PADDLE_TPU_TP env default; the env applies only when
-    mesh is unset."""
-    monkeypatch.setenv("PADDLE_TPU_TP", "2")
-    assert LLMEngine(model, block_size=8, mesh=1)._smesh is None
-    eng = LLMEngine(model, block_size=8)
-    assert eng._smesh is not None and eng._smesh.tp_degree == 2
-    assert as_serving_mesh(1) is None
 
 
 # ---------------------------------------------------------------------------

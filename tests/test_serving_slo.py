@@ -182,9 +182,7 @@ def _check_histogram_conformance(types, samples, family):
 # -- off by default -----------------------------------------------------------
 
 
-def test_everything_off_by_default(model, monkeypatch):
-    monkeypatch.delenv("PADDLE_TPU_SLO", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_POSTMORTEM_DIR", raising=False)
+def test_everything_off_by_default(model):
     engine = _engine(model)
     assert engine.slo is None and engine.recorder is None
     assert engine.scheduler.slo is None

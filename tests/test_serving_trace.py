@@ -205,8 +205,7 @@ def test_ring_buffer_never_grows_past_bound(model):
 
 # -- disabled tracing is free ----------------------------------------------
 
-def test_disabled_tracing_is_byte_identical_and_absent(model, monkeypatch):
-    monkeypatch.delenv("PADDLE_TPU_TRACE", raising=False)
+def test_disabled_tracing_is_byte_identical_and_absent(model):
     prompts = _prompts((13, 6, 20), seed=3)
     off = _engine(model, prefill_chunk=8)
     assert off.tracer is None            # default: no tracer object at all
@@ -251,13 +250,11 @@ def _spy_on_result(engine):
 
 
 @pytest.mark.parametrize("trace", [None, 1.0], ids=["untraced", "traced"])
-def test_step_reads_its_result_once_and_never_waits(
-        model, monkeypatch, trace):
+def test_step_reads_its_result_once_and_never_waits(model, trace):
     """A step's one host sync is one `np.asarray` of the packed result and
     nothing calls `block_until_ready`, with tracing off AND on: a traced
     step times the `sync` production runs, not a second path; what `sync`
     is made of is read from a device capture."""
-    monkeypatch.delenv("PADDLE_TPU_TRACE", raising=False)
     prompts = _prompts((13, 6, 20), seed=3)
     want = _engine(model, prefill_chunk=8).generate(prompts, max_new_tokens=6)
     engine = _engine(model, prefill_chunk=8, trace=trace)
@@ -299,19 +296,6 @@ def test_step_span_says_when_it_launched_and_which_program_ran(model):
         assert a["width"] in engine.width_buckets
         assert a["slots"] == sum(n * w for n, w in
                                  engine.step_groups(a["width"]))
-
-
-def test_trace_env_knob(model, monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_TRACE", "1")
-    assert _engine(model).tracer is not None
-    monkeypatch.setenv("PADDLE_TPU_TRACE", "0.25")
-    eng = _engine(model)
-    assert eng.tracer is not None and eng.tracer.sample == 0.25
-    monkeypatch.setenv("PADDLE_TPU_TRACE", "0")
-    assert _engine(model).tracer is None
-    monkeypatch.setenv("PADDLE_TPU_TRACE_BUF", "32")
-    monkeypatch.setenv("PADDLE_TPU_TRACE", "1")
-    assert _engine(model).tracer.capacity == 32
 
 
 def test_sampling_fraction_and_per_request_override(model):
@@ -411,8 +395,7 @@ def test_request_log_lines(model, caplog):
             r["total_ms"], abs=0.05)
 
 
-def test_request_log_off_by_default(model, caplog, monkeypatch):
-    monkeypatch.delenv("PADDLE_TPU_REQUEST_LOG", raising=False)
+def test_request_log_off_by_default(model, caplog):
     engine = _engine(model)
     with caplog.at_level(logging.INFO, logger="paddle_tpu.serving.request"):
         engine.generate(_prompts((5,), seed=10), max_new_tokens=2)

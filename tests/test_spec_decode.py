@@ -323,19 +323,9 @@ def test_spec_eos_inside_accepted_run(model):
 
 # -- knobs -----------------------------------------------------------------
 
-def test_spec_env_gate_and_per_request_optout(model, monkeypatch):
+def test_spec_default_off_and_per_request_optout(model):
     assert not LLMEngine(model, block_size=8, max_batch=2,
                          max_seq_len=64).spec_decoding  # default OFF
-    monkeypatch.setenv("PADDLE_TPU_SPEC_DECODE", "1")
-    assert LLMEngine(model, block_size=8, max_batch=2,
-                     max_seq_len=64).spec_decoding
-    monkeypatch.setenv("PADDLE_TPU_SPEC_DECODE", "0")
-    assert not LLMEngine(model, block_size=8, max_batch=2,
-                         max_seq_len=64).spec_decoding
-    # explicit ctor arg beats the env
-    assert LLMEngine(model, block_size=8, max_batch=2, max_seq_len=64,
-                     spec_decoding=True).spec_decoding
-    monkeypatch.delenv("PADDLE_TPU_SPEC_DECODE")
 
     # per-request opt-out on a spec engine: no drafts for that request
     prompts = _prompts((6, 8), seed=7)

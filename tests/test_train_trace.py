@@ -141,8 +141,7 @@ def test_phases_nest_inside_their_train_step():
 
 # -- tracing off is the pre-trace path --------------------------------------
 
-def test_trace_off_loss_trajectory_identical(monkeypatch):
-    monkeypatch.delenv("PADDLE_TPU_TRACE", raising=False)
+def test_trace_off_loss_trajectory_identical():
     tracing.reset_train_tracing()
     assert tracing.train_tracer() is None       # hook sites see None
     _, rec_off = _fit(epochs=2)
@@ -155,18 +154,16 @@ def test_trace_off_loss_trajectory_identical(monkeypatch):
     assert len(tr.chrome_trace()["traceEvents"]) > 0
 
 
-def test_env_knobs(monkeypatch):
+def test_environment_does_not_turn_training_tracing_on(monkeypatch):
+    """Training is traced only where code asks: `enable_train_tracing` is
+    the one switch, and no variable of the environment is a second."""
     monkeypatch.setenv("PADDLE_TPU_TRACE", "1")
     monkeypatch.setenv("PADDLE_TPU_TRACE_BUF", "64")
     tracing.reset_train_tracing()
-    tr = tracing.train_tracer()
-    assert isinstance(tr, TrainTracer) and tr.capacity == 64
-    assert tracing.train_tracer() is tr          # stable across calls
-    monkeypatch.setenv("PADDLE_TPU_TRACE", "0")
-    tracing.reset_train_tracing()
     assert tracing.train_tracer() is None
-    # explicit API wins over env
-    monkeypatch.setenv("PADDLE_TPU_TRACE", "1")
+    tr = tracing.enable_train_tracing()
+    assert isinstance(tr, TrainTracer) and tr.capacity == 65536
+    assert tracing.train_tracer() is tr          # stable across calls
     tracing.disable_train_tracing()
     assert tracing.train_tracer() is None
 
